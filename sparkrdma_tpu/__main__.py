@@ -15,13 +15,11 @@ def _info() -> int:
 
     print(f"sparkrdma_tpu {sparkrdma_tpu.__version__}")
     print(f"native runtime: {'built' if native.available() else 'pure-Python fallback'}")
-    try:
-        import jax
-        devs = jax.devices()
-        print(f"devices: {len(devs)} x {devs[0].device_kind} "
-              f"({devs[0].platform})")
-    except Exception as e:  # noqa: BLE001
-        print(f"devices: unavailable ({type(e).__name__})")
+    # a backend that fails to initialize is an error, not "unavailable"
+    import jax
+    devs = jax.devices()
+    print(f"devices: {len(devs)} x {devs[0].device_kind} "
+          f"({devs[0].platform})")
     return 0
 
 
@@ -75,14 +73,18 @@ def _selftest() -> int:
 
 
 def _demo() -> int:
-    """On-mesh TeraSort demo on whatever devices are available."""
+    """On-mesh TeraSort demo on jax's default backend — a toy-size
+    correctness demo, not a measurement path, so it may run on the CPU;
+    the record names the platform it ran on."""
     import jax
     import numpy as np
     from jax.sharding import Mesh
 
     from sparkrdma_tpu.models.terasort import (
         TeraSortConfig, generate_rows, run_terasort, verify_terasort)
+    from sparkrdma_tpu.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     devs = jax.devices()
     mesh = Mesh(np.array(devs), ("shuffle",))
     cfg = TeraSortConfig(rows_per_device=100_000, payload_words=4,
@@ -91,8 +93,9 @@ def _demo() -> int:
     out, counts, dt = run_terasort(mesh, cfg, rows=rows)
     verify_terasort(out, counts, rows, len(devs))
     print(json.dumps({"demo": "terasort", "rows": len(rows),
-                      "devices": len(devs), "step_s": round(dt, 4),
-                      "verified": True}))
+                      "devices": len(devs), "platform": devs[0].platform,
+                      "device_kind": devs[0].device_kind,
+                      "step_s": round(dt, 4), "verified": True}))
     return 0
 
 
@@ -122,7 +125,9 @@ def _engine_demo(use_mesh: bool = False) -> int:
         from jax.sharding import Mesh
 
         from sparkrdma_tpu.parallel import exchange as exchange_mod
+        from sparkrdma_tpu.utils.compile_cache import enable_compile_cache
 
+        enable_compile_cache()
         mesh = Mesh(np.array(jax.devices()), ("shuffle",))
         exchanges = exchange_mod.DATA_PLANE["exchanges"]
     try:
@@ -143,6 +148,8 @@ def _engine_demo(use_mesh: bool = False) -> int:
             from sparkrdma_tpu.parallel import exchange as exchange_mod
 
             record["data_plane"] = "mesh"
+            record["platform"] = mesh.devices.flat[0].platform
+            record["device_kind"] = mesh.devices.flat[0].device_kind
             record["collective_exchanges"] = (
                 exchange_mod.DATA_PLANE["exchanges"] - exchanges)
             ok = ok and record["collective_exchanges"] > 0

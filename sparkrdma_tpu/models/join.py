@@ -25,9 +25,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from sparkrdma_tpu.utils.compat import shard_map
 
 from sparkrdma_tpu.ops.partition import hash_partition
 from sparkrdma_tpu.parallel.exchange import resolve_impl, shuffle_shard

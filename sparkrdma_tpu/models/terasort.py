@@ -275,11 +275,30 @@ def run_terasort_streamed(mesh: Mesh, cfg: TeraSortConfig, rows: np.ndarray,
     return merged, num_rounds
 
 
+def _row_fingerprints(rows: np.ndarray) -> np.ndarray:
+    """One u64 per row: every word times a fixed odd per-column
+    multiplier, summed mod 2^64. Two row sets with equal fingerprint
+    multisets hold the same whole rows (key AND payload) up to a 2^-64
+    collision — chunked so a 1 GiB input never doubles in memory."""
+    mult = np.random.default_rng(0x7E5A).integers(
+        0, 2**63, size=rows.shape[1], dtype=np.uint64) * 2 + 1
+    out = np.empty(len(rows), dtype=np.uint64)
+    chunk = 1 << 20
+    for lo in range(0, len(rows), chunk):
+        out[lo:lo + chunk] = (rows[lo:lo + chunk].astype(np.uint64)
+                              * mult).sum(axis=1, dtype=np.uint64)
+    return out
+
+
 def verify_terasort(sorted_rows: np.ndarray, counts: np.ndarray,
                     input_rows: np.ndarray, num_devices: int) -> None:
-    """Check the global sort contract against the input multiset."""
+    """Check the global sort contract against the input: each device
+    locally sorted, device ranges ordered, the key multiset preserved,
+    and every payload still attached to the key it came with (whole-row
+    multiset equality, via ``_row_fingerprints``)."""
     per_dev = sorted_rows.reshape(num_devices, -1, sorted_rows.shape[-1])
     got_keys = []
+    got_fps = []
     prev_max = -1
     for d in range(num_devices):
         total = int(counts[d].sum())
@@ -289,7 +308,11 @@ def verify_terasort(sorted_rows: np.ndarray, counts: np.ndarray,
             assert keys[0] >= prev_max, f"device {d} overlaps previous range"
             prev_max = keys[-1]
         got_keys.append(keys)
+        got_fps.append(_row_fingerprints(per_dev[d][:total]))
     got = np.concatenate(got_keys)
     assert len(got) == len(input_rows), "row count mismatch"
     np.testing.assert_array_equal(np.sort(got),
                                   np.sort(input_rows[:, 0].astype(np.int64)))
+    assert np.array_equal(np.sort(np.concatenate(got_fps)),
+                          np.sort(_row_fingerprints(input_rows))), \
+        "payload detached from its key (row multiset differs)"

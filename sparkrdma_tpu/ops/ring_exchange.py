@@ -35,15 +35,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
-
-from sparkrdma_tpu.utils.compat import (
-    shape_dtype_struct,
-    shard_map,
-    tpu_compiler_params,
-)
 
 
 def _ring_kernel(axis_name: str, num_devices: int, use_barrier: bool,
@@ -126,8 +121,8 @@ def ring_all_to_all_shard(blocks: jnp.ndarray, axis_name: str,
                                not interpret)
     return pl.pallas_call(
         kernel,
-        out_shape=shape_dtype_struct(blocks.shape, blocks.dtype,
-                                     vma=frozenset({axis_name})),
+        out_shape=jax.ShapeDtypeStruct(blocks.shape, blocks.dtype,
+                                       vma=frozenset({axis_name})),
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         scratch_shapes=[
@@ -140,7 +135,7 @@ def ring_all_to_all_shard(blocks: jnp.ndarray, axis_name: str,
         # entry rendezvous; interpret mode has no barrier (and Mosaic
         # rejects the id when no barrier semaphore is referenced)
         compiler_params=(None if interpret
-                         else tpu_compiler_params(collective_id=7)),
+                         else pltpu.CompilerParams(collective_id=7)),
         interpret=interpret,
     )(blocks)
 

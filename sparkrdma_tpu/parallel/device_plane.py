@@ -78,20 +78,15 @@ def _resolve_plan_impl(mesh, impl: str, axis_name: str) -> str:
 
 
 def stage_to_device(arr: np.ndarray, sharding):
-    """One staged round's host->device upload, donation-friendly: when
-    the runtime supports aliasing (jax >= 0.4.31), the host staging
-    buffer — a BufferPool lease the native fetch engine already landed
-    wire bytes in, or the round's freshly-padded block, never touched
-    again after dispatch — may back the device array directly instead of
-    being copied. Backends that can't alias (or older runtimes without
-    the parameter) transfer exactly as before; results are identical
-    either way."""
+    """One staged round's host->device upload, donation-friendly: the
+    host staging buffer — a BufferPool lease the native fetch engine
+    already landed wire bytes in, or the round's freshly-padded block,
+    never touched again after dispatch — may back the device array
+    directly instead of being copied. Backends that can't alias transfer
+    exactly as before; results are identical either way."""
     import jax
 
-    try:
-        return jax.device_put(arr, sharding, may_alias=True)
-    except TypeError:  # runtime predates may_alias
-        return jax.device_put(arr, sharding)
+    return jax.device_put(arr, sharding, may_alias=True)
 
 
 # one-time latch for the mesh_rows_per_round deprecation (engine ctor
@@ -444,6 +439,7 @@ def make_fused_step(mesh, axis_name: str, row_words: int, *,
     import jax
     import jax.numpy as jnp
 
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from sparkrdma_tpu.ops.partition import uniform_splitters
@@ -452,7 +448,6 @@ def make_fused_step(mesh, axis_name: str, row_words: int, *,
         ragged_exchange_shard,
         resolve_transport,
     )
-    from sparkrdma_tpu.utils.compat import shard_map
 
     if sort_mode not in ("gather", "multisort", "colsort"):
         # a typo must not silently measure (and mislabel) the gather path

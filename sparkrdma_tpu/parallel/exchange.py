@@ -55,10 +55,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from sparkrdma_tpu.utils.compat import shard_map
 
 # Host-side dispatch tally for the ICI data plane. Callers that launch a
 # collective exchange (mesh_service, models) record here so tests and the
@@ -169,10 +167,15 @@ def ragged_exchange_shard(data: jnp.ndarray, send_counts: jnp.ndarray,
     # 2. data exchange over ICI.
     if impl in ("dense", "ring", "ring_interpret") \
             and output.shape[0] < mat.shape[0]:
-        # q = out_cap // D would be zero: no slot can carry even one row.
-        # gather handles any capacity; static shapes make this a
-        # trace-time branch
-        impl = "gather"
+        # q = out_cap // D would be zero: no slot can carry even one
+        # row. Running a different transport under the asked-for name
+        # would mislabel what moved the bytes, so this is the caller's
+        # sizing error (static shapes make it a trace-time check)
+        raise ValueError(
+            f"exchange impl {impl!r} needs a receive capacity of at "
+            f"least one row per device ({output.shape[0]} rows < "
+            f"{mat.shape[0]} devices); grow the buffer or ask for "
+            "'gather'")
     pair_overflow = jnp.bool_(False)
     if impl == "native":
         received = lax.ragged_all_to_all(
@@ -333,6 +336,11 @@ def shuffle_shard(data: jnp.ndarray, dest: jnp.ndarray, axis_name: str,
     return ragged_exchange_shard(grouped, counts, axis_name, output, impl)
 
 
+# the one compile-time rejection that selects the dense transport (see
+# _native_compiles; tests/test_tpu_aot.py pins the compiler's text)
+_LIMITED_ICI_ROUTING = "not supported in limited ICI routing"
+
+
 @functools.lru_cache(maxsize=32)
 def _native_compiles(mesh: Mesh, axis_name: str) -> Tuple[bool, str]:
     """(supported, reason): whether THIS mesh's TPU compiler accepts
@@ -341,10 +349,11 @@ def _native_compiles(mesh: Mesh, axis_name: str) -> Tuple[bool, str]:
     Not every topology does: v5e slices above 16 chips have limited ICI
     routing and the opcode is rejected at compile time ("Ragged
     all-to-all is currently not supported in limited ICI routing
-    settings" — found via AOT compile, tests/test_tpu_aot.py). One tiny
-    throwaway compile per (mesh, axis), cached; the actual compiler
-    error is preserved so a transient/unexpected failure is never
-    misreported as a topology limit.
+    settings"). One tiny throwaway compile per (mesh, axis), cached.
+    ONLY that rejection answers "unsupported"; any other compile failure
+    is not a topology limit and propagates with the compiler's message —
+    a broken toolchain must not quietly run the job on another
+    transport.
     """
     n = mesh.shape[axis_name]
     spec = P(axis_name)
@@ -361,20 +370,22 @@ def _native_compiles(mesh: Mesh, axis_name: str) -> Tuple[bool, str]:
     idx = jax.ShapeDtypeStruct((n, n), jnp.int32, sharding=sh)
     try:
         probe.lower(arg, arg, idx, idx).compile()
-        return True, ""
-    except Exception as e:  # noqa: BLE001 — any rejection means no
-        return False, f"{type(e).__name__}: {e}"
+    except Exception as e:  # the compiler's error type is not public API
+        if _LIMITED_ICI_ROUTING in str(e):
+            return False, f"{type(e).__name__}: {e}"
+        raise
+    return True, ""
 
 
 def resolve_impl(mesh: Mesh, impl: str = "auto",
                  axis_name: Optional[str] = None) -> str:
     """``auto`` -> native on TPU meshes whose compiler supports the
-    ragged-all-to-all opcode over the exchange axis, decomposed fallback
-    elsewhere (XLA:CPU has no opcode at all; large v5e slices reject it
-    for limited ICI routing — there the gather decomposition keeps
-    results correct, and ``make_chunked_exchange(impl="ring")`` is the
-    bandwidth-efficient alternative). ``axis_name`` defaults to the last
-    mesh axis (the convention everywhere in this package)."""
+    ragged-all-to-all opcode over the exchange axis; the dense fixed-slot
+    transport on the large v5e slices that reject it for limited ICI
+    routing (and only there — any other probe failure raises); the
+    gather oracle on non-TPU meshes (XLA:CPU has no opcode at all).
+    ``axis_name`` defaults to the last mesh axis (the convention
+    everywhere in this package)."""
     if impl != "auto":
         return impl
     platform = next(iter(mesh.devices.flat)).platform

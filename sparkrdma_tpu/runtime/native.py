@@ -4,7 +4,9 @@ The reference's equivalent layer is libdisni's JNI binding of libibverbs
 (pom.xml:79-96; load-failure handling at java/RdmaNode.java:109-112 — a
 missing native library degrades with a clear message rather than crashing).
 We keep that behavior: if ``libtpushuffle.so`` is absent or unloadable,
-``LIB`` is ``None`` and callers fall back to pure-Python implementations.
+``LIB`` is ``None`` and callers fall back to pure-Python implementations
+— after one WARNING saying so, because the two runtimes differ in every
+host-path cost and a run must be able to tell which one it had.
 
 Rebuild with ``make -C csrc``.
 """
@@ -12,6 +14,7 @@ Rebuild with ``make -C csrc``.
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 from typing import Optional
 
@@ -22,9 +25,14 @@ def _load() -> Optional[ctypes.CDLL]:
     try:
         lib = ctypes.CDLL(_LIB_PATH)
         return _bind(lib)
-    except (OSError, AttributeError):
+    except (OSError, AttributeError) as e:
         # missing OR stale .so (built before a symbol was added): degrade to
-        # pure Python rather than failing package import
+        # pure Python rather than failing package import. Runs once, at
+        # import, so this is the one warning per process.
+        logging.getLogger(__name__).warning(
+            "native runtime not loaded (%s: %s); every component runs "
+            "its pure-Python twin. Rebuild with `make -C csrc`.",
+            type(e).__name__, e)
         return None
 
 

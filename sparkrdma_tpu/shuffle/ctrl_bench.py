@@ -31,7 +31,7 @@ bench also reports ``registrations_per_s`` through the full sharded
 admission path (``ShardMap.assign`` + generation compose) — the number
 the tenant sustained bench corroborates end-to-end.
 
-Pure host path — identical on TPU and CPU-fallback records.
+Pure host path.
 """
 
 from __future__ import annotations

@@ -1053,6 +1053,13 @@ def test_chaos_replan_mid_stage_after_executor_loss(tmp_path):
         handle = driver.register_shuffle(1, num_maps=6, num_partitions=8,
                                          partitioner=PartitionerSpec("modulo"))
         run_map_stage(execs, handle, _skew_map_fn)
+        # publishes are one-sided (no ack): plan only once the driver's
+        # size histogram holds every map, or the plan is built from a
+        # partial (or empty) histogram
+        deadline = time.monotonic() + 5.0
+        while (driver.driver.size_histogram(1).maps_recorded < 6
+               and time.monotonic() < deadline):
+            time.sleep(0.005)
         plan = driver.plan_reduce(handle)
         assert plan is not None and len(plan.tasks) >= 3, f"seed={SEED}"
         assert plan.counts()["split_partitions"] >= 1, f"seed={SEED}"

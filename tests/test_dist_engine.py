@@ -22,16 +22,6 @@ from sparkrdma_tpu.shuffle.spark_compat import (
 )
 from sparkrdma_tpu.tasks import remote_executors
 
-# the two tests that run a REAL collective over the 2-process CPU mesh
-# need a jax whose XLA:CPU implements multiprocess computations (0.5+);
-# the failure-path tests never reach a successful collective and run
-# anywhere
-import jax  # noqa: E402
-
-_requires_multiprocess_cpu = pytest.mark.skipif(
-    tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5),
-    reason="jax<0.5 XLA:CPU cannot run multiprocess computations")
-
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _WORKER = f'''
@@ -158,7 +148,6 @@ def test_dist_collective_retries_through_recovery(monkeypatch, tmp_path):
         driver.stop()
 
 
-@_requires_multiprocess_cpu
 def test_rdd_over_distributed_mesh(tmp_path):
     """The RDD layer's pickled-blob shuffles ride the cross-process
     collective unchanged — including BOUNDED ROUNDS that split a map's
@@ -290,7 +279,6 @@ def test_kill_executor_mid_collective_fails_fast(tmp_path):
         driver.stop()
 
 
-@_requires_multiprocess_cpu
 def test_engine_distributed_mesh_reduce(tmp_path):
     driver = SparkCompatShuffleManager(CONF, isDriver=True)
     host, port = driver.driverAddr

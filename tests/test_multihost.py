@@ -9,15 +9,6 @@ import socket
 import subprocess
 import sys
 
-import jax
-import pytest
-
-# every test here runs a real two-process jax.distributed CPU mesh;
-# XLA:CPU only learned multiprocess computations in jax 0.5
-pytestmark = pytest.mark.skipif(
-    tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5),
-    reason="jax<0.5 XLA:CPU cannot run multiprocess computations")
-
 _WORKER = r'''
 import sys, numpy as np
 pid, port = int(sys.argv[1]), sys.argv[2]

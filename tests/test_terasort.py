@@ -46,6 +46,19 @@ def test_terasort_payload_rides_with_keys(mesh):
         np.testing.assert_array_equal(seg[:, 2], seg[:, 0] + 1)
 
 
+def test_verify_catches_a_detached_payload(mesh):
+    """verify_terasort holds the payload to its key: an output with the
+    right keys in the right order but two payloads swapped must fail."""
+    cfg = TeraSortConfig(rows_per_device=256, payload_words=3, out_factor=2)
+    rows = generate_rows(cfg, D, seed=3)
+    sorted_rows, counts, _ = run_terasort(mesh, cfg, rows=rows)
+    verify_terasort(sorted_rows, counts, rows, D)
+    bad = sorted_rows.copy()
+    bad[[0, 1], 1:] = bad[[1, 0], 1:]
+    with pytest.raises(AssertionError, match="payload detached"):
+        verify_terasort(bad, counts, rows, D)
+
+
 def test_numpy_baseline_is_a_true_sort():
     cfg = TeraSortConfig(rows_per_device=1000, payload_words=1)
     rows = generate_rows(cfg, 2, seed=2)
@@ -57,8 +70,11 @@ def test_numpy_baseline_is_a_true_sort():
 def test_graft_entry_contract():
     """entry() and dryrun_multichip() must work as the driver expects."""
     import importlib.util
+    import os
     spec = importlib.util.spec_from_file_location(
-        "__graft_entry__", "/root/repo/__graft_entry__.py")
+        "__graft_entry__", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "__graft_entry__.py"))
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     fn, args = mod.entry()

@@ -20,19 +20,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from sparkrdma_tpu.utils.compat import shard_map
-
 AXIS = "shuffle"
-
-# the opcode these tests compiler-validate arrived in jax 0.5.x; an older
-# interpreter can only ever watch resolve_impl fall back to dense, so the
-# native-path assertions are environment-gated (same spirit as the
-# tpu_mesh fixture's topology skip)
-requires_ragged = pytest.mark.skipif(
-    not hasattr(jax.lax, "ragged_all_to_all"),
-    reason="this jax lacks lax.ragged_all_to_all (the opcode under test)")
 
 
 @functools.lru_cache(maxsize=1)
@@ -62,7 +53,6 @@ def _lower_compile(jitted, *args):
     return text, compiled
 
 
-@requires_ragged
 def test_native_exchange_compiles_with_ragged_opcode(tpu_mesh):
     """The full 8-device native exchange AOT-compiles for v5e and actually
     lowers to the ragged-all-to-all opcode (not a silent decomposition)."""
@@ -77,7 +67,6 @@ def test_native_exchange_compiles_with_ragged_opcode(tpu_mesh):
     assert "ragged_all_to_all" in text, "native path decomposed away"
 
 
-@requires_ragged
 def test_terasort_step_compiles_for_tpu(tpu_mesh):
     """The flagship multi-chip step (partition + native ragged exchange +
     sort) passes the real XLA:TPU compiler at v5e layouts."""
@@ -148,7 +137,6 @@ def test_chunked_ring_round_compiles(tpu_mesh):
     _lower_compile(round_fn, grouped, counts, 0)
 
 
-@requires_ragged
 def test_2d_mesh_exchange_compiles(tpu_mesh):
     """dp x shuffle composition (the embedding a host engine uses) compiles
     for v5e — collectives ride the inner mesh axis only."""
@@ -173,7 +161,6 @@ def test_2d_mesh_exchange_compiles(tpu_mesh):
     assert "ragged_all_to_all" in text
 
 
-@requires_ragged
 def test_tpcds_step_compiles_for_tpu(tpu_mesh):
     """The 5-exchange star-join step (the TPC-DS-class plan) compiles for
     v5e with all exchanges on the native opcode."""
@@ -189,7 +176,6 @@ def test_tpcds_step_compiles_for_tpu(tpu_mesh):
     assert text.count("ragged_all_to_all") >= 5
 
 
-@requires_ragged
 def test_scale_up_topologies_resolve_and_compile():
     """The v5e compiler accepts ragged-all-to-all only up to 16 chips
     (32+ have limited ICI routing and reject the opcode — discovered by
@@ -220,7 +206,34 @@ def test_scale_up_topologies_resolve_and_compile():
             assert "all_to_all" in text, name
 
 
-@requires_ragged
+@pytest.mark.parametrize("message,want", [
+    ("INTERNAL: RET_CHECK failure !op_region_.target()."
+     "HasLimitedIciRouting() Ragged all-to-all is currently not supported "
+     "in limited ICI routing settings", "dense"),
+    ("INTERNAL: libtpu lost its mind", None),
+])
+def test_resolve_impl_only_forgives_the_routing_rejection(
+        tpu_mesh, monkeypatch, message, want):
+    """On a TPU mesh only the compiler's limited-ICI-routing rejection
+    selects dense; any other probe failure re-raises with the compiler's
+    message instead of quietly running another transport."""
+    from sparkrdma_tpu.parallel import exchange as exchange_mod
+
+    def refuse(*a, **kw):
+        raise RuntimeError(message)
+
+    monkeypatch.setattr(exchange_mod.lax, "ragged_all_to_all", refuse)
+    exchange_mod._native_compiles.cache_clear()
+    try:
+        if want is None:
+            with pytest.raises(RuntimeError, match="lost its mind"):
+                exchange_mod.resolve_impl(tpu_mesh, axis_name=AXIS)
+        else:
+            assert exchange_mod.resolve_impl(tpu_mesh, axis_name=AXIS) == want
+    finally:
+        exchange_mod._native_compiles.cache_clear()
+
+
 def test_native_parity_where_backend_executes():
     """Bit-identity of impl='native' vs the gather oracle, on any running
     backend that honors the opcode (today: real multi-chip TPU; XLA:CPU
