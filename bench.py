@@ -98,13 +98,14 @@ def _run_with_watchdog() -> int:
     results: dict = {}
     failures = []
     # multisort's 26-operand sort network never finished a cold compile
-    # within 900s on the XLA:TPU compiler; it is only worth attempting
-    # when the persistent cache already holds it (or the operator grants
-    # a bigger budget via BENCH_TIMEOUT_MULTISORT_S).
+    # within 900s on the XLA:TPU compiler, and a failed phase fails the
+    # run: it is attempted only when the operator grants it a budget
+    # (BENCH_TIMEOUT_MULTISORT_S) or pins it (BENCH_SORT_MODE).
     ms_timeout_s = int(env.get("BENCH_TIMEOUT_MULTISORT_S",
                                str(mode_timeout_s)))
-    plan = [("gather", mode_timeout_s), ("colsort", mode_timeout_s),
-            ("multisort", ms_timeout_s)]
+    plan = [("gather", mode_timeout_s), ("colsort", mode_timeout_s)]
+    if "BENCH_TIMEOUT_MULTISORT_S" in env:
+        plan.append(("multisort", ms_timeout_s))
     if env.get("BENCH_SORT_MODE"):
         # operator pinned a mode: run exactly that one (e.g. skipping the
         # multisort attempt entirely when its compile isn't cached yet),
@@ -236,9 +237,10 @@ def _cpu_baseline(size_mb: int, n: int,
     return dt, False
 
 
-def _secondary_workloads(detail: dict, mesh, n: int, on_tpu: bool) -> None:
-    """Time the PageRank / join / TPC-DS steps (BASELINE.md configs #3/#4);
-    best-effort — they enrich ``detail`` but never break the headline."""
+def _secondary_workloads(detail: dict, mesh, n: int) -> None:
+    """Time the PageRank / join / TPC-DS steps (BASELINE.md configs #3/#4)
+    and the host-side A/Bs into ``detail``; one that raised leaves an
+    ``*_error`` key there, which fails the run (``_phase_exit``)."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -246,8 +248,8 @@ def _secondary_workloads(detail: dict, mesh, n: int, on_tpu: bool) -> None:
 
     def bench_pagerank():
         from sparkrdma_tpu.models.pagerank import PageRankConfig, make_pagerank_step, random_graph
-        pcfg = PageRankConfig(num_vertices=(1 << 16) if on_tpu else 1024,
-                              edges_per_device=(1 << 20) // n if on_tpu else 4096,
+        pcfg = PageRankConfig(num_vertices=1 << 16,
+                              edges_per_device=(1 << 20) // n,
                               out_factor=max(2, n))
         edges, ranks, deg = random_graph(pcfg, n, seed=0)
         inputs = tuple(jax.device_put(x, sh) for x in (edges, ranks, deg))
@@ -255,7 +257,7 @@ def _secondary_workloads(detail: dict, mesh, n: int, on_tpu: bool) -> None:
 
     def bench_join():
         from sparkrdma_tpu.models.join import JoinConfig, make_join_step, generate_tables
-        jrows = (1 << 20) if on_tpu else 4096
+        jrows = 1 << 20
         jcfg = JoinConfig(rows_per_device_left=jrows, rows_per_device_right=jrows,
                           key_space=jrows, out_factor=2)
         left, right = generate_tables(jcfg, n, seed=0)
@@ -264,7 +266,7 @@ def _secondary_workloads(detail: dict, mesh, n: int, on_tpu: bool) -> None:
 
     def bench_tpcds():
         from sparkrdma_tpu.models.tpcds import TpcdsConfig, generate_star, make_tpcds_step, pad_to_devices
-        frows = (1 << 20) if on_tpu else 2048
+        frows = 1 << 20
         tcfg = TpcdsConfig(fact_rows_per_device=frows,
                            dim1_size=frows // 4, dim2_size=frows // 4,
                            num_groups=1024, out_factor=4)
@@ -280,7 +282,7 @@ def _secondary_workloads(detail: dict, mesh, n: int, on_tpu: bool) -> None:
     _progress("join done")
     _bench_secondary(detail, "tpcds", "tpcds_fact_rows_per_s", bench_tpcds, reps=3)
     _progress("tpcds done")
-    _bench_als(detail, mesh, n, on_tpu)
+    _bench_als(detail, mesh, n)
     _progress("als done")
     _bench_fetch_pipeline(detail)
     _progress("fetch pipeline done")
@@ -314,7 +316,7 @@ def _secondary_workloads(detail: dict, mesh, n: int, on_tpu: bool) -> None:
     _progress("control-plane scale-out done")
 
 
-def _bench_als(detail: dict, mesh, n: int, on_tpu: bool) -> None:
+def _bench_als(detail: dict, mesh, n: int) -> None:
     """ALS skewed half-step (BASELINE config #5, the skew stress): the
     zipf-hammered item side routed through the bounded-round chunked
     exchange, timed as ratings routed per second. Host-driven (grouping
@@ -324,7 +326,7 @@ def _bench_als(detail: dict, mesh, n: int, on_tpu: bool) -> None:
         from sparkrdma_tpu.models.als import (
             ALSConfig, als_half_step, generate_ratings)
 
-        per_dev = (1 << 16) if on_tpu else 2048
+        per_dev = 1 << 16
         acfg = ALSConfig(num_users=64 * n, num_items=max(16, per_dev // 64),
                          rank=8, zipf_a=1.3)
         ratings = generate_ratings(acfg, n, per_dev, seed=0)
@@ -1001,17 +1003,17 @@ def main() -> int:
         detail["cpu_baseline_cached"] = was_cached
         _progress(f"cpu baseline done ({cpu_dt:.1f}s, cached={was_cached})")
         if os.environ.get("BENCH_SKIP_SECONDARY") != "1":
-            _secondary_workloads(detail, mesh, n, on_tpu=True)
+            _secondary_workloads(detail, mesh, n)
         _round_provenance(detail)
         print(json.dumps({"metric": "terasort_secondary", "value": 0,
                           "unit": "", "detail": detail}))
         return _phase_exit(detail)
 
     # A/B the local-sort strategies on hardware (gather is latency-bound,
-    # multisort bandwidth-bound — see TeraSortConfig.sort_mode); the best
+    # the sorts bandwidth-bound — see TeraSortConfig.sort_mode); the best
     # one is the headline, both are recorded.
     env_mode = os.environ.get("BENCH_SORT_MODE", "")
-    modes = [env_mode] if env_mode else ["gather", "multisort"]
+    modes = [env_mode] if env_mode else ["gather", "colsort"]
     impl = os.environ.get("BENCH_IMPL", "auto")
     per_mode = {}
     per_mode_latency = {}
@@ -1132,7 +1134,7 @@ def main() -> int:
     if not light and os.environ.get("BENCH_SKIP_SECONDARY") != "1":
         # Secondary workloads (BASELINE.md configs #3/#4) enrich `detail`;
         # one that raised leaves an `*_error` key and fails the run.
-        _secondary_workloads(detail, mesh, n, on_tpu=True)
+        _secondary_workloads(detail, mesh, n)
 
     result = {
         "metric": "terasort_shuffle_throughput_per_chip",

@@ -5,11 +5,11 @@
 Runs BASELINE config #1 ("TeraSort 1 GB", 100-byte records) two ways, in
 this one process, on every chip ``jax.devices()`` returns:
 
-* **Job A — the fused step.** ``models.terasort.make_terasort_step`` (the
+* **Job A — the fused step.** ``models.terasort.run_terasort`` (the
   device plane's ``make_fused_step`` in range mode) over 1 GiB of rows in
-  one round, a few steps; the WHOLE output of the last step is compared
-  row for row with ``numpy_terasort`` on the same input and passed through
-  ``verify_terasort``.
+  one round, a warm and a timed step; the WHOLE output of the last step
+  is compared row for row with ``numpy_terasort`` on the same input and
+  passed through ``verify_terasort``.
 * **Job B — the same sort through the SPI** (deployment shape 2 of
   ``docs/DEPLOY.md``): a driver and four executor roles with the default
   ``TpuShuffleConf()``, a ``DAGEngine`` over the mesh, 16 map tasks writing
@@ -105,12 +105,13 @@ def peak_hbm(devices) -> list:
 # Job A: the fused step
 # ---------------------------------------------------------------------------
 
-def run_job_a(mesh, total_bytes: int, seed: int, compile_log: CompileLog,
-              steps: int = 3):
-    """1 GiB-class TeraSort round through ``make_terasort_step``.
-    Returns ``(record, failures)``. On a TPU mesh of more than one chip
-    the transport must be ``native`` and the compiled HLO must hold the
-    opcode; a CPU mesh (rehearsal, tests) rides the gather oracle."""
+def run_job_a(mesh, total_bytes: int, seed: int, compile_log: CompileLog):
+    """1 GiB-class TeraSort round through ``run_terasort`` (a warm and a
+    timed call of ``make_terasort_step``). Returns ``(record, failures)``.
+    On a TPU mesh of more than one chip the transport must be ``native``
+    and the compiled HLO must hold the opcode; a CPU mesh (rehearsal,
+    tests) rides the gather oracle. A receive overflow raises out of
+    ``run_terasort`` and fails the job at ``main``'s boundary."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -119,48 +120,48 @@ def run_job_a(mesh, total_bytes: int, seed: int, compile_log: CompileLog,
         generate_rows,
         make_terasort_step,
         numpy_terasort,
+        run_terasort,
         verify_terasort,
     )
-    from sparkrdma_tpu.parallel.exchange import resolve_impl
+    from sparkrdma_tpu.parallel import exchange as exchange_mod
 
     failures: list = []
     n = mesh.shape[AXIS]
     cfg = TeraSortConfig(rows_per_device=total_bytes // ROW_BYTES // n,
                          payload_words=24, out_factor=2)
     rows = generate_rows(cfg, n, seed=seed)
-    impl = resolve_impl(mesh, "auto", AXIS)
+    impl = exchange_mod.resolve_impl(mesh, "auto", AXIS)
     require_native = n > 1 and mesh.devices.flat[0].platform == "tpu"
     if require_native and impl != "native":
         failures.append(f"job_a: resolve_impl(mesh) = {impl!r}, not 'native'")
 
+    # the step run_terasort is about to call (the builder is memoized),
+    # compiled ahead so its HLO can be read; the call itself then finds
+    # the executable in the compile cache
     snap = compile_log.snapshot()
-    t0 = time.perf_counter()
-    rows_d = jax.block_until_ready(
-        jax.device_put(rows, NamedSharding(mesh, P(AXIS))))
-    h2d_s = time.perf_counter() - t0
     step = make_terasort_step(mesh, AXIS, cfg, impl="auto")
     t0 = time.perf_counter()
-    compiled = step.lower(rows_d).compile()
+    compiled = step.lower(jax.ShapeDtypeStruct(
+        rows.shape, rows.dtype,
+        sharding=NamedSharding(mesh, P(AXIS)))).compile()
     lower_compile_s = time.perf_counter() - t0
     ragged_ops = sum("ragged-all-to-all" in ln
                      for ln in compiled.as_text().splitlines())
     if require_native and not ragged_ops:
         failures.append("job_a: no ragged-all-to-all in the compiled HLO")
 
-    step_s = []
-    for _ in range(steps):
-        t0 = time.perf_counter()
-        out, counts, overflowed = jax.block_until_ready(compiled(rows_d))
-        step_s.append(round(time.perf_counter() - t0, 4))
+    before = exchange_mod.DATA_PLANE["exchanges"]
+    t0 = time.perf_counter()
+    out_np, counts_np, step_s = run_terasort(mesh, cfg, AXIS, impl="auto",
+                                             rows=rows)
+    run_s = time.perf_counter() - t0
+    dispatched = exchange_mod.DATA_PLANE["exchanges"] - before
     compile_facts = compile_log.since(snap)
-    if np.asarray(overflowed).any():
-        failures.append("job_a: receive overflow flagged")
+    if dispatched < 1:
+        failures.append("job_a: DATA_PLANE['exchanges'] did not advance")
 
     # the whole output of a step that ran at this size, against the plain
     # reference on the full input
-    t0 = time.perf_counter()
-    out_np, counts_np = np.asarray(out), np.asarray(counts)
-    d2h_s = time.perf_counter() - t0
     t0 = time.perf_counter()
     verified = False
     try:
@@ -185,14 +186,15 @@ def run_job_a(mesh, total_bytes: int, seed: int, compile_log: CompileLog,
         "hlo_ragged_all_to_all_ops": ragged_ops,
         "plan": {"plane": "device", "rows_per_round": cfg.rows_per_device,
                  "rounds": 1},
-        "steps": steps,
-        # every step of an n > 1 mesh dispatches the compiled exchange
-        "collective_exchanges": steps if n > 1 else 0,
-        "overflowed": bool(np.asarray(overflowed).any()),
+        # DATA_PLANE counts dispatched fused steps; on one device a step
+        # holds no collective
+        "fused_steps_dispatched": dispatched,
+        "collective_exchanges": dispatched if n > 1 else 0,
         "verified": verified,
-        "setup_facts": dict(compile_facts, h2d_s=round(h2d_s, 2),
+        "setup_facts": dict(compile_facts,
                             lower_compile_s=round(lower_compile_s, 2),
-                            step_wall_s=step_s, d2h_s=round(d2h_s, 2),
+                            run_terasort_wall_s=round(run_s, 2),
+                            step_wall_s=round(step_s, 4),
                             verify_s=round(verify_s, 2)),
         "peak_hbm_bytes": peak_hbm(mesh.devices.flat),
     }

@@ -1,7 +1,6 @@
 """Run the Pallas ring all-to-all COMPILED on this host's chips, once.
 
     python scripts/ring_chip_check.py            # on a multi-chip TPU host
-    python scripts/ring_chip_check.py --interpret  # sandbox: 4 virtual CPUs
 
 The ring (``ops/ring_exchange.py``) is off the default path and has only
 ever run interpreted; this checks that ``make_ring_all_to_all(mesh,
@@ -17,7 +16,9 @@ there is a block size the compiler will refuse; the largest here (3.3 MB
 per device) still compiled and matched on four v5e chips (2026-09-26).
 
 Prints one JSON line per shape and a last line with all of them; exits
-non-zero unless every shape ran and matched. Not part of chip_smoke.py.
+non-zero unless every shape ran and matched, so also without a multi-chip
+TPU (tests/test_ring_exchange.py covers the interpreted ring on the CPU).
+Not part of chip_smoke.py.
 """
 
 import json
@@ -34,10 +35,7 @@ SHAPES = [("aot_block_8x128", 8),
 
 _CHILD = r"""
 import functools, json, sys, time
-label, c, interpret = sys.argv[1], int(sys.argv[2]), sys.argv[3] == "1"
-if interpret:
-    from __graft_entry__ import _pin_virtual_cpu
-    _pin_virtual_cpu(4)
+label, c = sys.argv[1], int(sys.argv[2])
 import jax, numpy as np
 from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -46,10 +44,9 @@ from sparkrdma_tpu.ops.ring_exchange import make_ring_all_to_all
 devs = jax.devices()
 n = len(devs)
 rec = {"label": label, "block": [n, c, 128], "platform": devs[0].platform,
-       "device_kind": devs[0].device_kind, "devices": n,
-       "compiled": not interpret}
-if n < 2:
-    rec["status"] = "error: needs more than one device"
+       "device_kind": devs[0].device_kind, "devices": n}
+if devs[0].platform != "tpu" or n < 2:
+    rec["status"] = "error: needs a TPU host with more than one chip"
     print(json.dumps(rec)); sys.exit(1)
 mesh = Mesh(np.array(devs), ("shuffle",))
 sh = NamedSharding(mesh, P("shuffle"))
@@ -66,7 +63,7 @@ def reference(v):
 try:
     t0 = time.perf_counter()
     got = np.asarray(jax.block_until_ready(
-        make_ring_all_to_all(mesh, "shuffle", interpret=interpret)(xd)))
+        make_ring_all_to_all(mesh, "shuffle")(xd)))
     rec["first_call_s"] = round(time.perf_counter() - t0, 2)
     want = np.asarray(reference(xd))
     same = bool(np.array_equal(got, want)
@@ -80,14 +77,12 @@ sys.exit(0 if rec["status"] == "ok" else 1)
 
 
 def main() -> int:
-    interpret = "--interpret" in sys.argv[1:]
     env = dict(os.environ, PYTHONPATH=REPO)
     results = []
     for label, c in SHAPES:
         try:
             proc = subprocess.run(
-                [sys.executable, "-c", _CHILD, label, str(c),
-                 "1" if interpret else "0"],
+                [sys.executable, "-c", _CHILD, label, str(c)],
                 cwd=REPO, env=env, capture_output=True, text=True,
                 timeout=120)
             line = next((ln for ln in reversed(proc.stdout.splitlines())

@@ -127,7 +127,10 @@ def run_terasort(mesh: Mesh, cfg: TeraSortConfig, axis_name: str = "shuffle",
                  rows: Optional[np.ndarray] = None,
                  ) -> Tuple[np.ndarray, np.ndarray, float]:
     """Host driver: generate, run one jitted round, return
-    (sorted_rows_by_device, counts, step_seconds). Compile excluded."""
+    (sorted_rows_by_device, counts, step_seconds). Compile excluded.
+    Each dispatched step is tallied in ``exchange.DATA_PLANE``."""
+    from sparkrdma_tpu.parallel.exchange import record_exchange
+
     n = mesh.shape[axis_name]
     if rows is None:
         rows = generate_rows(cfg, n, seed)
@@ -136,9 +139,11 @@ def run_terasort(mesh: Mesh, cfg: TeraSortConfig, axis_name: str = "shuffle",
     rows_d = jax.device_put(rows, sharding)
     # compile + warm
     out, counts, overflowed = jax.block_until_ready(step(rows_d))
+    record_exchange(len(rows))
     t0 = time.perf_counter()
     out, counts, overflowed = jax.block_until_ready(step(rows_d))
     dt = time.perf_counter() - t0
+    record_exchange(len(rows))
     if np.asarray(overflowed).any():
         raise OverflowError(
             "receive buffer overflow: key skew exceeds out_factor headroom "
@@ -177,6 +182,8 @@ def run_terasort_streamed(mesh: Mesh, cfg: TeraSortConfig, rows: np.ndarray,
 
     Returns ``(per_device_sorted_rows: [D] list of u32[*, 1+P], rounds)``.
     """
+    from sparkrdma_tpu.parallel.exchange import record_exchange
+
     n = mesh.shape[axis_name]
     if len(rows) == 0:
         return [np.zeros((0, rows.shape[1]), rows.dtype)
@@ -220,6 +227,7 @@ def run_terasort_streamed(mesh: Mesh, cfg: TeraSortConfig, rows: np.ndarray,
             np.add.at(pads_for, dests, 1)
             chunk = np.concatenate([chunk, pad])
         result = pads_for, step(jax.device_put(chunk, sharding))
+        record_exchange(len(chunk) - tail_pad)
         times["stage_s"] += time.perf_counter() - t0
         return result
 

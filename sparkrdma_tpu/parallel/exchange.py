@@ -96,6 +96,15 @@ def _slot_fill(data: jnp.ndarray, starts: jnp.ndarray, counts: jnp.ndarray,
     return jnp.where(vmask, picked, 0), valid, dest_of_slot, within
 
 
+def _slot_rows(out_cap: int, n: int) -> int:
+    """Rows each (src, dst) pair owns in the fixed-slot transports: an
+    even split of the receive capacity. Below one row per device an even
+    split is zero, so a slot is the whole capacity instead — no pair can
+    land more than that, so a pair overflows only when the buffer does
+    and results match gather/native exactly (send side: < D*D rows)."""
+    return out_cap // n or out_cap
+
+
 def _pack_by_source(blocks: jnp.ndarray, recv_counts: jnp.ndarray,
                     base: jnp.ndarray) -> jnp.ndarray:
     """Compact per-source slot blocks ``[n, q, ...]`` into ``base``-shaped
@@ -165,17 +174,6 @@ def ragged_exchange_shard(data: jnp.ndarray, send_counts: jnp.ndarray,
     if output is None:
         output = jnp.zeros_like(data)
     # 2. data exchange over ICI.
-    if impl in ("dense", "ring", "ring_interpret") \
-            and output.shape[0] < mat.shape[0]:
-        # q = out_cap // D would be zero: no slot can carry even one
-        # row. Running a different transport under the asked-for name
-        # would mislabel what moved the bytes, so this is the caller's
-        # sizing error (static shapes make it a trace-time check)
-        raise ValueError(
-            f"exchange impl {impl!r} needs a receive capacity of at "
-            f"least one row per device ({output.shape[0]} rows < "
-            f"{mat.shape[0]} devices); grow the buffer or ask for "
-            "'gather'")
     pair_overflow = jnp.bool_(False)
     if impl == "native":
         received = lax.ragged_all_to_all(
@@ -200,7 +198,8 @@ def _dense_exchange(data: jnp.ndarray, mat: jnp.ndarray, my: jnp.ndarray,
                     output: jnp.ndarray, axis_name: str):
     """Fixed-slot ``lax.all_to_all`` exchange: every (src, dst) pair owns
     ``Q = out_capacity // D`` slot rows (any ``out_capacity % D``
-    remainder rows are unused headroom).
+    remainder rows are unused headroom; ``_slot_rows`` covers
+    ``out_capacity < D``).
 
     Exact (bit-identical to native/gather) whenever no pair exceeds its
     slot; a pair overflow is reported as an explicit bool (third return
@@ -212,8 +211,7 @@ def _dense_exchange(data: jnp.ndarray, mat: jnp.ndarray, my: jnp.ndarray,
     executable in CI.
     """
     n = mat.shape[0]
-    out_cap = output.shape[0]
-    q = out_cap // n
+    q = _slot_rows(output.shape[0], n)
     counts = mat[my]                      # what I send to each dest
     send, _, _, _ = _slot_fill(data, _exclusive_cumsum(counts), counts, n, q)
     got = lax.all_to_all(send.reshape((n, q) + data.shape[1:]), axis_name,
@@ -258,7 +256,7 @@ def _ring_exchange(data: jnp.ndarray, mat: jnp.ndarray, my: jnp.ndarray,
     (O(D/2) blocks per link) over switch routing. Bit-identical to
     dense/native/gather whenever no pair exceeds its slot."""
     n = mat.shape[0]
-    q = output.shape[0] // n
+    q = _slot_rows(output.shape[0], n)
     counts = mat[my]
     send, _, _, _ = _slot_fill(data, _exclusive_cumsum(counts), counts, n, q)
     got = _ring_move_blocks(send.reshape((n, q) + data.shape[1:]),

@@ -74,9 +74,9 @@ def test_smoke_jobs_at_toy_size_on_the_cpu_mesh():
 
     mesh = Mesh(np.array(jax.devices()[:8]), (chip_smoke.AXIS,))
     log = chip_smoke.CompileLog()
-    rec, failures = chip_smoke.run_job_a(mesh, 1 << 20, 3, log, steps=1)
+    rec, failures = chip_smoke.run_job_a(mesh, 1 << 20, 3, log)
     assert failures == [] and rec["verified"], (failures, rec)
-    assert rec["collective_exchanges"] == 1
+    assert rec["collective_exchanges"] >= 1  # read from DATA_PLANE
 
     rec, failures = chip_smoke.run_job_b(mesh, 2 << 20, 3, log)
     assert failures == [], failures

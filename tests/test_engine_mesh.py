@@ -103,6 +103,32 @@ def test_engine_job_rides_mesh(cluster, mesh, monkeypatch, rows_per_round):
         assert exchange_mod.DATA_PLANE["exchanges"] - before > 1
 
 
+@pytest.mark.parametrize("dataplane", ["auto", "device"])
+def test_tiny_stage_rides_the_dense_transport(cluster, mesh, dataplane):
+    """A stage with fewer rows per device than the mesh has devices (4
+    rows over 8) still runs on the transport it was planned with: dense
+    fixed slots hold such a buffer whole (``exchange._slot_rows``), no
+    other transport stands in, the stage neither fails nor degrades."""
+    import chip_smoke
+    from sparkrdma_tpu.utils.trace import Tracer
+
+    driver, execs = cluster
+    engine = DAGEngine(driver, execs, mesh=mesh, mesh_impl="dense",
+                       dataplane=dataplane)
+    engine.tracer = Tracer()
+    out = engine.run(chip_smoke.build_sort_job(2, D, 2, 0))
+    events = engine.tracer._events
+    names = [e["name"] for e in events]
+    selects = [e["args"] for e in events if e["name"] == "exchange.select"]
+    assert [(s["plane"], s["impl"]) for s in selects] == [("device", "dense")]
+    assert "exchange.degrade" not in names
+    keys = np.concatenate([r[0] for r in out])
+    want = np.sort(np.concatenate(
+        [chip_smoke.map_input(0, m, 2)[0] for m in range(2)]))
+    np.testing.assert_array_equal(keys, want)
+    assert all(r[2] for r in out)  # every partition arrived key-sorted
+
+
 def test_engine_mesh_survives_executor_loss(cluster, mesh, caplog):
     """Executor dies after the map stage: mesh staging surfaces the missing
     map as FetchFailed, the ordinary retry recomputes on survivors, the

@@ -268,6 +268,32 @@ def test_dense_pair_overflow_sets_flag_counts_stay_true(mesh):
     assert all(dc[i].sum() == 0 for i in range(1, D))
 
 
+@pytest.mark.parametrize("impl", ["dense", "ring_interpret"])
+def test_slot_transports_below_one_row_per_device(mesh, impl):
+    """Receive capacity < D (an even per-pair split would be zero rows):
+    the slot transports still run under their own name and agree with
+    gather bit for bit — rows, counts and flags — both when everything
+    fits (one pair carries the whole buffer) and when a receiver
+    overflows."""
+    capacity = 2
+    data = np.arange(1, D * capacity + 1, dtype=np.int32)
+    dest = np.full(D * capacity, -1, np.int32)
+    dest[6 * capacity: 7 * capacity] = 1      # device 6 -> 1: both rows
+    dest[0] = 4                               # device 0 -> 4: one row
+    got = _run_impl(mesh, data, dest, capacity, 1, impl)
+    want = _run_impl(mesh, data, dest, capacity, 1, "gather")
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    assert not got[2].any()
+    np.testing.assert_array_equal(got[0][1], data[12:14])
+    dest[3 * capacity] = 1                    # a third row for device 1
+    got = _run_impl(mesh, data, dest, capacity, 1, impl)
+    want = _run_impl(mesh, data, dest, capacity, 1, "gather")
+    np.testing.assert_array_equal(got[1], want[1])
+    np.testing.assert_array_equal(got[2], want[2])
+    assert got[2][1] and got[2].sum() == 1
+
+
 def test_capacity_overflow_sets_flag(mesh):
     """Aggregate receive past out_capacity sets the flag on native/gather
     paths too (here gather on CPU): every device sends its full buffer to
