@@ -23,14 +23,17 @@ this one process, on every chip ``jax.devices()`` returns:
 One process drives all the chips of the host: a chip belongs to one
 process at a time, so this script starts no other process.
 
-It exits 0 only if jax's backend is a TPU and every check passed, and its
-last line of standard output is then one JSON object that begins
-``{"ok": true, "device": {"platform": "tpu", ...`` and ends
-``"claim": null}``. Seconds in it are set-up facts of this run (compile
-included where it says so), never metrics. Without a TPU it prints no
-summary and exits non-zero. ``--rehearsal`` runs the same logic at toy
-size on four virtual CPU devices for the sandbox; its summary says
-``"rehearsal": true`` and has no ``ok`` key.
+It exits 0 only if jax's backend is a TPU and every check passed. It
+writes two lines to standard output. The first is the report, one JSON
+object with the facts of both jobs that ends ``"claim": null}``; seconds
+in it are set-up facts of this run (compile included where it says so),
+never metrics. The LAST line is the verdict, exactly
+``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}``
+with the device as jax reports it, and nothing else: the driver's check
+reads that line and refuses any other key. Without a TPU it prints
+neither line and exits non-zero. ``--rehearsal`` runs the same logic at
+toy size on four virtual CPU devices for the sandbox; its report says
+``"rehearsal": true`` and no line of it has an ``ok`` key.
 """
 
 from __future__ import annotations
@@ -425,6 +428,13 @@ def run_job_b(mesh, total_bytes: int, seed: int, compile_log: CompileLog,
 
 # ---------------------------------------------------------------------------
 
+def verdict(failures: list, devs) -> dict:
+    """The last line of standard output: these keys and no others."""
+    return {"ok": not failures,
+            "device": {"platform": devs[0].platform,
+                       "kind": devs[0].device_kind, "count": len(devs)}}
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=0)
@@ -476,11 +486,10 @@ def main(argv=None) -> int:
         print(f"chip_smoke.py: {name} done in "
               f"{time.perf_counter() - t0:.1f}s", file=sys.stderr)
 
-    summary = {"rehearsal": True} if args.rehearsal \
-        else {"ok": not failures}
-    summary.update({
-        "device": {"platform": devs[0].platform,
-                   "kind": devs[0].device_kind, "count": n},
+    last = verdict(failures, devs)
+    report = {"rehearsal": True} if args.rehearsal else {"ok": last["ok"]}
+    report.update({
+        "device": last["device"],
         "seed": args.seed,
         "exchange": (NO_EXCHANGE if n == 1 else
                      f"{(jobs['job_a'] or {}).get('exchange_impl')} "
@@ -497,7 +506,9 @@ def main(argv=None) -> int:
         "note": "seconds are set-up facts of this run, not metrics",
         "claim": None,
     })
-    print(json.dumps(summary))
+    print(json.dumps(report))
+    if not args.rehearsal:
+        print(json.dumps(last), flush=True)
     return 1 if failures else 0
 
 

@@ -32,6 +32,22 @@ def test_chip_entry_points_fail_without_a_tpu(script):
     assert "no TPU" in proc.stderr.strip().splitlines()[-1]
 
 
+def test_verdict_line_has_the_contract_keys_and_no_others():
+    """The driver's check reads the last line of standard output and
+    refuses any key beyond these; the jobs' facts go on the line before."""
+    import chip_smoke
+
+    devs = jax.devices()
+    for failures in ([], ["job_a: x"]):
+        line = json.loads(json.dumps(chip_smoke.verdict(failures, devs)))
+        assert line == {"ok": not failures,
+                        "device": {"platform": devs[0].platform,
+                                   "kind": devs[0].device_kind,
+                                   "count": len(devs)}}
+        assert isinstance(line["device"]["kind"], str)
+        assert type(line["device"]["count"]) is int
+
+
 def test_compile_cache_default_ignores_the_working_directory(tmp_path):
     code = ("from sparkrdma_tpu.utils.compile_cache import "
             "enable_compile_cache as e; import jax; "
