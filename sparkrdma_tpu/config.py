@@ -173,7 +173,9 @@ _KEYS = [
     _Key("wire_codec_key", "", "str",
          doc="Hex key material for wire_codec (aes-gcm: 16/24/32 bytes)."),
     _Key("trace_file", "", "str",
-         doc="Write a chrome://tracing JSON of shuffle spans here at stop."),
+         doc="Write a chrome://tracing JSON of shuffle spans here at stop. "
+             "While a jax.profiler session runs, a live tracer's spans "
+             "are in its profile too, under the same names."),
     _Key("collect_shuffle_reader_stats", False, "bool",
          doc="Collect per-remote fetch-latency histograms (ref collectShuffleReaderStats)."),
     _Key("fetch_time_bucket_size_ms", 300, "int", 1, 60000,

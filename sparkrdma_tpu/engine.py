@@ -1047,7 +1047,11 @@ class DAGEngine:
         with cell.lock:
             if cell.value is None:
                 try:
-                    cell.value = self._compute_mesh_partitions(handle)
+                    # the first touch only: a cache hit and the wait on
+                    # cell.lock above are no part of the shuffle
+                    with self.tracer.span("engine.mesh_reduce", "engine",
+                                          shuffle=sid):
+                        cell.value = self._compute_mesh_partitions(handle)
                 except BaseException:
                     # a failed compute must not wedge the cell: drop it so
                     # the retry (post-recovery) computes fresh
@@ -1153,8 +1157,11 @@ class DAGEngine:
             self.tracer.instant("exchange.degrade", "exchange",
                                 shuffle=sid, reason="executor_loss")
             raise
-        return split_by_partition(results, handle.num_partitions,
-                                  handle.row_payload_bytes)
+        with self.tracer.span("exchange.split", "exchange",
+                              partitions=handle.num_partitions,
+                              rows=sum(len(k) for k, _, _ in results)):
+            return split_by_partition(results, handle.num_partitions,
+                                      handle.row_payload_bytes)
 
     def _select_plan(self, handle, est_bytes: int, out_factor: int):
         """Ask the cost model which plane carries this stage; engine

@@ -354,14 +354,26 @@ def _local_sort(rows, keys, sort_mode: str, write_back_keys: bool):
     import jax
     import jax.numpy as jnp
 
+    def written_back(sorted_rows, sorted_keys):
+        # the key column already equals sorted_keys for valid rows;
+        # only padding rows (sentinel keys) need the overwrite
+        if write_back_keys:
+            return sorted_rows.at[:, 0].set(sorted_keys)
+        return sorted_rows
+
+    # the leaf scopes name the kernels in a device profile, whatever XLA
+    # calls its fusions: ``key_sort`` is the sort proper (the whole of
+    # multisort / colsort), ``row_gather`` the gather mode's row move
     if sort_mode == "multisort":
-        cols = tuple(rows[:, j] for j in range(rows.shape[1]))
-        # is_stable: all three modes must order duplicate keys
-        # identically (gather is stable via its iota tiebreak)
-        out = jax.lax.sort(keys + cols, num_keys=len(keys),
-                           is_stable=True)
-        sorted_keys = out[0]
-        sorted_rows = jnp.stack(out[len(keys):], axis=1)
+        with jax.named_scope("key_sort"):
+            cols = tuple(rows[:, j] for j in range(rows.shape[1]))
+            # is_stable: all three modes must order duplicate keys
+            # identically (gather is stable via its iota tiebreak)
+            out = jax.lax.sort(keys + cols, num_keys=len(keys),
+                               is_stable=True)
+            sorted_keys = out[0]
+            sorted_rows = written_back(
+                jnp.stack(out[len(keys):], axis=1), sorted_keys)
     elif sort_mode == "colsort":
         # identical keys in every lane + a STABLE sort => every column
         # receives the same permutation, so rows stay intact without a
@@ -369,30 +381,31 @@ def _local_sort(rows, keys, sort_mode: str, write_back_keys: bool):
         # as LSD radix passes: one stable per-lane sort per key word,
         # least significant first, remaining key words carried as
         # broadcast value operands so they ride the same permutation.
-        carried = tuple(jnp.broadcast_to(k[:, None], rows.shape)
-                        for k in keys)
-        sorted_rows = rows
-        for w in range(len(keys) - 1, -1, -1):
-            out = jax.lax.sort((carried[w], sorted_rows)
-                               + carried[:w] + carried[w + 1:],
-                               dimension=0, num_keys=1, is_stable=True)
-            sorted_rows = out[1]
-            rest = out[2:]
-            carried = rest[:w] + (out[0],) + rest[w:]
-        sorted_keys = carried[0][:, 0]
+        with jax.named_scope("key_sort"):
+            carried = tuple(jnp.broadcast_to(k[:, None], rows.shape)
+                            for k in keys)
+            sorted_rows = rows
+            for w in range(len(keys) - 1, -1, -1):
+                out = jax.lax.sort((carried[w], sorted_rows)
+                                   + carried[:w] + carried[w + 1:],
+                                   dimension=0, num_keys=1, is_stable=True)
+                sorted_rows = out[1]
+                rest = out[2:]
+                carried = rest[:w] + (out[0],) + rest[w:]
+            sorted_keys = carried[0][:, 0]
+            sorted_rows = written_back(sorted_rows, sorted_keys)
     else:
-        iota = jnp.arange(rows.shape[0], dtype=jnp.int32)
-        # iota as a FINAL KEY makes the order total: duplicate keys
-        # order by original position with no reliance on sort
-        # stability (a value-operand iota under an unstable sort
-        # could permute ties arbitrarily)
-        out = jax.lax.sort(keys + (iota,), num_keys=len(keys) + 1)
-        sorted_keys, order = out[0], out[-1]
-        sorted_rows = jnp.take(rows, order, axis=0)
-    if write_back_keys:
-        # the key column already equals sorted_keys for valid rows;
-        # only padding rows (sentinel keys) need the overwrite
-        sorted_rows = sorted_rows.at[:, 0].set(sorted_keys)
+        with jax.named_scope("key_sort"):
+            iota = jnp.arange(rows.shape[0], dtype=jnp.int32)
+            # iota as a FINAL KEY makes the order total: duplicate keys
+            # order by original position with no reliance on sort
+            # stability (a value-operand iota under an unstable sort
+            # could permute ties arbitrarily)
+            out = jax.lax.sort(keys + (iota,), num_keys=len(keys) + 1)
+            sorted_keys, order = out[0], out[-1]
+        with jax.named_scope("row_gather"):
+            sorted_rows = written_back(jnp.take(rows, order, axis=0),
+                                       sorted_keys)
     return sorted_rows, sorted_keys
 
 
@@ -459,6 +472,12 @@ def make_fused_step(mesh, axis_name: str, row_words: int, *,
     if partition == "range" and key_words != 1:
         raise ValueError("range partitioning is defined on single-word "
                          "u32 keys")
+    # The kernels' names below are op metadata, and jax leaves metadata
+    # out of the persistent compile cache's key by default: an executable
+    # cached by a build with other scopes, or none, would be loaded in
+    # place of this one and its device profile would carry that build's
+    # names. Process-wide, and a matter of cache keys only.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     n = mesh.shape[axis_name]
     impl = resolve_transport(mesh, impl, axis_name)
     spec = P(axis_name)
@@ -467,14 +486,26 @@ def make_fused_step(mesh, axis_name: str, row_words: int, *,
     splitters = uniform_splitters(n, jnp.uint32) if partition == "range" \
         else None
 
-    def sort_received(received, total):
-        """Key-sort received rows with pads (index >= total) masked to
-        the sentinel on every key word so they sort last; stable order
-        within equal keys is arrival (source-major) order."""
-        idx = jnp.arange(received.shape[0], dtype=jnp.int32)
-        keys = tuple(jnp.where(idx < total, k, sentinel)
-                     for k in _row_keys(received, key_words))
-        return _local_sort(received, keys, sort_mode, write_back)[0]
+    def exchange_and_sort(grouped, counts):
+        """The multi-device tail both partition modes share: the
+        destination-grouped rows cross the mesh (``fused.exchange``),
+        then the received rows are key-sorted (``fused.receive_sort``)
+        with pads (index >= total) masked to the sentinel on every key
+        word so they sort last; stable order within equal keys is
+        arrival (source-major) order."""
+        with jax.named_scope("fused.exchange"):
+            output = jnp.zeros((grouped.shape[0] * out_factor, row_words),
+                               dtype=grouped.dtype)
+            received, recv_counts, _, overflowed = ragged_exchange_shard(
+                grouped, counts, axis_name, output=output, impl=impl)
+        with jax.named_scope("fused.receive_sort"):
+            total = recv_counts.sum()
+            idx = jnp.arange(received.shape[0], dtype=jnp.int32)
+            keys = tuple(jnp.where(idx < total, k, sentinel)
+                         for k in _row_keys(received, key_words))
+            sorted_rows = _local_sort(received, keys, sort_mode,
+                                      write_back)[0]
+        return sorted_rows, recv_counts[None], overflowed[None]
 
     # pallas interpret-mode outputs confuse the vma checker when mixed
     # with collectives; disable it ONLY for the ring transports (same
@@ -493,30 +524,28 @@ def make_fused_step(mesh, axis_name: str, row_words: int, *,
             keys = (rows[:, 0],)
             if n == 1:
                 # single-device: no exchange, one sort is the whole job
-                sorted_rows, _ = _local_sort(rows, keys, sort_mode,
-                                             write_back)
+                with jax.named_scope("fused.receive_sort"):
+                    sorted_rows, _ = _local_sort(rows, keys, sort_mode,
+                                                 write_back)
                 counts = jnp.array([[rows.shape[0]]], dtype=jnp.int32)
                 return sorted_rows, counts, jnp.zeros((1,), bool)
 
-            # Local sort by KEY once: range partition is monotonic in
-            # key, so key-sorted rows are destination-grouped for free —
-            # this replaces the separate argsort-by-destination + gather
-            # entirely.
-            grouped, sorted_keys = _local_sort(rows, keys, sort_mode,
-                                               write_back)
-            # per-destination counts: D-1 binary searches on sorted keys
-            bounds = jnp.searchsorted(sorted_keys, splitters, side="left")
-            bounds = jnp.concatenate([
-                jnp.zeros(1, bounds.dtype), bounds,
-                jnp.array([rows.shape[0]], bounds.dtype)])
-            counts = jnp.diff(bounds).astype(jnp.int32)
-
-            output = jnp.zeros((rows.shape[0] * out_factor, row_words),
-                               dtype=rows.dtype)
-            received, recv_counts, _, overflowed = ragged_exchange_shard(
-                grouped, counts, axis_name, output=output, impl=impl)
-            sorted_rows = sort_received(received, recv_counts.sum())
-            return sorted_rows, recv_counts[None], overflowed[None]
+            with jax.named_scope("fused.partition"):
+                # Local sort by KEY once: range partition is monotonic
+                # in key, so key-sorted rows are destination-grouped for
+                # free — this replaces the separate argsort-by-
+                # destination + gather entirely.
+                grouped, sorted_keys = _local_sort(rows, keys, sort_mode,
+                                                   write_back)
+                # per-destination counts: D-1 binary searches on sorted
+                # keys
+                bounds = jnp.searchsorted(sorted_keys, splitters,
+                                          side="left")
+                bounds = jnp.concatenate([
+                    jnp.zeros(1, bounds.dtype), bounds,
+                    jnp.array([rows.shape[0]], bounds.dtype)])
+                counts = jnp.diff(bounds).astype(jnp.int32)
+            return exchange_and_sort(grouped, counts)
 
         return step
 
@@ -526,19 +555,16 @@ def make_fused_step(mesh, axis_name: str, row_words: int, *,
         dest = dest.reshape(-1)
         if n == 1:
             valid = dest >= 0
-            idx_keys = tuple(jnp.where(valid, k, sentinel)
-                             for k in _row_keys(rows, key_words))
-            sorted_rows, _ = _local_sort(rows, idx_keys, sort_mode,
-                                         write_back)
+            with jax.named_scope("fused.receive_sort"):
+                idx_keys = tuple(jnp.where(valid, k, sentinel)
+                                 for k in _row_keys(rows, key_words))
+                sorted_rows, _ = _local_sort(rows, idx_keys, sort_mode,
+                                             write_back)
             counts = jnp.sum(valid).astype(jnp.int32).reshape(1, 1)
             return sorted_rows, counts, jnp.zeros((1,), bool)
-        grouped, counts = group_by_destination(rows, dest, n)
-        output = jnp.zeros((rows.shape[0] * out_factor, row_words),
-                           dtype=rows.dtype)
-        received, recv_counts, _, overflowed = ragged_exchange_shard(
-            grouped, counts, axis_name, output=output, impl=impl)
-        sorted_rows = sort_received(received, recv_counts.sum())
-        return sorted_rows, recv_counts[None], overflowed[None]
+        with jax.named_scope("fused.partition"):
+            grouped, counts = group_by_destination(rows, dest, n)
+        return exchange_and_sort(grouped, counts)
 
     return step
 
@@ -615,11 +641,24 @@ def run_fused_exchange_rounds(mesh, axis_name: str, blocks,
     sharding = NamedSharding(mesh, P(axis_name))
     runs: List[list] = [[] for _ in range(n)]
 
+    def staged(r: int):
+        """Round ``r``'s block from the caller's stream, or None at its
+        end. The stream's work is the round's staging (spills -> u32
+        rows and destinations); its last pull, which finds the end,
+        records ``rows=0``."""
+        with tracer.span("exchange.stage", "exchange", round=r) as args:
+            block = next(blocks, None)
+            args["rows"] = len(block[0]) if block is not None else 0
+            args["bytes"] = (block[0].nbytes + block[1].nbytes
+                             if block is not None else 0)
+        return block
+
     def dispatch(r: int, chunk: np.ndarray, dchunk: np.ndarray):
         """Stage one round (pad to the static shape) and launch its
         collective; jax dispatch is async — no blocking here."""
         with tracer.span("exchange.round", "exchange", round=r,
-                         rows=len(chunk)):
+                         rows=len(chunk),
+                         bytes=per_round * (row_words + 1) * 4):
             rows_p = np.zeros((per_round, row_words), np.uint32)
             rows_p[:len(chunk)] = chunk
             dest_p = np.full(per_round, -1, np.int32)
@@ -627,57 +666,71 @@ def run_fused_exchange_rounds(mesh, axis_name: str, blocks,
             out = step(stage_to_device(rows_p, sharding),
                        stage_to_device(dest_p, sharding))
         record_exchange(len(chunk))
-        return out
+        return r, out
 
-    def collect(results) -> None:
-        # np.asarray blocks on the device step (exchange + sort)
-        out, counts, overflowed = results
-        if np.asarray(overflowed).any():
-            raise OverflowError(
-                "fused exchange receive overflow: skew exceeds the "
-                "out_factor headroom for this round size — the engine "
-                "degrades the stage to the host dataplane")
-        out = np.asarray(out).reshape(n, -1, row_words)
-        counts = np.asarray(counts)
+    def collect(launched) -> None:
+        r, results = launched
+        with tracer.span("exchange.collect", "exchange", round=r) as args:
+            got = _pull_runs(results, n, row_words)
+            args["rows"] = sum(len(g) for g in got)
+            args["bytes"] = sum(int(a.nbytes) for a in results)
         for d in range(n):
-            # .copy(): a view would pin the padded round buffer across
-            # all rounds
-            runs[d].append(out[d][:int(counts[d].sum())].copy())
+            runs[d].append(got[d])
 
+    blocks = iter(blocks)
     rounds = 0
-    if pipeline_rounds:
-        in_flight = None
-        for chunk, dchunk in blocks:
-            nxt = dispatch(rounds, chunk, dchunk)
+    in_flight = None
+    while (block := staged(rounds)) is not None:
+        nxt = dispatch(rounds, *block)
+        if not pipeline_rounds:
+            collect(nxt)
+        else:
             if in_flight is not None:
                 tracer.instant("exchange.overlap", "exchange",
                                dispatched=rounds, collecting=rounds - 1)
                 collect(in_flight)
             in_flight = nxt
-            rounds += 1
-        if in_flight is not None:
-            collect(in_flight)
-    else:
-        for chunk, dchunk in blocks:
-            collect(dispatch(rounds, chunk, dchunk))
-            rounds += 1
+        rounds += 1
+    if in_flight is not None:
+        collect(in_flight)
 
     if rounds == 0:
         return [np.zeros((0, row_words), np.uint32) for _ in range(n)], 0
-    if rounds == 1:
-        return [runs[d][0] for d in range(n)], 1
+    with tracer.span("exchange.merge", "exchange", runs=rounds,
+                     rows=sum(len(r) for rs in runs for r in rs)):
+        merged = [_merge_device_runs(rs, row_words, key_words)
+                  for rs in runs]
+    return merged, rounds
 
+
+def _pull_runs(results, n: int, row_words: int) -> List[np.ndarray]:
+    """Wait for one launched step and bring each device's received rows
+    to the host: ``np.asarray`` blocks on the device (exchange + sort)
+    and pulls the whole ``out_factor``-padded receive buffer."""
+    out, counts, overflowed = results
+    if np.asarray(overflowed).any():
+        raise OverflowError(
+            "fused exchange receive overflow: skew exceeds the "
+            "out_factor headroom for this round size — the engine "
+            "degrades the stage to the host dataplane")
+    out = np.asarray(out).reshape(n, -1, row_words)
+    counts = np.asarray(counts)
+    # .copy(): a view would pin the padded round buffer across all rounds
+    return [out[d][:int(counts[d].sum())].copy() for d in range(n)]
+
+
+def _merge_device_runs(device_runs: list, row_words: int,
+                       key_words: int) -> np.ndarray:
+    """One device's key-sorted runs (one per round) as one sorted run,
+    via the tournament merge; a single run passes through."""
+    if not device_runs:
+        return np.zeros((0, row_words), np.uint32)
+    if len(device_runs) == 1:
+        return device_runs[0]
     from sparkrdma_tpu.shuffle.external import merge_runs
 
-    merged = []
-    for d in range(n):
-        if not runs[d]:
-            merged.append(np.zeros((0, row_words), np.uint32))
-            continue
-        _, out = merge_runs([(_run_keys(r, key_words), r)
-                             for r in runs[d]])
-        merged.append(out)
-    return merged, rounds
+    return merge_runs([(_run_keys(r, key_words), r)
+                       for r in device_runs])[1]
 
 
 # ---------------------------------------------------------------------------
@@ -766,15 +819,14 @@ def run_hierarchical_exchange(mesh, axis_name: str,
             order = np.argsort(_run_keys(sub, key_words), kind="stable")
             runs[d].append(np.ascontiguousarray(sub[order]))
 
-    def collect(s: int, lo: int, ns: int, result) -> None:
-        out, counts, overflowed = result
-        if np.asarray(overflowed).any():
-            raise OverflowError(
-                f"hierarchical exchange receive overflow in slice {s}")
-        out = np.asarray(out).reshape(ns, -1, row_words)
-        counts = np.asarray(counts)
+    def collect(s: int, lo: int, ns: int, r: int, result) -> None:
+        with tracer.span("exchange.collect", "exchange", round=r,
+                         slice=s) as args:
+            got = _pull_runs(result, ns, row_words)
+            args["rows"] = sum(len(g) for g in got)
+            args["bytes"] = sum(int(a.nbytes) for a in result)
         for i in range(ns):
-            runs[lo + i].append(out[i][:int(counts[i].sum())].copy())
+            runs[lo + i].append(got[i])
 
     def run_phase(per_slice: Dict[int, Tuple[np.ndarray, np.ndarray]],
                   phase: str, dcn_moves=None) -> None:
@@ -822,7 +874,8 @@ def run_hierarchical_exchange(mesh, axis_name: str,
                     continue
                 with tracer.span("exchange.round", "exchange",
                                  round=rounds, phase=phase, slice=s,
-                                 rows=len(chunk)):
+                                 rows=len(chunk),
+                                 bytes=per_round * (row_words + 1) * 4):
                     rows_p = np.zeros((per_round, row_words), np.uint32)
                     rows_p[:len(chunk)] = chunk
                     dest_p = np.full(per_round, -1, np.int32)
@@ -840,7 +893,7 @@ def run_hierarchical_exchange(mesh, axis_name: str,
             charge()
             for s, lo, ns, chunk, dchunk, out in batch:
                 try:
-                    collect(s, lo, ns, out)
+                    collect(s, lo, ns, rounds, out)
                 except OverflowError:
                     # degrade ONLY this slice's residue to host serving;
                     # the other slices stay on ICI
@@ -886,16 +939,9 @@ def run_hierarchical_exchange(mesh, axis_name: str,
                        cross_slice_bytes=inter_rows * row_bytes,
                        degraded_slices=sorted(degraded))
 
-    from sparkrdma_tpu.shuffle.external import merge_runs
-
-    merged = []
-    for d in range(n):
-        if not runs[d]:
-            merged.append(np.zeros((0, row_words), np.uint32))
-        elif len(runs[d]) == 1:
-            merged.append(runs[d][0])
-        else:
-            _, out = merge_runs([(_run_keys(r, key_words), r)
-                                 for r in runs[d]])
-            merged.append(out)
+    with tracer.span("exchange.merge", "exchange",
+                     runs=max(len(rs) for rs in runs),
+                     rows=sum(len(r) for rs in runs for r in rs)):
+        merged = [_merge_device_runs(rs, row_words, key_words)
+                  for rs in runs]
     return merged, rounds

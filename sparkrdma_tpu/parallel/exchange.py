@@ -318,7 +318,8 @@ def group_by_destination(data: jnp.ndarray, dest: jnp.ndarray,
     dest = jnp.where((dest < 0) | (dest >= num_partitions),
                      num_partitions, dest.astype(jnp.int32))
     order = jnp.argsort(dest, stable=True)
-    grouped = jnp.take(data, order, axis=0)
+    with jax.named_scope("row_gather"):   # a device profile's kernel name
+        grouped = jnp.take(data, order, axis=0)
     counts = jnp.bincount(dest, length=num_partitions + 1)[:num_partitions]
     return grouped, counts.astype(jnp.int32)
 

@@ -24,6 +24,7 @@ import numpy as np
 from sparkrdma_tpu.parallel import device_plane as device_plane_mod
 from sparkrdma_tpu.shuffle.fetcher import ReadMetrics
 from sparkrdma_tpu.shuffle.manager import ShuffleHandle, TpuShuffleManager
+from sparkrdma_tpu.utils import trace as trace_mod
 
 
 def device_row_words(payload_bytes: int) -> int:
@@ -163,6 +164,7 @@ def run_mesh_reduce_fused(managers: Sequence[TpuShuffleManager],
         run_fused_exchange_rounds,
     )
 
+    tracer = tracer if tracer is not None else trace_mod.NULL
     n_dev = mesh.shape[axis_name]
     partitioner = handle.partitioner.build(handle.num_partitions)
     pw = device_row_words(handle.row_payload_bytes)
@@ -200,21 +202,19 @@ def run_mesh_reduce_fused(managers: Sequence[TpuShuffleManager],
             key_words=2, out_factor=out_factor, impl=impl, tracer=tracer)
     else:
         # one shot: the cost model only picks this when the stage fits
-        # the budget, so whole-stage staging is within contract
-        keys, payload = _stage_all(managers, handle, expect_maps)
-        rows = _rows_to_u32(keys, payload)
-        dest = (np.asarray(partitioner(keys), dtype=np.int32) % n_dev)
+        # the budget, so whole-stage staging is within contract. The
+        # staging is here; the driver's own exchange.stage spans only
+        # slice what this one made
+        with tracer.span("exchange.stage", "exchange", round=0) as args:
+            keys, payload = _stage_all(managers, handle, expect_maps)
+            rows = _rows_to_u32(keys, payload)
+            dest = (np.asarray(partitioner(keys), dtype=np.int32) % n_dev)
+            args["rows"] = len(rows)
+            args["bytes"] = rows.nbytes + dest.nbytes
         per_device, _rounds = run_fused_exchange(
             mesh, axis_name, rows, dest, key_words=2,
             out_factor=out_factor, impl=impl, tracer=tracer)
-
-    # unpack: rows arrive key-sorted per device already
-    results = []
-    for d in range(n_dev):
-        k, p = _u32_to_rows(per_device[d], handle.row_payload_bytes)
-        parts = np.asarray(partitioner(k), dtype=np.int64)
-        results.append((k, p, parts))
-    return results
+    return _unpack_devices(per_device, handle, partitioner, tracer)
 
 
 def run_mesh_reduce_hier(managers: Sequence[TpuShuffleManager],
@@ -254,50 +254,63 @@ def run_mesh_reduce_hier(managers: Sequence[TpuShuffleManager],
     )
     from sparkrdma_tpu.shuffle.planner import slice_aligned_partition_map
 
+    tracer = tracer if tracer is not None else trace_mod.NULL
     n_dev = mesh.shape[axis_name]
     partitioner = handle.partitioner.build(handle.num_partitions)
     row_bytes = 4 * device_row_words(handle.row_payload_bytes)
     num_mgrs = max(1, len(managers))
 
-    all_rows, all_parts, all_home = [], [], []
-    part_bytes = np.zeros((topology.num_slices, handle.num_partitions),
-                          dtype=np.int64)
-    delivered: set = set()
-    for i, k, p in _iter_committed_batches_indexed(managers, handle,
-                                                   delivered):
-        home = topology.slice_of_slot(i, num_mgrs)
-        parts = np.asarray(partitioner(k), dtype=np.int64)
-        np.add.at(part_bytes[home], parts, row_bytes)
-        all_rows.append(_rows_to_u32(k, p))
-        all_parts.append(parts)
-        all_home.append(np.full(len(k), home, dtype=np.int32))
-    _check_staging_complete(delivered, expect_maps, handle.shuffle_id)
-    if not all_rows:
-        rows = np.zeros((0, device_row_words(handle.row_payload_bytes)),
-                        np.uint32)
-        parts = np.zeros(0, np.int64)
-        home = np.zeros(0, np.int32)
-    else:
-        rows = np.concatenate(all_rows)
-        parts = np.concatenate(all_parts)
-        home = np.concatenate(all_home)
+    with tracer.span("exchange.stage", "exchange", round=0) as args:
+        all_rows, all_parts, all_home = [], [], []
+        part_bytes = np.zeros((topology.num_slices, handle.num_partitions),
+                              dtype=np.int64)
+        delivered: set = set()
+        for i, k, p in _iter_committed_batches_indexed(managers, handle,
+                                                       delivered):
+            home = topology.slice_of_slot(i, num_mgrs)
+            parts = np.asarray(partitioner(k), dtype=np.int64)
+            np.add.at(part_bytes[home], parts, row_bytes)
+            all_rows.append(_rows_to_u32(k, p))
+            all_parts.append(parts)
+            all_home.append(np.full(len(k), home, dtype=np.int32))
+        _check_staging_complete(delivered, expect_maps, handle.shuffle_id)
+        if not all_rows:
+            rows = np.zeros(
+                (0, device_row_words(handle.row_payload_bytes)), np.uint32)
+            parts = np.zeros(0, np.int64)
+            home = np.zeros(0, np.int32)
+        else:
+            rows = np.concatenate(all_rows)
+            parts = np.concatenate(all_parts)
+            home = np.concatenate(all_home)
 
-    if partition_map is None:
-        partition_map = slice_aligned_partition_map(part_bytes, topology,
-                                                    n_dev)
-    dest = partition_map[parts].astype(np.int32) if len(parts) else \
-        np.zeros(0, np.int32)
+        if partition_map is None:
+            partition_map = slice_aligned_partition_map(part_bytes,
+                                                        topology, n_dev)
+        dest = partition_map[parts].astype(np.int32) if len(parts) else \
+            np.zeros(0, np.int32)
+        args["rows"] = len(rows)
+        args["bytes"] = rows.nbytes + dest.nbytes
 
     per_device, _rounds = run_hierarchical_exchange(
         mesh, axis_name, topology, rows, dest, home, key_words=2,
         rows_per_round=rows_per_round, out_factor=out_factor, impl=impl,
         tracer=tracer)
 
-    results = []
-    for d in range(n_dev):
-        k, p = _u32_to_rows(per_device[d], handle.row_payload_bytes)
-        pts = np.asarray(partitioner(k), dtype=np.int64)
-        results.append((k, p, pts))
+    return _unpack_devices(per_device, handle, partitioner, tracer)
+
+
+def _unpack_devices(per_device, handle, partitioner, tracer):
+    """Per-device sorted u32 rows -> ``(keys, payload, partition ids)``
+    per device: the unpacking and the partitioner's second pass over
+    every key (rows arrive key-sorted per device already)."""
+    with tracer.span("exchange.unpack", "exchange",
+                     rows=sum(len(r) for r in per_device)):
+        results = []
+        for rows in per_device:
+            k, p = _u32_to_rows(rows, handle.row_payload_bytes)
+            results.append(
+                (k, p, np.asarray(partitioner(k), dtype=np.int64)))
     return results
 
 

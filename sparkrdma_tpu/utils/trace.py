@@ -9,19 +9,36 @@ staging, exchange rounds — each a timed event with thread identity.
 
 Enabled by the ``trace_file`` config key; zero overhead when off (the
 module-level NULL tracer's span() is a no-op context manager).
+
+One call site, two clocks: a live tracer's ``span`` also enters a
+``jax.profiler.TraceAnnotation`` of the same name, so whenever a profiler
+session is running (``device_profile``, or anyone's ``jax.profiler``
+trace) the program's spans lie in the profile beside the device's ops.
+With no session an annotation is one flag read. Every ``Tracer`` of a
+process counts from one origin, so the dumps of a driver's and its
+executors' tracers overlay in Perfetto.
 """
 
 from __future__ import annotations
 
 import json
-import logging
 import os
 import threading
 import time
 from contextlib import contextmanager
-from typing import List, Optional
+from typing import List
 
-log = logging.getLogger(__name__)
+# the one origin of every Tracer's clock in this process
+_ORIGIN = time.perf_counter()
+
+
+def _annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)``. Imported here, so only a
+    live tracer's first span pays for importing jax and the no-op tracer
+    never does."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name)
 
 
 class Tracer:
@@ -30,22 +47,26 @@ class Tracer:
     def __init__(self, process_name: str = "sparkrdma_tpu"):
         self._events: List[dict] = []
         self._lock = threading.Lock()
-        self._t0 = time.perf_counter()
         self.process_name = process_name
         self.enabled = True
         self.dropped = 0
 
     def _now_us(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e6
+        return (time.perf_counter() - _ORIGIN) * 1e6
 
     @contextmanager
     def span(self, name: str, category: str = "shuffle", **args):
+        """Time the block as one chrome event and, under a running
+        profiler session, as a ``TraceAnnotation`` of the same name.
+        Yields the event's ``args`` dict: a block that learns a size only
+        at its end (the rows a ``next()`` returned) fills it in there."""
         if not self.enabled:
-            yield
+            yield args
             return
         start = self._now_us()
         try:
-            yield
+            with _annotation(name):
+                yield args
         finally:
             end = self._now_us()
             with self._lock:
@@ -70,7 +91,9 @@ class Tracer:
                       end_us: float, **args) -> None:
         """Record a span with explicit trace-clock endpoints (from
         ``now_us``). Used by the pipelined fetcher to emit separate
-        issue→wire→complete phases of one asynchronous fetch."""
+        issue→wire→complete phases of one asynchronous fetch. Tracer-only:
+        an annotation cannot be entered after the fact, so these spans
+        are in the chrome dump and in no profile."""
         if not self.enabled:
             return
         with self._lock:
@@ -130,30 +153,20 @@ class Tracer:
 
 @contextmanager
 def device_profile(log_dir: str):
-    """Capture an XLA device profile (TensorBoard/Perfetto format) around
-    a block: compiled-step timelines, HBM transfers and fusion names the
-    host-side span tracer cannot see. The TPU-native upgrade of the
-    reference's wall-clock logging — pair with ``Tracer`` spans to line
-    host orchestration up against device execution.
-
-    No-ops (with a warning) when jax.profiler is unavailable so callers
-    can leave it on unconditionally in tooling.
+    """The operator's one call for "a device profile with the program's
+    spans in it": an XLA profile (``.xplane.pb`` under ``log_dir``, for
+    TensorBoard / Perfetto) of the block, holding the device's ops and,
+    on the host plane, every span a live ``Tracer`` records meanwhile.
+    The Python tracer is off: it would slow the host work being
+    measured. A profiler that cannot start or stop raises: a run that
+    asked for a profile must not pass for one that has it.
     """
-    try:
-        import jax
+    import jax
 
-        jax.profiler.start_trace(log_dir)
-    except Exception as e:  # noqa: BLE001 — profiling must never break a job
-        log.warning("device profile unavailable: %s", e)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    with jax.profiler.trace(log_dir, profiler_options=options):
         yield
-        return
-    try:
-        yield
-    finally:
-        try:
-            jax.profiler.stop_trace()
-        except Exception:  # noqa: BLE001
-            log.warning("device profile stop failed", exc_info=True)
 
 
 class _NullTracer(Tracer):

@@ -20,9 +20,15 @@ from __future__ import annotations
 # explicit-boundary ``complete_span`` emissions of the async fetcher.
 SPANS = frozenset({
     "engine.dist_reduce",
+    "engine.mesh_reduce",
     "engine.stage",
     "engine.task",
+    "exchange.collect",
+    "exchange.merge",
     "exchange.round",
+    "exchange.split",
+    "exchange.stage",
+    "exchange.unpack",
     "fetch.blocks",
     "fetch.complete",
     "fetch.driver_table",

@@ -436,3 +436,137 @@ def test_bench_round_json_provenance():
     assert "_bench_dense_guard" in main_src
     sec_src = inspect.getsource(bench_mod._secondary_workloads)
     assert "_bench_fused_exchange" in sec_src
+
+
+# -- the result stage's spans, on the Tracer's clock and the profiler's ---
+
+D4 = 4
+STAGE_SPANS = ("exchange.stage", "exchange.round", "exchange.collect")
+ONCE_SPANS = ("exchange.merge", "exchange.unpack", "exchange.split")
+
+
+@pytest.fixture(scope="module")
+def mesh4():
+    return Mesh(np.array(jax.devices()[:D4]), ("shuffle",))
+
+
+def _traced_job(cluster, mesh4, tracer, budget):
+    """One device-plane shuffle (4 partitions, 4 maps x 400 rows) with
+    ``tracer`` as the engine's; returns its X events and row count."""
+    driver, execs = cluster
+    P, maps, rows = 4, 4, 400
+    stage, reduce_fn = _job(P, maps, rows, 1000, 8100 + SEED)
+    engine = DAGEngine(driver, execs, mesh=mesh4, dataplane="device",
+                       device_hbm_budget=budget)
+    if tracer is not None:
+        engine.tracer = tracer
+    engine.run(ResultStage(P, reduce_fn, parents=[stage]))
+    return maps * rows
+
+
+# 12-byte rows, out_factor 2 on 4 devices with 4 partitions: 64 rows a
+# device a round -> 1,600 rows in 7 rounds; the default budget: one shot
+MULTI_ROUND_BUDGET = 12 * (2 + 2 * 2) * 64
+
+
+@pytest.mark.parametrize("budget, multi_round", [
+    (MULTI_ROUND_BUDGET, True), (64 << 20, False)])
+def test_result_stage_spans_tile_the_mesh_reduce(cluster, mesh4, budget,
+                                                 multi_round):
+    tracer = Tracer()
+    records = _traced_job(cluster, mesh4, tracer, budget)
+    spans = [e for e in tracer._events if e["ph"] == "X"]
+    by_name = {}
+    for e in spans:
+        by_name.setdefault(e["name"], []).append(e)
+    (reduce_span,) = by_name["engine.mesh_reduce"]
+    assert set(reduce_span["args"]) == {"shuffle"}
+    lo, hi = reduce_span["ts"], reduce_span["ts"] + reduce_span["dur"]
+    for name in STAGE_SPANS + ONCE_SPANS:
+        assert by_name.get(name), f"no {name} span"
+        for e in by_name[name]:
+            assert e["tid"] == reduce_span["tid"]
+            assert lo <= e["ts"] and e["ts"] + e["dur"] <= hi, name
+    for name in ONCE_SPANS:
+        assert len(by_name[name]) == 1, name
+    rounds = len(by_name["exchange.round"])
+    merge, unpack, split = (by_name[n][0]["args"] for n in ONCE_SPANS)
+    assert merge == {"runs": rounds, "rows": records}
+    assert unpack == {"rows": records}
+    assert split == {"partitions": 4, "rows": records}
+    assert len(by_name["exchange.collect"]) == rounds
+    for name in ("exchange.round", "exchange.collect"):
+        assert sum(e["args"]["rows"] for e in by_name[name]) == records
+        assert sorted(e["args"]["round"] for e in by_name[name]) == list(
+            range(rounds))
+    for e in by_name["exchange.collect"]:
+        # the whole padded receive buffer comes back, not the useful rows
+        assert e["args"]["bytes"] > e["args"]["rows"] * 12
+    staged = [e["args"] for e in by_name["exchange.stage"]]
+    assert all(set(a) == {"round", "rows", "bytes"} for a in staged)
+    if multi_round:
+        assert rounds == -(-records // (64 * D4)) and rounds >= 3
+        # one span a round, and the stream's last pull that finds its end
+        assert [a["rows"] > 0 for a in staged] == [True] * rounds + [False]
+        assert sum(a["rows"] for a in staged) == records
+    else:
+        assert rounds == 1 and merge["runs"] == 1
+        # the real staging, then the driver's slicing of what it made
+        assert staged[0]["rows"] == records
+
+
+def test_noop_tracer_enters_no_annotation(cluster, mesh4, monkeypatch):
+    from sparkrdma_tpu.utils import trace as trace_mod
+
+    entered = []
+    real = trace_mod._annotation
+    monkeypatch.setattr(trace_mod, "_annotation",
+                        lambda name: entered.append(name) or real(name))
+    _traced_job(cluster, mesh4, None, MULTI_ROUND_BUDGET)
+    assert entered == [] and trace_mod.NULL._events == []
+    # the counter does count: the same job with a live tracer
+    tracer = Tracer()
+    _traced_job(cluster, mesh4, tracer, MULTI_ROUND_BUDGET)
+    assert len(entered) == sum(e["ph"] == "X" for e in tracer._events) > 0
+
+
+def test_live_tracer_spans_are_in_the_profile(cluster, mesh4, tmp_path):
+    """Every span of a live tracer is a TraceAnnotation of the same name:
+    a running profiler session holds as many host-plane events of each
+    name as the Tracer recorded."""
+    from collections import Counter
+
+    from benchmark import xplane
+
+    tracer = Tracer()
+    with jax.profiler.trace(str(tmp_path)):
+        _traced_job(cluster, mesh4, tracer, MULTI_ROUND_BUDGET)
+    recorded = Counter(e["name"] for e in tracer._events if e["ph"] == "X")
+    assert {"engine.mesh_reduce", "engine.stage", "engine.task",
+            *STAGE_SPANS, *ONCE_SPANS} <= set(recorded)
+    host = xplane.load_xplane(xplane.find_xplane(str(tmp_path)))[
+        "planes"][xplane.HOST_PLANE]
+    profiled = Counter(name for events in host.values()
+                       for name, _, _ in events if name in recorded)
+    assert profiled == recorded
+
+
+@pytest.mark.parametrize("partition", ["range", "dest"])
+@pytest.mark.parametrize("devices", [1, 4])
+def test_fused_step_hlo_carries_the_kernel_scopes(devices, partition):
+    from sparkrdma_tpu.parallel.device_plane import make_fused_step
+
+    mesh_n = Mesh(np.array(jax.devices()[:devices]), ("shuffle",))
+    step = make_fused_step(mesh_n, "shuffle", 4, impl="gather",
+                           partition=partition)
+    args = [jax.ShapeDtypeStruct((devices * 256, 4), np.uint32)]
+    if partition == "dest":
+        args.append(jax.ShapeDtypeStruct((devices * 256,), np.int32))
+    hlo = step.lower(*args).compile().as_text()
+    assert "fused.receive_sort/row_gather" in hlo
+    assert "fused.receive_sort/key_sort" in hlo
+    multi = ("fused.exchange", "fused.partition")
+    assert all((name in hlo) == (devices > 1) for name in multi)
+    # the names are metadata: a cached executable of a build without them
+    # must not be served for this program
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
