@@ -176,9 +176,10 @@ def run_terasort_streamed(mesh: Mesh, cfg: TeraSortConfig, rows: np.ndarray,
     ``phase_times``, when a dict is passed, is filled with wall seconds per
     phase — ``stage_s`` (host chunk prep + device_put + async dispatch),
     ``collect_s`` (blocking device wait + host-side run splitting) and
-    ``merge_s`` (final per-device tournament merge) — the per-phase view
-    BASELINE config #2 rehearsals report (with pipelining on, stage and
-    collect overlap, so their sum can exceed end-to-end wall time).
+    ``merge_s`` (final per-device merge of the rounds' runs) — the
+    per-phase view BASELINE config #2 rehearsals report (with pipelining
+    on, stage and collect overlap, so their sum can exceed end-to-end
+    wall time).
 
     Returns ``(per_device_sorted_rows: [D] list of u32[*, 1+P], rounds)``.
     """
@@ -271,10 +272,10 @@ def run_terasort_streamed(mesh: Mesh, cfg: TeraSortConfig, rows: np.ndarray,
         if not runs[d]:
             merged.append(np.zeros((0, rows.shape[1]), rows.dtype))
             continue
-        # R key-sorted runs -> one sorted output via an O(N log R)
-        # pairwise tournament of vectorized positional merges (keys are a
-        # zero-copy view of column 0; earlier rounds win ties, matching
-        # the former stable re-sort's order exactly)
+        # R key-sorted runs -> one sorted output in merge_runs' single
+        # pass, each row written once (keys are a zero-copy view of
+        # column 0; earlier rounds win ties, matching the former stable
+        # re-sort's order exactly)
         _, out = merge_runs([(r[:, 0], r) for r in runs[d]])
         merged.append(out)
     times["merge_s"] = time.perf_counter() - t0

@@ -621,10 +621,10 @@ def run_fused_exchange_rounds(mesh, axis_name: str, blocks,
     when a dispatch preceded the previous round's collection).
 
     Returns ``(per_device_sorted_rows, rounds)``: device d's rows
-    key-sorted (u64 packed keys when ``key_words == 2``), rounds merged
-    via the tournament merge. Raises ``OverflowError`` on any round's
-    receive overflow — the caller (engine) degrades the stage to the
-    host dataplane.
+    key-sorted (u64 packed keys when ``key_words == 2``), the rounds'
+    runs merged in one pass (``exchange.merge``). Raises
+    ``OverflowError`` on any round's receive overflow — the caller
+    (engine) degrades the stage to the host dataplane.
     """
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -696,11 +696,7 @@ def run_fused_exchange_rounds(mesh, axis_name: str, blocks,
 
     if rounds == 0:
         return [np.zeros((0, row_words), np.uint32) for _ in range(n)], 0
-    with tracer.span("exchange.merge", "exchange", runs=rounds,
-                     rows=sum(len(r) for rs in runs for r in rs)):
-        merged = [_merge_device_runs(rs, row_words, key_words)
-                  for rs in runs]
-    return merged, rounds
+    return _merge_all_devices(tracer, runs, row_words, key_words), rounds
 
 
 def _pull_runs(results, n: int, row_words: int) -> List[np.ndarray]:
@@ -719,10 +715,26 @@ def _pull_runs(results, n: int, row_words: int) -> List[np.ndarray]:
     return [out[d][:int(counts[d].sum())].copy() for d in range(n)]
 
 
+def _merge_all_devices(tracer, runs: List[list], row_words: int,
+                       key_words: int) -> List[np.ndarray]:
+    """The ``exchange.merge`` span of both drivers: every device's runs
+    -> one sorted run each. ``bytes`` is what the merge wrote: each row
+    once (``rows * row_words * 4``), and 0 for a device whose single
+    run passed through."""
+    with tracer.span("exchange.merge", "exchange",
+                     runs=max(len(rs) for rs in runs),
+                     rows=sum(len(r) for rs in runs for r in rs)) as args:
+        merged = [_merge_device_runs(rs, row_words, key_words)
+                  for rs in runs]
+        args["bytes"] = sum(int(m.nbytes) for m, rs in zip(merged, runs)
+                            if all(m is not r for r in rs))
+    return merged
+
+
 def _merge_device_runs(device_runs: list, row_words: int,
                        key_words: int) -> np.ndarray:
-    """One device's key-sorted runs (one per round) as one sorted run,
-    via the tournament merge; a single run passes through."""
+    """One device's key-sorted runs (one per round) as one sorted run:
+    ``merge_runs``' single pass; a single run passes through."""
     if not device_runs:
         return np.zeros((0, row_words), np.uint32)
     if len(device_runs) == 1:
@@ -740,7 +752,7 @@ def _merge_device_runs(device_runs: list, row_words: int,
 def _run_keys(r: np.ndarray, key_words: int) -> np.ndarray:
     """Sort/merge keys of device-row runs: packed u64 for the 2-word
     layout, column 0 otherwise (shared by the flat and hierarchical
-    drivers' tournament merges and the host-side degrade sort)."""
+    drivers' merges and the host-side degrade sort)."""
     if key_words == 2:
         return r[:, :2].copy().view(np.uint64).reshape(-1)
     return r[:, 0]
@@ -939,9 +951,4 @@ def run_hierarchical_exchange(mesh, axis_name: str,
                        cross_slice_bytes=inter_rows * row_bytes,
                        degraded_slices=sorted(degraded))
 
-    with tracer.span("exchange.merge", "exchange",
-                     runs=max(len(rs) for rs in runs),
-                     rows=sum(len(r) for rs in runs for r in rs)):
-        merged = [_merge_device_runs(rs, row_words, key_words)
-                  for rs in runs]
-    return merged, rounds
+    return _merge_all_devices(tracer, runs, row_words, key_words), rounds

@@ -5,9 +5,13 @@ The reference leans on Spark's ExternalSorter for beyond-memory reduces
 merge). A standalone framework needs that half in-tree:
 
 * ``merge_two`` / ``merge_runs`` — vectorized positional merges of sorted
-  row arrays (O(N log R) tournament over R runs; numpy has no merge
-  primitive, but two sorted arrays interleave with two ``searchsorted``
-  calls and two scatters — no per-row Python).
+  row arrays, no per-row Python (numpy has no merge primitive). Two
+  arrays interleave with two ``searchsorted`` calls and one scatter
+  each; R runs merge in ONE pass: a stable argsort of the concatenated
+  keys (timsort gallops through the R presorted runs) gives every row
+  its place, and every run's rows are scattered there straight from
+  where they lie, block by block of the output — a row is written once
+  however many runs there are.
 * ``ExternalMerger`` — the spill path: batches accumulate to a memory
   budget, spill as sorted runs to disk, then stream back globally sorted
   via a k-way buffered merge whose resident set is bounded by
@@ -47,8 +51,16 @@ def merge_two(a_keys: np.ndarray, a_rows: np.ndarray,
 
 
 def merge_runs(runs: Sequence[Batch]) -> Batch:
-    """Tournament-merge R key-sorted runs in O(N log R) — the in-memory
-    replacement for the full re-sort (models/terasort.py streamed merge)."""
+    """Merge R key-sorted runs in one pass, every row written once.
+
+    The result is the stable sort of the runs' concatenation: on equal
+    keys the earlier run first, within a run the original order. Only
+    the keys are concatenated; each run's rows go from where they lie
+    straight to their places in one fresh output, so the peak is inputs
+    + output + three index arrays of 8 bytes a row. A single non-empty
+    run is returned as it came (no copy). Replaces the full re-sort of
+    the streamed reduces (models/terasort.py, parallel/device_plane.py).
+    """
     runs = list(runs)
     nonempty = [r for r in runs if len(r[0])]
     if not nonempty:
@@ -56,15 +68,58 @@ def merge_runs(runs: Sequence[Batch]) -> Batch:
             k0, r0 = runs[0]
             return k0[:0], r0[:0]
         return np.zeros(0, np.uint64), np.zeros((0, 0), np.uint8)
-    runs = nonempty
-    while len(runs) > 1:
-        nxt = []
-        for i in range(0, len(runs) - 1, 2):
-            nxt.append(merge_two(*runs[i], *runs[i + 1]))
-        if len(runs) % 2:
-            nxt.append(runs[-1])
-        runs = nxt
-    return runs[0]
+    if len(nonempty) == 1:
+        return nonempty[0]
+    keys = np.concatenate([k for k, _ in nonempty])
+    order = np.argsort(keys, kind="stable")
+    place = np.empty(len(order), np.intp)  # the inverse permutation
+    place[order] = np.arange(len(order))
+    r0 = nonempty[0][1]
+    rows = np.empty((len(keys),) + r0.shape[1:], r0.dtype)
+    if rows.size:
+        _scatter_runs([r for _, r in nonempty], place, rows)
+    return keys[order], rows
+
+
+# rows one fancy-index call should move on average: enough that numpy's
+# cost per call stays small beside the copy, few enough that a block of
+# the output (this many rows from each run) stays in the cache
+_ROWS_PER_CALL = 256
+
+
+def _scatter_runs(runs: List[np.ndarray], place: np.ndarray,
+                  out: np.ndarray) -> None:
+    """Write every run's rows to their places in ``out``, each once.
+
+    A sorted run's places only rise, so the rows it sends to one block
+    of the output are a slice of it. The output is therefore filled
+    block by block, every run's slice for a block before the next
+    block: the writes stay within the cache, where R sweeps over the
+    whole output, run by run, would each touch every page of it. A row
+    moves as one opaque element (one memmove, not a loop over words).
+    """
+    dst = _as_records(out)
+    block = _ROWS_PER_CALL * len(runs)
+    edges = np.arange(0, len(out) + block, block)
+    pieces, start = [], 0
+    for r in runs:
+        p = place[start:start + len(r)]
+        start += len(r)
+        pieces.append((p, _as_records(r),
+                       np.searchsorted(p, edges).tolist()))
+    for b in range(len(edges) - 1):
+        for p, src, cuts in pieces:
+            a, z = cuts[b], cuts[b + 1]
+            if z > a:
+                dst[p[a:z]] = src[a:z]
+
+
+def _as_records(rows: np.ndarray) -> np.ndarray:
+    """``rows`` (not zero-width) with each row as one opaque element: a
+    view, or a copy where the rows are not contiguous."""
+    flat = np.ascontiguousarray(rows).reshape(len(rows), -1)
+    return flat.view(np.dtype((np.void, flat.shape[1] * flat.itemsize))
+                     ).reshape(-1)
 
 
 class ExternalMerger:

@@ -414,9 +414,9 @@ def run_mesh_reduce_streamed(managers: Sequence[TpuShuffleManager],
     host staging) budget: spills stream through the SAME jitted exchange
     step in bounded rounds of ``rows_per_round`` rows per device — device
     memory is static per round, host staging holds one round — and each
-    device's key-sorted round outputs merge O(N log R) via the tournament
-    merge (`shuffle/external.py`). Same contract as ``run_mesh_reduce``
-    with ``sort_by_key=True``.
+    device's key-sorted round outputs merge in one pass, each row written
+    once (`shuffle/external.py::merge_runs`). Same contract as
+    ``run_mesh_reduce`` with ``sort_by_key=True``.
 
     ``pipeline_rounds``: double-buffer — round r+1 is decoded from the
     spills, padded, and DISPATCHED (jax dispatch is async) before round

@@ -21,6 +21,7 @@ from sparkrdma_tpu.parallel.device_plane import (
     StageProfile,
     auto_rows_per_round,
     run_fused_exchange,
+    run_fused_exchange_rounds,
     select_dataplane,
 )
 from sparkrdma_tpu.shuffle.manager import PartitionerSpec
@@ -353,6 +354,35 @@ def test_round_overlap_traces(mesh):
         np.testing.assert_array_equal(a, b)
 
 
+@pytest.mark.parametrize("want_rounds", [4, 1])
+def test_merge_span_counts_bytes_written(mesh, want_rounds):
+    """``exchange.merge``'s ``bytes``: the merge of several rounds' runs
+    writes every row exactly once (``rows * row_words * 4``, where the
+    pairwise tournament wrote each once a level); one round's single run
+    passes through and nothing is written."""
+    rng = np.random.default_rng(SEED + 27)
+    N, W = 128 * D * 4, 5
+    per_round = N // want_rounds
+    rows = rng.integers(0, 2**32, (N, W), dtype=np.uint32)
+    dest = (rows[:, 0] % D).astype(np.int32)
+    blocks = ((rows[i:i + per_round], dest[i:i + per_round])
+              for i in range(0, N, per_round))
+    tracer = Tracer()
+    res, rounds = run_fused_exchange_rounds(
+        mesh, "shuffle", blocks, W, per_round // D, key_words=2,
+        impl="gather", out_factor=4, tracer=tracer)
+    assert rounds == want_rounds
+    (merge,) = [e["args"] for e in tracer._events
+                if e["name"] == "exchange.merge"]
+    assert merge == {"runs": rounds, "rows": N,
+                     "bytes": N * W * 4 if rounds > 1 else 0}
+    for d, r in enumerate(res):
+        assert (r[:, 0] % D == d).all()
+        k = r[:, :2].copy().view(np.uint64).reshape(-1)
+        assert (k[:-1] <= k[1:]).all()
+    assert sum(len(r) for r in res) == N
+
+
 # -- satellite: topology-warning dedupe ----------------------------------
 
 def test_topology_warning_dedupes_per_mesh_axis(mesh, caplog):
@@ -491,7 +521,10 @@ def test_result_stage_spans_tile_the_mesh_reduce(cluster, mesh4, budget,
         assert len(by_name[name]) == 1, name
     rounds = len(by_name["exchange.round"])
     merge, unpack, split = (by_name[n][0]["args"] for n in ONCE_SPANS)
-    assert merge == {"runs": rounds, "rows": records}
+    # the merge wrote every 12-byte row once, or nothing: a single run
+    # passes through
+    assert merge == {"runs": rounds, "rows": records,
+                     "bytes": records * 12 if multi_round else 0}
     assert unpack == {"rows": records}
     assert split == {"partitions": 4, "rows": records}
     assert len(by_name["exchange.collect"]) == rounds

@@ -41,6 +41,103 @@ def test_merge_runs_matches_full_sort():
     np.testing.assert_array_equal(merged[:, 0], want[:, 0])
 
 
+def _keys_of(rows, key_kind):
+    """The callers' two key forms: column 0, or columns 0-1 packed (low
+    word first) as the device plane's u64."""
+    if key_kind == "u64":
+        return rows[:, :2].copy().view(np.uint64).reshape(-1)
+    return rows[:, 0]
+
+
+def _sorted_runs(rng, lengths, key_kind, key_range):
+    """Key-sorted u32[n, 5] runs; every row carries its run in column 3
+    and its index within the run in column 4, so a row that moved, was
+    lost or was written twice shows in the bytes."""
+    runs = []
+    for i, n in enumerate(lengths):
+        rows = rng.integers(0, 2**32, size=(n, 5), dtype=np.uint32)
+        rows[:, 0] = rng.integers(0, key_range, n)
+        rows[:, 1] = rng.integers(0, min(key_range, 3), n)
+        rows = rows[np.argsort(_keys_of(rows, key_kind), kind="stable")]
+        rows[:, 3], rows[:, 4] = i, np.arange(n)
+        runs.append((_keys_of(rows, key_kind), rows))
+    return runs
+
+
+# long enough that every merge crosses several blocks of the output
+MERGE_LENGTHS = {
+    "R1": [300],
+    "R2": [900, 1300],
+    "R3": [1257, 1, 2400],
+    "R7": [0, 1200, 5000, 0, 3, 640, 3333],  # empty runs among the others
+    "R24": [400 + 170 * (i % 5) for i in range(24)],
+    "R2_one_empty": [0, 250],
+}
+
+
+@pytest.mark.parametrize("key_range", [50, 2**32], ids=["ties", "spread"])
+@pytest.mark.parametrize("key_kind", ["u32", "u64"])
+@pytest.mark.parametrize("lengths", list(MERGE_LENGTHS.values()),
+                         ids=list(MERGE_LENGTHS))
+def test_merge_runs_is_the_stable_sort_of_the_concatenation(
+        lengths, key_kind, key_range):
+    """Whole rows, byte for byte: ``merge_runs`` equals the stable argsort
+    of the runs' concatenation — key-sorted, the earlier run first on
+    equal keys, a run's own order kept — and leaves its inputs as they
+    were."""
+    rng = np.random.default_rng(len(lengths) * 1000 + key_range % 997)
+    runs = _sorted_runs(rng, lengths, key_kind, key_range)
+    before = [r.copy() for _, r in runs]
+    keys, merged = merge_runs(runs)
+
+    all_keys = np.concatenate([k for k, _ in runs])
+    all_rows = np.concatenate([r for _, r in runs])
+    order = np.argsort(all_keys, kind="stable")
+    assert merged.dtype == all_rows.dtype and keys.dtype == all_keys.dtype
+    assert merged.tobytes() == all_rows[order].tobytes()
+    np.testing.assert_array_equal(keys, all_keys[order])
+    # the tags say it without the reference: within one key the run
+    # number never falls, and within one run the index only rises
+    same_key = keys[1:] == keys[:-1]
+    run, idx = merged[:, 3].astype(np.int64), merged[:, 4].astype(np.int64)
+    assert (np.diff(run)[same_key] >= 0).all()
+    assert (np.diff(idx)[same_key & (np.diff(run) == 0)] > 0).all()
+    for (_, r), was in zip(runs, before):
+        np.testing.assert_array_equal(r, was)
+    if sum(1 for n in lengths if n) == 1:   # the one run passes through
+        assert merged is next(r for _, r in runs if len(r))
+
+
+def _layout_rows(layout, rng, n):
+    if layout == "zero_width":      # keys-only shuffles: nothing to move
+        return np.zeros((n, 0), np.uint8)
+    if layout == "one_d":           # a row is one scalar
+        return rng.integers(0, 2**63, n).astype(np.uint64)
+    if layout == "strided":         # rows that are not contiguous in memory
+        return rng.integers(0, 256, (n, 14), dtype=np.uint8)[:, ::2]
+    if layout == "three_d":
+        return rng.integers(0, 2**16, (n, 3, 2)).astype(np.uint16)
+    raise AssertionError(layout)
+
+
+@pytest.mark.parametrize("layout",
+                         ["zero_width", "one_d", "strided", "three_d"])
+def test_merge_runs_row_layouts(layout):
+    """Rows move as opaque records whatever their shape and strides, and
+    many blocks of the output are crossed (3 runs x 2,000 rows)."""
+    rng = np.random.default_rng(5)
+    runs = []
+    for n in (2000, 1500, 2500):
+        keys = np.sort(rng.integers(0, 400, n).astype(np.uint64))
+        runs.append((keys, _layout_rows(layout, rng, n)))
+    keys, merged = merge_runs(runs)
+    all_rows = np.concatenate([r for _, r in runs])
+    order = np.argsort(np.concatenate([k for k, _ in runs]), kind="stable")
+    assert merged.shape == all_rows.shape and merged.dtype == all_rows.dtype
+    np.testing.assert_array_equal(merged, all_rows[order])
+    assert (keys[1:] >= keys[:-1]).all()
+
+
 def test_external_merger_exact_and_bounded(tmp_path):
     rng = np.random.default_rng(2)
     W = 24
@@ -162,7 +259,7 @@ def test_merge_completes_under_address_space_cap(tmp_path):
 
 
 def test_terasort_streamed_uses_merge(tmp_path):
-    """The streamed TeraSort host merge is the tournament merge and its
+    """The streamed TeraSort host merge is ``merge_runs`` and its
     output is unchanged (exact multiset + sorted per device)."""
     import os as _os
     _os.environ.setdefault("XLA_FLAGS",

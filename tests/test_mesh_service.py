@@ -188,7 +188,7 @@ def test_streamed_mesh_reduce_matches_one_shot(cluster, mesh):
         np.testing.assert_array_equal(k1, k2)
         np.testing.assert_array_equal(parts1, parts2)
         # payload multiset per device (duplicate-key order may differ
-        # between a global stable sort and a tournament merge)
+        # between a global stable sort and a merge of the rounds' runs)
         rows1 = np.concatenate([k1[:, None].astype(np.uint64),
                                 p1.astype(np.uint64)], axis=1)
         rows2 = np.concatenate([k2[:, None].astype(np.uint64),

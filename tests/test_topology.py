@@ -296,12 +296,18 @@ def test_hierarchical_budget_rounds_parity(mesh):
     one_shot, r1 = run_hierarchical_exchange(
         mesh, "shuffle", TOPO, rows, dest, home, key_words=2,
         out_factor=8, impl="gather")
+    tracer = Tracer()
     rounds, rn = run_hierarchical_exchange(
         mesh, "shuffle", TOPO, rows, dest, home, key_words=2,
-        out_factor=8, impl="gather", rows_per_round=128)
+        out_factor=8, impl="gather", rows_per_round=128, tracer=tracer)
     assert rn > r1
     for d in range(D):
         assert _canon(one_shot[d]) == _canon(rounds[d])
+    # every device merged several runs: each row written exactly once
+    (merge,) = [e["args"] for e in tracer._events
+                if e["name"] == "exchange.merge"]
+    assert merge["runs"] > 1 and merge["rows"] == len(rows)
+    assert merge["bytes"] == rows.nbytes
 
 
 # -- engine end-to-end: the three planes agree ---------------------------
