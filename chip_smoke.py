@@ -20,6 +20,12 @@ this one process, on every chip ``jax.devices()`` returns:
   record for record with a numpy sort of the same input, and with each
   other byte for byte.
 
+* **Job C — PageRank** (BASELINE config #3 at the size of the benchmark's
+  cell ``pagerank_1chip``: 16,777,216 edges and 468,750 vertices a chip,
+  Zipf in-links): ``models.pagerank.PageRankJob`` over a resident
+  ``powerlaw_graph``, a warm job and a timed one of three supersteps;
+  the ranks of the last are held to ``benchmark/reference_pagerank.py``.
+
 One process drives all the chips of the host: a chip belongs to one
 process at a time, so this script starts no other process.
 
@@ -54,6 +60,11 @@ AXIS = "shuffle"
 ROW_BYTES = 100          # the gensort record: 25 u32 words on the device
 PAYLOAD_BYTES = 92       # Job B: u64 key + 92 bytes = the same 25-word row
 FULL_BYTES = 1 << 30     # BASELINE config #1
+# Job C: a chip's graph in the benchmark's cell pagerank_1chip
+PAGERANK_EDGES = 16_777_216
+PAGERANK_VERTICES = 468_750
+PAGERANK_ITERATIONS = 3
+PAGERANK_ZIPF_S = 0.9
 NO_EXCHANGE = "none: one device, the step is a local sort"
 
 
@@ -427,6 +438,84 @@ def run_job_b(mesh, total_bytes: int, seed: int, compile_log: CompileLog,
 
 
 # ---------------------------------------------------------------------------
+# Job C: PageRank over a resident power-law graph
+# ---------------------------------------------------------------------------
+
+def run_job_c(mesh, edges_per_chip: int, seed: int, compile_log: CompileLog):
+    """One PageRank job (after a warm one) of ``PAGERANK_ITERATIONS``
+    supersteps over ``edges_per_chip`` edges a chip at the cell's degree,
+    its ranks against the float64 reference. Returns ``(record,
+    failures)``."""
+    import jax
+
+    from benchmark import reference_pagerank
+    from sparkrdma_tpu.models.pagerank import (
+        PageRankConfig,
+        PageRankJob,
+        place_graph,
+        powerlaw_graph,
+    )
+    from sparkrdma_tpu.parallel import exchange as exchange_mod
+    from sparkrdma_tpu.utils.trace import Tracer
+
+    failures: list = []
+    n = mesh.shape[AXIS]
+    cfg = PageRankConfig(
+        num_vertices=n * (edges_per_chip * PAGERANK_VERTICES
+                          // PAGERANK_EDGES),
+        edges_per_device=edges_per_chip, out_factor=2)
+    impl = exchange_mod.resolve_impl(mesh, "auto", AXIS)
+    if (n > 1 and mesh.devices.flat[0].platform == "tpu"
+            and impl != "native"):
+        failures.append(f"job_c: resolve_impl(mesh) = {impl!r}, not 'native'")
+    edges, _, out_deg = powerlaw_graph(cfg, n, seed, PAGERANK_ZIPF_S)
+    snap = compile_log.snapshot()
+    graph = place_graph(mesh, AXIS, edges, out_deg)
+    job = PageRankJob(mesh, AXIS, cfg, PAGERANK_ITERATIONS)
+    t0 = time.perf_counter()
+    job(graph)
+    warm_job_s = time.perf_counter() - t0
+    compile_facts = compile_log.since(snap)
+    job.tracer = Tracer()
+    t0 = time.perf_counter()
+    ranks = job(graph)
+    job_s = time.perf_counter() - t0
+    with tempfile.TemporaryDirectory(prefix="chip_smoke_") as tmp:
+        job.tracer.dump(os.path.join(tmp, "job_c.json"))
+        with open(os.path.join(tmp, "job_c.json")) as f:
+            events = json.load(f)["traceEvents"]
+    received = next(e["args"]["received"] for e in events
+                    if e["name"] == "pagerank.job")
+    counters = {e["name"]: e["args"]["value"] for e in events
+                if e.get("ph") == "C"}
+    if received != [graph.num_edges] * PAGERANK_ITERATIONS:
+        failures.append(f"job_c: contributions received {received}, valid "
+                        f"edges in {graph.num_edges}")
+    t0 = time.perf_counter()
+    problems, readings = reference_pagerank.pagerank_report(
+        np.asarray(ranks), edges, cfg.num_vertices, cfg.damping,
+        PAGERANK_ITERATIONS)
+    failures += [f"job_c: {p}" for p in problems]
+    record = {
+        "edges": graph.num_edges, "vertices": cfg.num_vertices,
+        "iterations": PAGERANK_ITERATIONS, "record_bytes": 8,
+        "exchange_impl": impl,
+        "contributions_received": received,
+        "recv_fill": counters.get("pagerank.recv_fill"),
+        "max_in_degree": counters.get("pagerank.max_in_degree"),
+        "ranks_on_device": isinstance(ranks, jax.Array),
+        "verified": not problems,
+        "against_reference": readings,
+        "setup_facts": dict(compile_facts,
+                            warm_job_s=round(warm_job_s, 2),
+                            job_s=round(job_s, 2),
+                            verify_s=round(time.perf_counter() - t0, 2)),
+        "peak_hbm_bytes": peak_hbm(mesh.devices.flat),
+    }
+    return record, failures
+
+
+# ---------------------------------------------------------------------------
 
 def verdict(failures: list, devs) -> dict:
     """The last line of standard output: these keys and no others."""
@@ -466,15 +555,17 @@ def main(argv=None) -> int:
     mesh = Mesh(np.array(devs), (AXIS,))
     n = len(devs)
     bytes_a = bytes_b = FULL_BYTES
+    edges_c = PAGERANK_EDGES
     if args.rehearsal:
-        bytes_a, bytes_b = 4 << 20, 64 << 20
+        bytes_a, bytes_b, edges_c = 4 << 20, 64 << 20, 1 << 16
     failures: list = []
     if not native.available():
         failures.append("native runtime not loaded (make -C csrc)")
 
     jobs = {}
     for name, fn, size in (("job_a", run_job_a, bytes_a),
-                           ("job_b", run_job_b, bytes_b)):
+                           ("job_b", run_job_b, bytes_b),
+                           ("job_c", run_job_c, edges_c)):
         t0 = time.perf_counter()
         try:
             jobs[name], job_failures = fn(mesh, size, args.seed, compile_log)
@@ -496,6 +587,7 @@ def main(argv=None) -> int:
                      f"over {n} devices"),
         "job_a": jobs.get("job_a"),
         "job_b": jobs.get("job_b"),
+        "job_c": jobs.get("job_c"),
         "failures": failures,
         "reduced": [],
         "native_runtime_loaded": native.available(),

@@ -22,8 +22,9 @@ shape GraphX produces, minus the host.
 from __future__ import annotations
 
 import functools
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
-from typing import Tuple
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -31,7 +32,13 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from sparkrdma_tpu.parallel.exchange import resolve_impl, shuffle_shard
+from sparkrdma_tpu.parallel.exchange import (
+    group_by_destination,
+    ragged_exchange_shard,
+    record_exchange,
+    resolve_impl,
+)
+from sparkrdma_tpu.utils import trace
 
 
 @dataclass(frozen=True)
@@ -42,9 +49,24 @@ class PageRankConfig:
     out_factor: int = 2
 
 
+# Records a wire row carries. The TPU's ragged all-to-all moves a row as
+# 128 32-bit lanes (512 bytes) whatever its width: an 8-byte record sent as
+# a row of its own is padded 64-fold, in the send buffer and in the receive
+# buffer (1,536 bytes of HBM an edge at ``out_factor`` 2, so a chip could
+# not hold 10^7 edges). 64 records fill the lanes exactly.
+WIRE_RECORDS = 64
+
+
+def wire_rows(cfg: PageRankConfig, num_devices: int) -> int:
+    """Wire rows a device sends at most: its edges in whole rows, and one
+    more for each destination's last, partly filled row. The receive
+    buffer holds ``out_factor`` times as many."""
+    return -(-cfg.edges_per_device // WIRE_RECORDS) + num_devices
+
+
 def make_pagerank_step(mesh: Mesh, axis_name: str, cfg: PageRankConfig,
                        impl: str = "auto"):
-    """One jitted PageRank iteration.
+    """One jitted PageRank iteration (a *superstep*).
 
     Per-device inputs (leading axis sharded over ``axis_name``):
       ``edges: i32[D*E, 2]`` — (src, dst) global vertex ids; padding rows
@@ -53,53 +75,115 @@ def make_pagerank_step(mesh: Mesh, axis_name: str, cfg: PageRankConfig,
         ``[d*V/D, (d+1)*V/D)``);
       ``out_deg: f32[V]`` — out-degrees, sharded identically.
 
-    Returns ``(ranks, overflowed[D])``; ``overflowed[d]`` flags a receive
-    buffer too small for the contribution fan-in (results invalid — raise
-    ``out_factor``), mirroring the TeraSort/join steps.
+    Returns ``(ranks, received[D, 2], overflowed[D])``: ``received[d]``
+    is the number of contributions device d was sent and the number of
+    wire rows they came in; ``overflowed[d]`` flags a receive buffer too
+    small for that fan-in (results invalid — raise ``out_factor``),
+    mirroring the TeraSort/join steps.
+
+    The shuffle's record is 8 bytes, ``(u32 dst, f32 contribution)``,
+    grouped by destination device as rows ``u32[E, 2]``
+    (``group_by_destination``). On the wire ``WIRE_RECORDS`` records of
+    one destination travel as one row of 128 lanes: each destination's
+    group is first filled up to whole wire rows with records ``(the
+    destination's first vertex, 0.0)``, which add nothing where they
+    land, so the receiver needs no count of them.
+
+    A device profile names the step's three phases by scope:
+    ``pagerank.contrib`` (the two per-edge gathers and the divide),
+    ``pagerank.exchange`` (grouping, with its ``row_gather``, and the
+    transport) and ``pagerank.accumulate`` (masking, scatter-add,
+    damping).
     """
     n = mesh.shape[axis_name]
     impl = resolve_impl(mesh, impl, axis_name)
     v_local = cfg.num_vertices // n
     spec = P(axis_name)
+    fill = n * WIRE_RECORDS   # records that may be needed to fill groups up
+    rows_out = wire_rows(cfg, n)
+    slack = rows_out * WIRE_RECORDS - cfg.edges_per_device - fill
 
     @jax.jit
     @functools.partial(shard_map, mesh=mesh,
                        in_specs=(spec, spec, spec),
-                       out_specs=(spec, spec))
+                       out_specs=(spec, spec, spec))
     def step(edges, ranks, out_deg):
         me = jax.lax.axis_index(axis_name)
-        src, dst = edges[:, 0], edges[:, 1]
-        valid = src >= 0
-        # local rank lookup: src ids are local to this shard
-        src_local = jnp.where(valid, src - me * v_local, 0)
-        contrib = jnp.where(valid,
-                            ranks[src_local] / jnp.maximum(out_deg[src_local], 1.0),
-                            0.0)
-        # rows: (dst, contribution bits) — one u32 matrix for the exchange
-        rows = jnp.stack([dst.astype(jnp.uint32),
-                          jax.lax.bitcast_convert_type(
-                              contrib.astype(jnp.float32), jnp.uint32)], axis=1)
-        dest_dev = jnp.where(valid, dst // v_local, -1)
-        output = jnp.zeros((rows.shape[0] * cfg.out_factor, 2), jnp.uint32)
-        received, recv_counts, _, overflowed = shuffle_shard(
-            rows, dest_dev, axis_name, n, output=output, impl=impl)
-        total = recv_counts.sum()
-        rvalid = jnp.arange(received.shape[0], dtype=jnp.int32) < total
-        rdst = jnp.where(rvalid,
-                         received[:, 0].astype(jnp.int32) - me * v_local, 0)
-        rcontrib = jnp.where(
-            rvalid,
-            jax.lax.bitcast_convert_type(received[:, 1], jnp.float32), 0.0)
-        sums = jnp.zeros(v_local, jnp.float32).at[rdst].add(rcontrib)
-        new_ranks = (1.0 - cfg.damping) / cfg.num_vertices + cfg.damping * sums
-        return new_ranks, overflowed[None]
+        with jax.named_scope("pagerank.contrib"):
+            src, dst = edges[:, 0], edges[:, 1]
+            valid = src >= 0
+            # local rank lookup: src ids are local to this shard
+            src_local = jnp.where(valid, src - me * v_local, 0)
+            contrib = jnp.where(
+                valid,
+                ranks[src_local] / jnp.maximum(out_deg[src_local], 1.0),
+                0.0)
+            dest_dev = jnp.where(valid, dst // v_local, -1)
+        with jax.named_scope("pagerank.exchange"):
+            # fill records: the first ``(-count) % 64`` of each
+            # destination's 64 are sent to it, the others to nobody
+            devices = jnp.arange(n, dtype=jnp.int32)
+            # a compare and a sum: a bincount is a scatter-add of E rows
+            counts = jnp.sum(dest_dev[:, None] == devices[None, :], axis=0,
+                             dtype=jnp.int32)
+            lane = jnp.arange(WIRE_RECORDS, dtype=jnp.int32)
+            fill_dest = jnp.where(
+                lane[None, :] < (-counts % WIRE_RECORDS)[:, None],
+                devices[:, None], -1).reshape(-1)
+            fill_dst = jnp.repeat(devices * v_local, WIRE_RECORDS)
+            # rows: (dst, contribution bits) — one u32 matrix to group
+            rows = jnp.stack([
+                jnp.concatenate([dst, fill_dst, jnp.zeros(slack, jnp.int32)]
+                                ).astype(jnp.uint32),
+                jax.lax.bitcast_convert_type(
+                    jnp.concatenate([contrib.astype(jnp.float32),
+                                     jnp.zeros(fill + slack, jnp.float32)]),
+                    jnp.uint32)], axis=1)
+            dest_dev = jnp.concatenate(
+                [dest_dev, fill_dest, jnp.full(slack, -1, jnp.int32)])
+            grouped, sent = group_by_destination(rows, dest_dev, n)
+            wire = jnp.concatenate(
+                [grouped[:, 0].reshape(rows_out, WIRE_RECORDS),
+                 grouped[:, 1].reshape(rows_out, WIRE_RECORDS)], axis=1)
+            output = jnp.zeros((rows_out * cfg.out_factor,
+                                2 * WIRE_RECORDS), jnp.uint32)
+            received, recv_rows, _, overflowed = ragged_exchange_shard(
+                wire, sent // WIRE_RECORDS, axis_name, output=output,
+                impl=impl)
+            contributions = jax.lax.all_gather(counts, axis_name)[:, me].sum()
+        with jax.named_scope("pagerank.accumulate"):
+            total = recv_rows.sum()
+            rvalid = jnp.repeat(
+                jnp.arange(received.shape[0], dtype=jnp.int32) < total,
+                WIRE_RECORDS)
+            rdst = jnp.where(
+                rvalid,
+                received[:, :WIRE_RECORDS].reshape(-1).astype(jnp.int32)
+                - me * v_local, 0)
+            rcontrib = jnp.where(
+                rvalid,
+                jax.lax.bitcast_convert_type(
+                    received[:, WIRE_RECORDS:].reshape(-1), jnp.float32),
+                0.0)
+            sums = jnp.zeros(v_local, jnp.float32).at[rdst].add(rcontrib)
+            new_ranks = ((1.0 - cfg.damping) / cfg.num_vertices
+                         + cfg.damping * sums)
+        return (new_ranks,
+                jnp.stack([contributions, total]).astype(jnp.int32)[None],
+                overflowed[None])
 
     return step
 
 
+def _initial_ranks(num_vertices: int) -> np.ndarray:
+    return np.full(num_vertices, 1.0 / num_vertices, dtype=np.float32)
+
+
 def random_graph(cfg: PageRankConfig, num_devices: int, seed: int = 0,
                  ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Random directed graph, edges placed on their src's device.
+    """Random directed graph with *uniform* endpoints (no real graph has
+    them: the tests' small fixture; ``powerlaw_graph`` is the web's
+    shape), edges placed on their src's device.
     Returns (edges[D*E, 2], ranks[V], out_deg[V])."""
     rng = np.random.default_rng(seed)
     v_local = cfg.num_vertices // num_devices
@@ -113,30 +197,149 @@ def random_graph(cfg: PageRankConfig, num_devices: int, seed: int = 0,
         lo = d * cfg.edges_per_device
         edges[lo:lo + cfg.edges_per_device] = e
         np.add.at(out_deg, e[:, 0], 1.0)
-    ranks = np.full(cfg.num_vertices, 1.0 / cfg.num_vertices, dtype=np.float32)
-    return edges, ranks, out_deg
+    return edges, _initial_ranks(cfg.num_vertices), out_deg
+
+
+_GRAPH_CHUNK = 1 << 20   # edges a generator task draws; part of the seeding
+
+
+def powerlaw_graph(cfg: PageRankConfig, num_devices: int, seed: int = 0,
+                   zipf_s: float = 0.9,
+                   ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Seeded directed graph whose in-links follow a power law, edges
+    placed on their src's device. Returns what ``random_graph`` returns.
+
+    Sources are uniform over the device's own vertices. Targets are drawn
+    from a Zipf over all ``V`` vertices *bounded to* ``V``: the inverse
+    CDF of the cumulative weights ``k^-zipf_s``, k = 1..V
+    (``np.random.zipf`` is unbounded and needs s > 1). A seeded
+    permutation of the vertex ids then spreads the hubs over the id range,
+    and so over the devices. Vectorised, in chunks of ``_GRAPH_CHUNK``
+    edges on a few threads (numpy's generators and ``searchsorted``
+    release the interpreter lock): each chunk has a generator of its own,
+    seeded by ``(seed, device, chunk)``, so the graph does not depend on
+    the number of threads."""
+    num_v, per_dev = cfg.num_vertices, cfg.edges_per_device
+    v_local = num_v // num_devices
+    cdf = np.cumsum(np.arange(1, num_v + 1, dtype=np.float64) ** -zipf_s)
+    cdf /= cdf[-1]
+    perm = np.random.default_rng([seed, num_v]).permutation(
+        num_v).astype(np.int32)
+    edges = np.empty((num_devices * per_dev, 2), dtype=np.int32)
+
+    def draw(task) -> None:
+        d, lo = task
+        hi = min(lo + _GRAPH_CHUNK, per_dev)
+        rng = np.random.default_rng([seed, d, lo // _GRAPH_CHUNK])
+        out = edges[d * per_dev + lo:d * per_dev + hi]
+        out[:, 0] = rng.integers(d * v_local, (d + 1) * v_local,
+                                 size=hi - lo, dtype=np.int32)
+        # rank k-1 of the Zipf; a draw of exactly 1.0 cannot happen, the
+        # clip guards the last cumulative weight's rounding
+        zipf_rank = np.searchsorted(cdf, rng.random(hi - lo), side="right")
+        out[:, 1] = perm[np.minimum(zipf_rank, num_v - 1)]
+
+    tasks = [(d, lo) for d in range(num_devices)
+             for lo in range(0, per_dev, _GRAPH_CHUNK)]
+    with ThreadPoolExecutor(max_workers=min(8, len(tasks))) as pool:
+        list(pool.map(draw, tasks))
+    out_deg = np.bincount(edges[:, 0], minlength=num_v).astype(np.float32)
+    return edges, _initial_ranks(num_v), out_deg
+
+
+class ResidentGraph(NamedTuple):
+    """A graph on the devices, as a job takes it."""
+    edges: jax.Array      # i32[D*E, 2], sharded over the shuffle axis
+    out_deg: jax.Array    # f32[V], range-sharded
+    num_edges: int        # valid (non-padding) edges
+    max_in_degree: int    # the largest hub's fan-in
+
+
+def place_graph(mesh: Mesh, axis_name: str, edges: np.ndarray,
+                out_deg: np.ndarray) -> ResidentGraph:
+    """Put a host graph on the mesh, once, for any number of jobs."""
+    shard = NamedSharding(mesh, P(axis_name))
+    valid = edges[:, 0] >= 0
+    num_edges = int(valid.sum())
+    dst = edges[:, 1] if num_edges == len(edges) else edges[valid, 1]
+    return ResidentGraph(jax.device_put(edges, shard),
+                         jax.device_put(out_deg, shard), num_edges,
+                         int(np.bincount(dst).max()) if num_edges else 0)
+
+
+class PageRankJob:
+    """``job(graph) -> ranks``: one PageRank job over a resident graph.
+
+    A job resets the ranks to ``1/V`` on the devices, dispatches
+    ``iterations`` supersteps back to back (the ranks never leave HBM
+    and the host does not wait between them), blocks once, and only then
+    reads every superstep's ``overflowed`` flag: any one set raises
+    ``OverflowError``. Returns the ranks as a sharded ``jax.Array``.
+    The programs are built here, once, for any number of jobs.
+
+    Spans, on ``self.tracer`` (a caller may set one per job, as with the
+    engine's): ``pagerank.job`` (``iterations``, ``edges``, ``vertices``;
+    at its end ``received``, the contributions delivered in each
+    superstep) around ``pagerank.dispatch`` and ``pagerank.wait``.
+    Counters, per job: ``pagerank.recv_fill`` (most wire rows any device
+    received over its receive capacity) and ``pagerank.max_in_degree``.
+    """
+
+    def __init__(self, mesh: Mesh, axis_name: str, cfg: PageRankConfig,
+                 iterations: int, impl: str = "auto", tracer=trace.NULL):
+        self.cfg = cfg
+        self.iterations = iterations
+        self.tracer = tracer
+        self._step = make_pagerank_step(mesh, axis_name, cfg, impl)
+        # the receive buffer, in wire rows (the step's ``output``)
+        self._capacity = cfg.out_factor * wire_rows(
+            cfg, mesh.shape[axis_name])
+        self._reset = jax.jit(
+            lambda: jnp.full(cfg.num_vertices, 1.0 / cfg.num_vertices,
+                             jnp.float32),
+            out_shardings=NamedSharding(mesh, P(axis_name)))
+
+    def __call__(self, graph: ResidentGraph) -> jax.Array:
+        tracer = self.tracer
+        with tracer.span("pagerank.job", "pagerank",
+                         iterations=self.iterations, edges=graph.num_edges,
+                         vertices=self.cfg.num_vertices) as args:
+            with tracer.span("pagerank.dispatch", "pagerank"):
+                ranks = self._reset()
+                facts = []
+                for _ in range(self.iterations):
+                    ranks, received, overflowed = self._step(
+                        graph.edges, ranks, graph.out_deg)
+                    facts.append((received, overflowed))
+                    record_exchange(graph.num_edges)
+            with tracer.span("pagerank.wait", "pagerank"):
+                jax.block_until_ready(ranks)
+            received = np.array([np.asarray(r) for r, _ in facts])
+            args["received"] = received[:, :, 0].sum(axis=1).tolist()
+            tracer.counter("pagerank.recv_fill",
+                           float(received[:, :, 1].max()) / self._capacity,
+                           "pagerank")
+            tracer.counter("pagerank.max_in_degree", graph.max_in_degree,
+                           "pagerank")
+            late = [i for i, (_, o) in enumerate(facts)
+                    if np.asarray(o).any()]
+            if late:
+                raise OverflowError(
+                    f"pagerank receive buffer overflow in supersteps "
+                    f"{late}: contribution fan-in exceeds out_factor "
+                    "headroom; raise PageRankConfig.out_factor")
+        return ranks
 
 
 def run_pagerank(mesh: Mesh, cfg: PageRankConfig, iterations: int,
                  axis_name: str = "shuffle", seed: int = 0,
                  impl: str = "auto") -> np.ndarray:
-    """Host loop: `iterations` jitted shuffle rounds; returns final ranks."""
-    n = mesh.shape[axis_name]
-    edges, ranks, out_deg = random_graph(cfg, n, seed)
-    step = make_pagerank_step(mesh, axis_name, cfg, impl)
-    shard = NamedSharding(mesh, P(axis_name))
-    edges_d = jax.device_put(edges, shard)
-    ranks_d = jax.device_put(ranks, shard)
-    deg_d = jax.device_put(out_deg, shard)
-    overflowed = None
-    for _ in range(iterations):
-        ranks_d, overflowed = step(edges_d, ranks_d, deg_d)
-    ranks_h = np.asarray(jax.block_until_ready(ranks_d))
-    if overflowed is not None and np.asarray(overflowed).any():
-        raise OverflowError(
-            "pagerank receive buffer overflow: contribution fan-in exceeds "
-            "out_factor headroom; raise PageRankConfig.out_factor")
-    return ranks_h
+    """``iterations`` supersteps over ``random_graph(cfg, seed)``; returns
+    the final ranks on the host. The small-graph convenience over
+    ``place_graph`` + ``PageRankJob``."""
+    edges, _, out_deg = random_graph(cfg, mesh.shape[axis_name], seed)
+    job = PageRankJob(mesh, axis_name, cfg, iterations, impl)
+    return np.asarray(job(place_graph(mesh, axis_name, edges, out_deg)))
 
 
 def numpy_pagerank(edges: np.ndarray, num_vertices: int, damping: float,

@@ -37,6 +37,9 @@ SPANS = frozenset({
     "fetch.merged",
     "fetch.refetch_range",
     "fetch.vectored",
+    "pagerank.dispatch",
+    "pagerank.job",
+    "pagerank.wait",
     "push.map",
     "push.planned",
     "write.merge",
@@ -97,6 +100,8 @@ INSTANTS = frozenset({
 COUNTERS = frozenset({
     "ha_failovers",
     "oplog_lag_entries",
+    "pagerank.max_in_degree",
+    "pagerank.recv_fill",
     "peer.suspects",
 })
 

@@ -102,3 +102,22 @@ def test_smoke_jobs_at_toy_size_on_the_cpu_mesh():
     assert rec["collective_exchanges"] >= 1
     assert rec["host_plane"]["tcp_fetchers_built"] > 0
     json.dumps(rec)  # the record must be printable as the summary
+
+
+def test_smoke_pagerank_job_at_toy_size_on_the_cpu_mesh():
+    """Job C: one PageRank job at the cell's degree against the float64
+    reference, on four CPU devices; its facts are a record for the
+    report line, and ``verdict`` has no key for them."""
+    import chip_smoke
+
+    mesh = Mesh(np.array(jax.devices()[:4]), (chip_smoke.AXIS,))
+    rec, failures = chip_smoke.run_job_c(mesh, 1 << 14, 2**31 + 3,
+                                         chip_smoke.CompileLog())
+    assert failures == [] and rec["verified"], (failures, rec)
+    assert rec["edges"] == 4 << 14 and rec["iterations"] == 3
+    assert rec["vertices"] == 4 * ((1 << 14) * 468_750 // 16_777_216)
+    assert rec["contributions_received"] == [4 << 14] * 3
+    assert 0 < rec["recv_fill"] <= 1 and rec["ranks_on_device"]
+    assert rec["against_reference"]["bound_share"] < 1
+    json.dumps(rec)
+    assert set(chip_smoke.verdict([], jax.devices()[:1])) == {"ok", "device"}

@@ -1,0 +1,200 @@
+"""The PageRank deployment on the normal path: the seeded power-law graph,
+the job entry over a resident graph, its spans, counters and scopes, and
+the system against the benchmark's float64 reference on one virtual device
+and on four (the one-chip cut's tie to the deployment)."""
+
+import os
+import re
+import sys
+
+import jax
+import numpy as np
+import pytest
+from jax.sharding import Mesh
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+from benchmark import reference_pagerank  # noqa: E402
+from sparkrdma_tpu.models.pagerank import (  # noqa: E402
+    PageRankConfig,
+    PageRankJob,
+    make_pagerank_step,
+    place_graph,
+    powerlaw_graph,
+    wire_rows,
+)
+from sparkrdma_tpu.utils.trace import Tracer  # noqa: E402
+
+AXIS = "shuffle"
+ZIPF_S = 0.9
+
+
+def _mesh(n):
+    return Mesh(np.array(jax.devices()[:n]), (AXIS,))
+
+
+def _events(tracer, tmp_path):
+    import json
+
+    path = str(tmp_path / "trace.json")
+    tracer.dump(path)
+    with open(path) as f:
+        return json.load(f)["traceEvents"]
+
+
+# -- the generator ------------------------------------------------------------
+
+def test_powerlaw_graph_is_deterministic_in_the_seed():
+    cfg = PageRankConfig(num_vertices=4096, edges_per_device=5000)
+    a = powerlaw_graph(cfg, 4, seed=2**31 + 11, zipf_s=ZIPF_S)
+    b = powerlaw_graph(cfg, 4, seed=2**31 + 11, zipf_s=ZIPF_S)
+    c = powerlaw_graph(cfg, 4, seed=2**31 + 12, zipf_s=ZIPF_S)
+    for x, y in zip(a, b):
+        np.testing.assert_array_equal(x, y)
+    assert not np.array_equal(a[0], c[0])
+
+
+def test_powerlaw_graph_is_bounded_and_places_edges_on_their_source():
+    devices, per_dev, num_v = 4, 6000, 1024
+    cfg = PageRankConfig(num_vertices=num_v, edges_per_device=per_dev)
+    edges, ranks, out_deg = powerlaw_graph(cfg, devices, seed=5,
+                                           zipf_s=ZIPF_S)
+    assert edges.shape == (devices * per_dev, 2) and edges.dtype == np.int32
+    assert edges.min() >= 0 and edges.max() < num_v
+    owner = edges[:, 0] // (num_v // devices)
+    np.testing.assert_array_equal(owner, np.repeat(np.arange(devices),
+                                                   per_dev))
+    np.testing.assert_array_equal(
+        out_deg, np.bincount(edges[:, 0], minlength=num_v))
+    assert out_deg.dtype == np.float32 and out_deg.sum() == len(edges)
+    np.testing.assert_allclose(ranks, 1.0 / num_v)
+    # the hubs are spread over the id range, and so over the devices
+    top = np.argsort(np.bincount(edges[:, 1], minlength=num_v))[-8:]
+    assert len(set(top // (num_v // devices))) > 1
+
+
+def test_powerlaw_graph_top_hub_is_the_zipf_expectation():
+    num_v, total = 4096, 200_000
+    cfg = PageRankConfig(num_vertices=num_v, edges_per_device=total)
+    edges, _, _ = powerlaw_graph(cfg, 1, seed=9, zipf_s=ZIPF_S)
+    in_degree = np.sort(np.bincount(edges[:, 1], minlength=num_v))[::-1]
+    weights = np.arange(1, num_v + 1, dtype=np.float64) ** -ZIPF_S
+    expect = total * weights / weights.sum()
+    assert 0.8 * expect[0] < in_degree[0] < 1.25 * expect[0]
+    # and the tail is a power law's, not a uniform graph's
+    assert in_degree[0] > 20 * np.median(in_degree)
+    assert 0.8 * expect[:64].sum() < in_degree[:64].sum() < 1.25 * expect[
+        :64].sum()
+
+
+# -- the system against the benchmark's reference -----------------------------
+
+@pytest.mark.parametrize("devices", [1, 4])
+def test_job_equals_the_float64_reference(devices):
+    """One device (the cell's cut) and four (the deployment's form) give
+    the uncut reference's ranks: same seed, same whole graph."""
+    num_v, total = 1024, 16384
+    cfg = PageRankConfig(num_vertices=num_v,
+                         edges_per_device=total // devices, out_factor=2)
+    edges, _, out_deg = powerlaw_graph(cfg, devices, seed=2**31 + 3,
+                                       zipf_s=ZIPF_S)
+    mesh = _mesh(devices)
+    job = PageRankJob(mesh, AXIS, cfg, iterations=3)
+    ranks = np.asarray(job(place_graph(mesh, AXIS, edges, out_deg)))
+    problems, readings = reference_pagerank.pagerank_report(
+        ranks, edges, num_v, cfg.damping, 3)
+    assert problems == []
+    assert readings["bound_share"] < 0.5
+    assert readings["relative_error"] < 1e-5
+    assert abs(ranks.sum() - 1.0) < 1e-3
+
+
+def test_a_hub_past_out_factor_raises():
+    devices, per_dev, num_v = 4, 512, 256
+    cfg = PageRankConfig(num_vertices=num_v, edges_per_device=per_dev,
+                         out_factor=2)
+    edges, _, out_deg = powerlaw_graph(cfg, devices, seed=1, zipf_s=ZIPF_S)
+    edges[:, 1] = 7    # every edge of every device points at one vertex
+    mesh = _mesh(devices)
+    job = PageRankJob(mesh, AXIS, cfg, iterations=2)
+    with pytest.raises(OverflowError, match="supersteps \\[0, 1\\]"):
+        job(place_graph(mesh, AXIS, edges, out_deg))
+
+
+# -- the job entry -------------------------------------------------------------
+
+def test_job_returns_a_resident_array_and_compiles_once():
+    import jax.monitoring
+
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, duration, **kw: compiles.append(event)
+        if event == "/jax/core/compile/backend_compile_duration" else None)
+    cfg = PageRankConfig(num_vertices=512, edges_per_device=2048)
+    mesh = _mesh(4)
+    edges, _, out_deg = powerlaw_graph(cfg, 4, seed=3, zipf_s=ZIPF_S)
+    graph = place_graph(mesh, AXIS, edges, out_deg)
+    assert graph.num_edges == len(edges)
+    assert graph.max_in_degree == np.bincount(edges[:, 1]).max()
+    job = PageRankJob(mesh, AXIS, cfg, iterations=3)
+    first = job(graph)
+    after_first = len(compiles)
+    second = job(graph)
+    assert after_first >= 1 and len(compiles) == after_first
+    for ranks in (first, second):
+        assert isinstance(ranks, jax.Array)
+        assert ranks.shape == (512,) and len(ranks.sharding.device_set) == 4
+    # a job starts from 1/V, whatever the one before it left
+    np.testing.assert_array_equal(np.asarray(first), np.asarray(second))
+
+
+def test_job_spans_and_counters(tmp_path):
+    devices, per_dev, num_v = 4, 1000, 512   # 1000: not a whole wire row
+    cfg = PageRankConfig(num_vertices=num_v, edges_per_device=per_dev)
+    mesh = _mesh(devices)
+    edges, _, out_deg = powerlaw_graph(cfg, devices, seed=4, zipf_s=ZIPF_S)
+    edges[-10:, 0] = -1    # padding rows are no contributions
+    graph = place_graph(mesh, AXIS, edges, out_deg)
+    tracer = Tracer()
+    PageRankJob(mesh, AXIS, cfg, iterations=3, tracer=tracer)(graph)
+    events = _events(tracer, tmp_path)
+    spans = {e["name"]: e for e in events if e.get("ph") == "X"}
+    assert set(spans) == {"pagerank.job", "pagerank.dispatch",
+                          "pagerank.wait"}
+    job = spans["pagerank.job"]
+    assert job["args"] == {"iterations": 3, "edges": len(edges) - 10,
+                           "vertices": num_v,
+                           "received": [len(edges) - 10] * 3}
+    for inner in ("pagerank.dispatch", "pagerank.wait"):
+        assert job["ts"] <= spans[inner]["ts"]
+        assert (spans[inner]["ts"] + spans[inner]["dur"]
+                <= job["ts"] + job["dur"])
+    counters = {e["name"]: e["args"]["value"] for e in events
+                if e.get("ph") == "C"}
+    assert counters["pagerank.max_in_degree"] == graph.max_in_degree
+    # wire rows: most any device received over its receive capacity
+    assert wire_rows(cfg, devices) == 16 + devices   # 1000 -> 16 rows of 64
+    capacity = cfg.out_factor * wire_rows(cfg, devices)
+    assert 1 / capacity <= counters["pagerank.recv_fill"] <= 1.0
+
+
+def test_the_three_scopes_name_the_steps_ops():
+    cfg = PageRankConfig(num_vertices=256, edges_per_device=1024)
+    edges, ranks, out_deg = powerlaw_graph(cfg, 4, seed=1, zipf_s=ZIPF_S)
+    step = make_pagerank_step(_mesh(4), AXIS, cfg)
+    text = step.lower(edges, ranks, out_deg).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in ("pagerank.contrib", "pagerank.exchange",
+                  "pagerank.accumulate", "pagerank.exchange/row_gather"):
+        assert any(f"/{scope}/" in n for n in names), scope
+    # the kernels the scopes are for lie under them, and nowhere else
+    for scope, kernel in (("pagerank.accumulate", "scatter-add"),
+                          ("pagerank.contrib", "gather"),
+                          ("pagerank.exchange/row_gather", "gather"),
+                          ("pagerank.exchange", "sort")):
+        assert any(f"/{scope}/" in n and n.endswith(kernel)
+                   for n in names), (scope, kernel)
+    assert not any(n.endswith("scatter-add") and "pagerank.exchange" not in n
+                   and "pagerank.accumulate" not in n for n in names)
