@@ -137,6 +137,7 @@ def run_job_a(mesh, total_bytes: int, seed: int, compile_log: CompileLog):
         run_terasort,
         verify_terasort,
     )
+    from sparkrdma_tpu.ops.row_permute import forms_label
     from sparkrdma_tpu.parallel import exchange as exchange_mod
 
     failures: list = []
@@ -198,6 +199,10 @@ def run_job_a(mesh, total_bytes: int, seed: int, compile_log: CompileLog):
         "rows_per_device": cfg.rows_per_device, "out_factor": cfg.out_factor,
         "exchange_impl": impl if n > 1 else NO_EXCHANGE,
         "hlo_ragged_all_to_all_ops": ragged_ops,
+        # the form the rows followed their order in, as the step's trace
+        # chose it (ops/row_permute.py); job B's is its rounds' spans',
+        # job C's its job span's
+        "row_move": forms_label(step.row_moves),
         "plan": {"plane": "device", "rows_per_round": cfg.rows_per_device,
                  "rounds": 1},
         # DATA_PLANE counts dispatched fused steps; on one device a step
@@ -311,6 +316,8 @@ def run_plane(driver, execs, mesh, job, dataplane: str, trace_dir: str):
         "selects": selects,
         "degrades": sum(e["name"] == "exchange.degrade" for e in events),
         "rounds": sum(e["name"] == "exchange.round" for e in events),
+        "row_move": sorted({e["args"]["row_move"] for e in events
+                            if e["name"] == "exchange.round"}),
         "dispatches": exchange_mod.DATA_PLANE["exchanges"] - before,
         "tcp_fetchers_built": built["n"],
         "remote_bytes": sum(r[3] for r in results),
@@ -416,6 +423,7 @@ def run_job_b(mesh, total_bytes: int, seed: int, compile_log: CompileLog,
         "plan": {"plane": select.get("plane"),
                  "rows_per_round": select.get("rows_per_round"),
                  "rounds": dev["rounds"], "reason": select.get("reason")},
+        "row_move": dev["row_move"],
         # DATA_PLANE counts dispatched fused rounds; on one device a
         # round holds no collective
         "fused_rounds_dispatched": dev["dispatches"],
@@ -484,8 +492,8 @@ def run_job_c(mesh, edges_per_chip: int, seed: int, compile_log: CompileLog):
         job.tracer.dump(os.path.join(tmp, "job_c.json"))
         with open(os.path.join(tmp, "job_c.json")) as f:
             events = json.load(f)["traceEvents"]
-    received = next(e["args"]["received"] for e in events
-                    if e["name"] == "pagerank.job")
+    job_args = next(e["args"] for e in events if e["name"] == "pagerank.job")
+    received = job_args["received"]
     counters = {e["name"]: e["args"]["value"] for e in events
                 if e.get("ph") == "C"}
     if received != [graph.num_edges] * PAGERANK_ITERATIONS:
@@ -500,6 +508,7 @@ def run_job_c(mesh, edges_per_chip: int, seed: int, compile_log: CompileLog):
         "edges": graph.num_edges, "vertices": cfg.num_vertices,
         "iterations": PAGERANK_ITERATIONS, "record_bytes": 8,
         "exchange_impl": impl,
+        "row_move": job_args["row_move"],
         "contributions_received": received,
         "recv_fill": counters.get("pagerank.recv_fill"),
         "max_in_degree": counters.get("pagerank.max_in_degree"),

@@ -32,11 +32,13 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from sparkrdma_tpu.ops.row_permute import forms_label
 from sparkrdma_tpu.parallel.exchange import (
     group_by_destination,
     ragged_exchange_shard,
     record_exchange,
     resolve_impl,
+    row_mover,
 )
 from sparkrdma_tpu.utils import trace
 
@@ -93,7 +95,8 @@ def make_pagerank_step(mesh: Mesh, axis_name: str, cfg: PageRankConfig,
     ``pagerank.contrib`` (the two per-edge gathers and the divide),
     ``pagerank.exchange`` (grouping, with its ``row_gather``, and the
     transport) and ``pagerank.accumulate`` (masking, scatter-add,
-    damping).
+    damping). ``step.row_moves`` lists the form the grouping's row move
+    took (``ops.row_permute``), once the step has been traced.
     """
     n = mesh.shape[axis_name]
     impl = resolve_impl(mesh, impl, axis_name)
@@ -102,6 +105,10 @@ def make_pagerank_step(mesh: Mesh, axis_name: str, cfg: PageRankConfig,
     fill = n * WIRE_RECORDS   # records that may be needed to fill groups up
     rows_out = wire_rows(cfg, n)
     slack = rows_out * WIRE_RECORDS - cfg.edges_per_device - fill
+    # the form the grouping's row move took, filled while the step is
+    # traced (ops.row_permute): step.row_moves
+    row_moves: list = []
+    move = row_mover(mesh, row_moves)
 
     @jax.jit
     @functools.partial(shard_map, mesh=mesh,
@@ -141,7 +148,7 @@ def make_pagerank_step(mesh: Mesh, axis_name: str, cfg: PageRankConfig,
                     jnp.uint32)], axis=1)
             dest_dev = jnp.concatenate(
                 [dest_dev, fill_dest, jnp.full(slack, -1, jnp.int32)])
-            grouped, sent = group_by_destination(rows, dest_dev, n)
+            grouped, sent = group_by_destination(rows, dest_dev, n, move)
             wire = jnp.concatenate(
                 [grouped[:, 0].reshape(rows_out, WIRE_RECORDS),
                  grouped[:, 1].reshape(rows_out, WIRE_RECORDS)], axis=1)
@@ -172,6 +179,7 @@ def make_pagerank_step(mesh: Mesh, axis_name: str, cfg: PageRankConfig,
                 jnp.stack([contributions, total]).astype(jnp.int32)[None],
                 overflowed[None])
 
+    step.row_moves = row_moves
     return step
 
 
@@ -280,7 +288,8 @@ class PageRankJob:
     Spans, on ``self.tracer`` (a caller may set one per job, as with the
     engine's): ``pagerank.job`` (``iterations``, ``edges``, ``vertices``;
     at its end ``received``, the contributions delivered in each
-    superstep) around ``pagerank.dispatch`` and ``pagerank.wait``.
+    superstep, and ``row_move``, the form the rows followed their order
+    in) around ``pagerank.dispatch`` and ``pagerank.wait``.
     Counters, per job: ``pagerank.recv_fill`` (most wire rows any device
     received over its receive capacity) and ``pagerank.max_in_degree``.
     """
@@ -316,6 +325,7 @@ class PageRankJob:
                 jax.block_until_ready(ranks)
             received = np.array([np.asarray(r) for r, _ in facts])
             args["received"] = received[:, :, 0].sum(axis=1).tolist()
+            args["row_move"] = forms_label(self._step.row_moves)
             tracer.counter("pagerank.recv_fill",
                            float(received[:, :, 1].max()) / self._capacity,
                            "pagerank")

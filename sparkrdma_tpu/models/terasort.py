@@ -39,10 +39,18 @@ class TeraSortConfig:
     payload_words: int = 24  # 4B key word + 24*4B payload ≈ the classic 100B row
     out_factor: int = 2      # receive headroom (uniform keys -> mild skew)
     # How payload follows its key through a local sort:
-    #   "gather"    — sort (key, iota) then ONE row gather. Measured on
-    #                 v5e: the gather runs at ~1 word/cycle (28.8 ns/row
-    #                 at width 25, ~3.4x the 8.5 ns/row key sort) — it is
-    #                 the step's bottleneck.
+    #   "gather"    — sort (key, iota), then the rows follow the order
+    #                 through ops.row_permute.permute_rows. On the v5e XLA
+    #                 keeps u32[N, 25] column-major (one row is 25 words
+    #                 in 25 different 512-byte sublane rows) and, past the
+    #                 size where operand and result fit VMEM, its gather
+    #                 cost 37.6 ns a row beside a 2.6 ns a row key sort:
+    #                 93 % of the one-chip step (ledger, PR 28). Wide rows
+    #                 at such sizes therefore move as contiguous 128-lane
+    #                 records (pack, one 512-byte copy a record, unpack:
+    #                 6.5 ns a row; PERF.md section 6, PR 29); rows under
+    #                 8 or over 64 words and small rounds stay with jnp.take
+    #                 (row_permute.row_move_form).
     #   "multisort" — every payload column rides the sort network as an
     #                 extra rank-1 lax.sort operand: no gather, but the
     #                 XLA:TPU compile cost grows ~16s per operand and a
@@ -57,8 +65,10 @@ class TeraSortConfig:
     #                 network. Carries the key column W times (2x the
     #                 multisort bytes) but compiles like a 2-operand sort
     #                 and runs lane-parallel.
-    # Which wins is hardware-dependent (gather is latency-bound, the
-    # sorts bandwidth-bound); bench A/Bs via BENCH_SORT_MODE.
+    # On the chip colsort took 3.5x / 2.5x gather's time and multisort
+    # never finished a cold compile (PR 21's bench.py run, ROADMAP
+    # "Recent"); bench A/Bs via BENCH_SORT_MODE, and ROADMAP D8 keeps the
+    # two losers' deletion.
     sort_mode: str = "gather"
 
     @property

@@ -337,11 +337,14 @@ def select_dataplane(mesh, axis_name: str, profile: StageProfile, *,
 # the fused step: partition + exchange + local sort, one shard_map program
 # ---------------------------------------------------------------------------
 
-def _local_sort(rows, keys, sort_mode: str, write_back_keys: bool):
+def _local_sort(rows, keys, sort_mode: str, write_back_keys: bool, move):
     """One local sort of full rows by (pre-masked) keys. The three
     strategies and their trade-offs are documented on
-    ``models.terasort.TeraSortConfig.sort_mode`` (gather is
-    latency-bound, the sorts bandwidth-bound; bench A/Bs them).
+    ``models.terasort.TeraSortConfig.sort_mode``. ``gather`` sorts
+    ``(key, iota)`` and then lets the rows follow the order through
+    ``move(rows, order)``: ``ops.row_permute.permute_rows`` bound to the
+    platform the step compiles for, which picks its data path from that
+    and the shape.
 
     ``keys`` is a TUPLE of u32 key vectors, most significant first —
     one entry for single-word keys (TeraSort), two for the u64 packed
@@ -404,8 +407,7 @@ def _local_sort(rows, keys, sort_mode: str, write_back_keys: bool):
             out = jax.lax.sort(keys + (iota,), num_keys=len(keys) + 1)
             sorted_keys, order = out[0], out[-1]
         with jax.named_scope("row_gather"):
-            sorted_rows = written_back(jnp.take(rows, order, axis=0),
-                                       sorted_keys)
+            sorted_rows = written_back(move(rows, order), sorted_keys)
     return sorted_rows, sorted_keys
 
 
@@ -448,6 +450,11 @@ def make_fused_step(mesh, axis_name: str, row_words: int, *,
     the ``out_factor`` headroom or a dense-slot pair overflow — results
     there are truncated and MUST not be trusted (the engine's remedy:
     degrade the stage to the host dataplane).
+
+    ``step.row_moves`` lists the form each of the step's row moves took
+    (``ops.row_permute``: ``"packed"`` / ``"take"``), in program order. It
+    is filled while the step is traced (its first call or ``lower``) and
+    stays empty under a ``sort_mode`` that moves no rows by an order.
     """
     import jax
     import jax.numpy as jnp
@@ -460,6 +467,7 @@ def make_fused_step(mesh, axis_name: str, row_words: int, *,
         group_by_destination,
         ragged_exchange_shard,
         resolve_transport,
+        row_mover,
     )
 
     if sort_mode not in ("gather", "multisort", "colsort"):
@@ -480,6 +488,10 @@ def make_fused_step(mesh, axis_name: str, row_words: int, *,
     jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     n = mesh.shape[axis_name]
     impl = resolve_transport(mesh, impl, axis_name)
+    # how the step's rows follow an order, and the form each such move took
+    # ("packed" / "take"), filled while the step is traced: step.row_moves
+    row_moves: list = []
+    move = row_mover(mesh, row_moves)
     spec = P(axis_name)
     sentinel = jnp.uint32(0xFFFFFFFF)
     write_back = key_words == 1
@@ -504,7 +516,7 @@ def make_fused_step(mesh, axis_name: str, row_words: int, *,
             keys = tuple(jnp.where(idx < total, k, sentinel)
                          for k in _row_keys(received, key_words))
             sorted_rows = _local_sort(received, keys, sort_mode,
-                                      write_back)[0]
+                                      write_back, move)[0]
         return sorted_rows, recv_counts[None], overflowed[None]
 
     # pallas interpret-mode outputs confuse the vma checker when mixed
@@ -526,7 +538,7 @@ def make_fused_step(mesh, axis_name: str, row_words: int, *,
                 # single-device: no exchange, one sort is the whole job
                 with jax.named_scope("fused.receive_sort"):
                     sorted_rows, _ = _local_sort(rows, keys, sort_mode,
-                                                 write_back)
+                                                 write_back, move)
                 counts = jnp.array([[rows.shape[0]]], dtype=jnp.int32)
                 return sorted_rows, counts, jnp.zeros((1,), bool)
 
@@ -536,7 +548,7 @@ def make_fused_step(mesh, axis_name: str, row_words: int, *,
                 # free — this replaces the separate argsort-by-
                 # destination + gather entirely.
                 grouped, sorted_keys = _local_sort(rows, keys, sort_mode,
-                                                   write_back)
+                                                   write_back, move)
                 # per-destination counts: D-1 binary searches on sorted
                 # keys
                 bounds = jnp.searchsorted(sorted_keys, splitters,
@@ -547,25 +559,26 @@ def make_fused_step(mesh, axis_name: str, row_words: int, *,
                 counts = jnp.diff(bounds).astype(jnp.int32)
             return exchange_and_sort(grouped, counts)
 
-        return step
+    else:
 
-    @jax.jit
-    @functools.partial(shard_map, **shard_kwargs)
-    def step(rows, dest):
-        dest = dest.reshape(-1)
-        if n == 1:
-            valid = dest >= 0
-            with jax.named_scope("fused.receive_sort"):
-                idx_keys = tuple(jnp.where(valid, k, sentinel)
-                                 for k in _row_keys(rows, key_words))
-                sorted_rows, _ = _local_sort(rows, idx_keys, sort_mode,
-                                             write_back)
-            counts = jnp.sum(valid).astype(jnp.int32).reshape(1, 1)
-            return sorted_rows, counts, jnp.zeros((1,), bool)
-        with jax.named_scope("fused.partition"):
-            grouped, counts = group_by_destination(rows, dest, n)
-        return exchange_and_sort(grouped, counts)
+        @jax.jit
+        @functools.partial(shard_map, **shard_kwargs)
+        def step(rows, dest):
+            dest = dest.reshape(-1)
+            if n == 1:
+                valid = dest >= 0
+                with jax.named_scope("fused.receive_sort"):
+                    idx_keys = tuple(jnp.where(valid, k, sentinel)
+                                     for k in _row_keys(rows, key_words))
+                    sorted_rows, _ = _local_sort(rows, idx_keys, sort_mode,
+                                                 write_back, move)
+                counts = jnp.sum(valid).astype(jnp.int32).reshape(1, 1)
+                return sorted_rows, counts, jnp.zeros((1,), bool)
+            with jax.named_scope("fused.partition"):
+                grouped, counts = group_by_destination(rows, dest, n, move)
+            return exchange_and_sort(grouped, counts)
 
+    step.row_moves = row_moves
     return step
 
 
@@ -629,6 +642,7 @@ def run_fused_exchange_rounds(mesh, axis_name: str, blocks,
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from sparkrdma_tpu.ops.row_permute import forms_label
     from sparkrdma_tpu.parallel.exchange import record_exchange
 
     tracer = tracer if tracer is not None else trace_mod.NULL
@@ -658,13 +672,15 @@ def run_fused_exchange_rounds(mesh, axis_name: str, blocks,
         collective; jax dispatch is async — no blocking here."""
         with tracer.span("exchange.round", "exchange", round=r,
                          rows=len(chunk),
-                         bytes=per_round * (row_words + 1) * 4):
+                         bytes=per_round * (row_words + 1) * 4) as args:
             rows_p = np.zeros((per_round, row_words), np.uint32)
             rows_p[:len(chunk)] = chunk
             dest_p = np.full(per_round, -1, np.int32)
             dest_p[:len(chunk)] = dchunk
             out = step(stage_to_device(rows_p, sharding),
                        stage_to_device(dest_p, sharding))
+            # what the step's trace chose, known once it has been called
+            args["row_move"] = forms_label(step.row_moves)
         record_exchange(len(chunk))
         return r, out
 

@@ -58,6 +58,8 @@ import numpy as np
 from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from sparkrdma_tpu.ops.row_permute import permute_rows
+
 # Host-side dispatch tally for the ICI data plane. Callers that launch a
 # collective exchange (mesh_service, models) record here so tests and the
 # engine can assert that a job's shuffle bytes actually crossed the mesh
@@ -303,7 +305,7 @@ def _gather_exchange(data: jnp.ndarray, mat: jnp.ndarray, my: jnp.ndarray,
 
 
 def group_by_destination(data: jnp.ndarray, dest: jnp.ndarray,
-                         num_partitions: int,
+                         num_partitions: int, move=permute_rows,
                          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Stable local grouping of rows by destination partition.
 
@@ -313,13 +315,18 @@ def group_by_destination(data: jnp.ndarray, dest: jnp.ndarray,
     ``dest >= num_partitions`` or ``dest < 0`` are treated as padding: they
     sort to the end and don't count.
 
+    The rows follow the order through ``move(rows, order)``:
+    ``ops.row_permute.permute_rows``, which a caller that knows its mesh
+    binds to the mesh's platform (``row_mover``); unbound it is
+    ``jnp.take``.
+
     Returns ``(grouped_rows, counts)`` with ``counts: i32[num_partitions]``.
     """
     dest = jnp.where((dest < 0) | (dest >= num_partitions),
                      num_partitions, dest.astype(jnp.int32))
     order = jnp.argsort(dest, stable=True)
     with jax.named_scope("row_gather"):   # a device profile's kernel name
-        grouped = jnp.take(data, order, axis=0)
+        grouped = move(data, order)
     counts = jnp.bincount(dest, length=num_partitions + 1)[:num_partitions]
     return grouped, counts.astype(jnp.int32)
 
@@ -327,11 +334,12 @@ def group_by_destination(data: jnp.ndarray, dest: jnp.ndarray,
 def shuffle_shard(data: jnp.ndarray, dest: jnp.ndarray, axis_name: str,
                   num_devices: int,
                   output: Optional[jnp.ndarray] = None,
-                  impl: str = "native"):
-    """Full per-shard shuffle step: group locally by destination device,
-    then ragged-exchange. Returns (received, recv_counts, recv_offsets,
-    overflowed) — see ``ragged_exchange_shard``."""
-    grouped, counts = group_by_destination(data, dest, num_devices)
+                  impl: str = "native", move=permute_rows):
+    """Full per-shard shuffle step: group locally by destination device
+    (``move``: see ``group_by_destination``), then ragged-exchange.
+    Returns (received, recv_counts, recv_offsets, overflowed) — see
+    ``ragged_exchange_shard``."""
+    grouped, counts = group_by_destination(data, dest, num_devices, move)
     return ragged_exchange_shard(grouped, counts, axis_name, output, impl)
 
 
@@ -376,6 +384,20 @@ def _native_compiles(mesh: Mesh, axis_name: str) -> Tuple[bool, str]:
     return True, ""
 
 
+def mesh_platform(mesh: Mesh) -> str:
+    """The platform a program on ``mesh`` compiles for (a described
+    topology's devices say "tpu" in a process whose backend is the CPU)."""
+    return next(iter(mesh.devices.flat)).platform
+
+
+def row_mover(mesh: Mesh, chosen: Optional[list] = None):
+    """``ops.row_permute.permute_rows`` for a step compiled for ``mesh``:
+    ``move(rows, order)``. ``chosen`` gains the form of each move traced
+    through it."""
+    return functools.partial(permute_rows, platform=mesh_platform(mesh),
+                             chosen=chosen)
+
+
 def resolve_impl(mesh: Mesh, impl: str = "auto",
                  axis_name: Optional[str] = None) -> str:
     """``auto`` -> native on TPU meshes whose compiler supports the
@@ -387,8 +409,7 @@ def resolve_impl(mesh: Mesh, impl: str = "auto",
     everywhere in this package)."""
     if impl != "auto":
         return impl
-    platform = next(iter(mesh.devices.flat)).platform
-    if platform != "tpu":
+    if mesh_platform(mesh) != "tpu":
         return "gather"
     axis = axis_name or mesh.axis_names[-1]
     ok, reason = _native_compiles(mesh, axis)
@@ -662,8 +683,7 @@ def chunked_exchange(mesh: Mesh, axis_name: str, grouped: np.ndarray,
     # batch of rounds SIGABRTs. On TPU collectives run device-side (the
     # host thread is not parked), so a deeper pipeline is safe and keeps
     # dispatch off the critical path.
-    platform = next(iter(mesh.devices.flat)).platform
-    sync_every = 1 if platform == "cpu" else 8
+    sync_every = 1 if mesh_platform(mesh) == "cpu" else 8
     for r in range(num_rounds):
         acc = round_acc(grouped_d, counts_d, r, acc)
         if (r + 1) % sync_every == 0:
@@ -716,6 +736,7 @@ def make_shuffle_exchange(mesh: Mesh, axis_name: str, impl: str = "auto",
     spec = P(axis_name)
     n = mesh.shape[axis_name]
     impl = resolve_transport(mesh, impl, axis_name)
+    move = row_mover(mesh)
 
     # pallas interpret-mode outputs confuse the vma checker when mixed
     # with collectives; disable it ONLY for the ring transports so the
@@ -731,7 +752,7 @@ def make_shuffle_exchange(mesh: Mesh, axis_name: str, impl: str = "auto",
         output = jnp.zeros((data.shape[0] * out_factor,) + data.shape[1:],
                            dtype=data.dtype)
         received, recv_counts, recv_offsets, overflowed = shuffle_shard(
-            data, dest, axis_name, n, output=output, impl=impl)
+            data, dest, axis_name, n, output=output, impl=impl, move=move)
         return received, recv_counts[None], recv_offsets[None], \
             overflowed[None]
 

@@ -347,6 +347,9 @@ def test_round_overlap_traces(mesh):
     assert len(spans) == rounds
     assert len(overlaps) == rounds - 1, \
         "rounds did not overlap (no double buffering)"
+    # every round says which form its step's rows followed their order in
+    # (what the step's trace chose: a CPU mesh, so jnp.take both times)
+    assert {e["args"]["row_move"] for e in spans} == {"take"}
     # sequential mode: same bytes, zero overlap instants
     res_s, _, spans_s, overlaps_s = run(False)
     assert len(spans_s) == rounds and not overlaps_s
@@ -603,3 +606,53 @@ def test_fused_step_hlo_carries_the_kernel_scopes(devices, partition):
     # the names are metadata: a cached executable of a build without them
     # must not be served for this program
     assert jax.config.jax_compilation_cache_include_metadata_in_key
+
+
+@pytest.mark.parametrize("devices,partition,key_words", [
+    (1, "range", 1), (4, "range", 1), (1, "dest", 2), (4, "dest", 2)])
+def test_fused_step_packed_form_is_byte_identical(monkeypatch, devices,
+                                                  partition, key_words):
+    """``make_fused_step`` with the packed row permute forced (its kernels
+    interpreted) returns what the ``take`` form returns, byte for byte:
+    sorted rows with their padding rows, counts, overflow flags."""
+    from sparkrdma_tpu.ops import row_permute
+    from sparkrdma_tpu.parallel.device_plane import make_fused_step
+
+    mesh_n = Mesh(np.array(jax.devices()[:devices]), ("shuffle",))
+    rng = np.random.default_rng(SEED + 29)
+    n = devices * 700
+    rows = rng.integers(0, 2**32, (n, 25), dtype=np.uint32)
+    rows[::7, 0] = rows[3, 0]           # duplicate keys: ties by position
+    args = [rows]
+    if partition == "dest":
+        dest = rng.integers(0, devices, n).astype(np.int32)
+        dest[::11] = -1                 # padding rows, not sent
+        args.append(dest)
+
+    def run():
+        make_fused_step.cache_clear()   # the form is chosen while tracing
+        # a ring transport: interpreted kernels need check_vma off
+        step = make_fused_step(mesh_n, "shuffle", 25, impl="ring_interpret",
+                               partition=partition, key_words=key_words)
+        assert step.row_moves == []     # filled by the trace
+        return [np.asarray(x) for x in step(*args)], step
+
+    # one move on one device (the single sort); across devices one before
+    # the exchange (the key sort's, or the grouping's) and the receive sort's
+    moves = 1 if devices == 1 else 2
+    try:
+        want, step = run()
+        assert step.row_moves == ["take"] * moves
+        monkeypatch.setattr(row_permute, "row_move_form",
+                            lambda n_rows, row_words, platform: "packed")
+        got, step = run()
+        assert step.row_moves == ["packed"] * moves
+        lowered = step.lower(*args)
+        assert step.row_moves == ["packed"] * moves     # the same trace
+    finally:
+        make_fused_step.cache_clear()
+    hlo = lowered.as_text(debug_info=True)
+    for part in ("pack", "permute", "unpack"):
+        assert f"row_gather/{part}" in hlo
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)

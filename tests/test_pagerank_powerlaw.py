@@ -166,7 +166,9 @@ def test_job_spans_and_counters(tmp_path):
     job = spans["pagerank.job"]
     assert job["args"] == {"iterations": 3, "edges": len(edges) - 10,
                            "vertices": num_v,
-                           "received": [len(edges) - 10] * 3}
+                           "received": [len(edges) - 10] * 3,
+                           # 8-byte rows: jnp.take on any platform
+                           "row_move": "take"}
     for inner in ("pagerank.dispatch", "pagerank.wait"):
         assert job["ts"] <= spans[inner]["ts"]
         assert (spans[inner]["ts"] + spans[inner]["dur"]

@@ -264,3 +264,140 @@ def test_native_parity_where_backend_executes():
     want = jax.block_until_ready(oracle(data_d, dest_d))
     for g, w in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+# ---------------------------------------------------------------------------
+# the packed row permute (ops/row_permute.py) at the benchmark's shapes
+# ---------------------------------------------------------------------------
+
+MOSAIC_CALL = 'custom_call_target="tpu_custom_call"'
+PERMUTE_SCOPES = ("row_gather/pack", "row_gather/permute",
+                  "row_gather/unpack")
+
+
+@functools.lru_cache(maxsize=1)
+def _v5e_2x2():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc("v5e:2x2").devices, ""
+    except Exception as e:  # noqa: BLE001 — no libtpu compiler in this env
+        return None, str(e)
+
+
+@pytest.fixture
+def v5e_host():
+    """The four described chips of one v5e host (the benchmark's)."""
+    devices, err = _v5e_2x2()
+    if devices is None:
+        pytest.skip(f"TPU AOT topology unavailable: {err[:120]}")
+    return devices
+
+
+def _fused_step_args(devices, rows_per_chip, row_words, partition):
+    from sparkrdma_tpu.parallel.device_plane import make_fused_step
+
+    mesh = Mesh(np.array(devices), (AXIS,))
+    sh = NamedSharding(mesh, P(AXIS))
+    n = len(devices) * rows_per_chip
+    args = [jax.ShapeDtypeStruct((n, row_words), jnp.uint32, sharding=sh)]
+    if partition == "dest":
+        args.append(jax.ShapeDtypeStruct((n,), jnp.int32, sharding=sh))
+    key_words = 2 if partition == "dest" else 1
+    step = make_fused_step(mesh, AXIS, row_words, partition=partition,
+                           key_words=key_words)
+    return step, args
+
+
+# the compiler's own count of a chip's temporaries. One chip: the packed
+# operand and the packed result, 1.37 GB each (2,792,655,872 when written);
+# a padded row-major copy of the rows alone would be 5.5 GB. Four chips:
+# the parent's step already counts 8,247,000,064 (the receive buffer rides
+# the collective at 128 lanes); the packed form added 612,864 to it.
+@pytest.mark.parametrize("chips,rows_per_chip,gathers,temp_limit", [
+    (1, 10_737_418, 1, 3_000_000_000),      # fused_1chip
+    (4, 5_368_709, 2, 8_400_000_000),       # fused_4chip
+])
+def test_fused_step_compiles_with_the_packed_permute(
+        v5e_host, chips, rows_per_chip, gathers, temp_limit):
+    """At the fused cells' shapes every row gather is the three Mosaic
+    kernels, named under ``row_gather``, and no 128-lane-padded copy of
+    the rows appears among the temporaries."""
+    step, args = _fused_step_args(v5e_host[:chips], rows_per_chip, 25,
+                                  "range")
+    compiled = step.lower(*args).compile()
+    assert step.row_moves == ["packed"] * gathers
+    calls = [line for line in compiled.as_text().splitlines()
+             if MOSAIC_CALL in line]
+    assert len(calls) == 3 * gathers
+    for scope in PERMUTE_SCOPES:
+        assert sum(scope in line for line in calls) == gathers, scope
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_limit
+
+
+@pytest.mark.parametrize("rows_per_chip,row_words,partition", [
+    (111_848, 25, "dest"),      # a round of spi_device_1chip: under the edge
+    (16_777_280, 2, "dest"),    # pagerank_1chip's rows: too narrow
+])
+def test_fused_step_without_the_packed_permute(v5e_host, rows_per_chip,
+                                               row_words, partition):
+    """Where ``row_move_form`` says ``take`` the step holds no Mosaic call.
+    The form is chosen while tracing, so the lowered text says it all (the
+    compile, a minute of sort networks, would add nothing)."""
+    step, args = _fused_step_args(v5e_host[:1], rows_per_chip, row_words,
+                                  partition)
+    text = step.lower(*args).as_text(debug_info=True)
+    assert step.row_moves == ["take"]
+    assert "tpu_custom_call" not in text
+    assert "row_gather" in text
+
+
+@pytest.mark.parametrize("row_words,mosaic_calls", [
+    (8, 3), (24, 3), (33, 3), (64, 3), (65, 0), (100, 0), (128, 0)])
+def test_permute_rows_compiles_at_every_width_class(v5e_host, row_words,
+                                                    mosaic_calls):
+    """The three kernels compile for the chip at 4 and at 2 records to a
+    packed row, not only at the cells' 25 words; over 64 words the program
+    is XLA's gather. (At 1 record to a row the permute's index block was
+    512 words, which Mosaic refuses beside XLA's 1,024-word SMEM tiles:
+    found on the chip, PR 29, where jnp.take was as fast at those
+    widths.)"""
+    from jax.sharding import SingleDeviceSharding
+
+    from sparkrdma_tpu.ops.row_permute import permute_rows
+
+    one = SingleDeviceSharding(v5e_host[0])
+    n = 2_000_003       # past the edge at 8 words, and no multiple of a block
+    rows = jax.ShapeDtypeStruct((n, row_words), jnp.uint32, sharding=one)
+    order = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one)
+    chosen = []
+    compiled = jax.jit(
+        lambda r, o: permute_rows(r, o, "tpu", chosen)).lower(
+            rows, order).compile()
+    assert chosen == ["packed" if mosaic_calls else "take"]
+    assert compiled.as_text().count(MOSAIC_CALL) == mosaic_calls
+
+
+def test_pagerank_step_holds_no_mosaic_call(v5e_host):
+    """``group_by_destination`` on 8-byte rows stays with ``jnp.take`` on
+    a TPU mesh too."""
+    from sparkrdma_tpu.parallel.exchange import (
+        group_by_destination,
+        row_mover,
+    )
+
+    mesh = Mesh(np.array(v5e_host[:1]), (AXIS,))
+    sh = NamedSharding(mesh, P(AXIS))
+
+    @jax.jit
+    @functools.partial(shard_map, mesh=mesh, in_specs=(P(AXIS), P(AXIS)),
+                       out_specs=P(AXIS))
+    def grouped(rows, dest):
+        return group_by_destination(rows, dest, 1, move)[0]
+
+    chosen = []
+    move = row_mover(mesh, chosen)
+    rows = jax.ShapeDtypeStruct((16_777_280, 2), jnp.uint32, sharding=sh)
+    dest = jax.ShapeDtypeStruct((16_777_280,), jnp.int32, sharding=sh)
+    assert "tpu_custom_call" not in grouped.lower(rows, dest).as_text()
+    assert chosen == ["take"]
