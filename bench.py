@@ -37,8 +37,8 @@ def _run_phase(env: dict, label: str, env_overrides: dict,
     """One budgeted inner-bench subprocess; returns (result, failure).
 
     The phase structure exists because one slow stage must never cost a
-    different stage its record: round 3 lost the gather-mode hardware
-    number to the numpy baseline + secondary compiles sharing its budget.
+    different stage its record: round 3 lost the step's hardware number
+    to the numpy baseline + secondary compiles sharing its budget.
     A phase that printed its record but exited non-zero (a secondary
     wrote an ``*_error`` key) returns both.
     """
@@ -63,30 +63,10 @@ def _run_phase(env: dict, label: str, env_overrides: dict,
     return result, f"{label}: exit={proc.returncode}: {stderr[-400:]}"
 
 
-def _run_inner(env: dict, mode: str, timeout_s: int,
-               light: bool) -> tuple[Optional[dict], str]:
-    """One sort-mode run. ``light`` strips the baseline + secondary
-    workloads (they run in their own phase, see _run_secondary)."""
-    overrides = {"BENCH_SORT_MODE": mode}
-    if light:
-        overrides["BENCH_LIGHT"] = "1"
-    return _run_phase(env, mode, overrides, timeout_s)
-
-
-def _run_secondary(env: dict, timeout_s: int) -> tuple[Optional[dict], str]:
-    """Baseline + secondary workloads in their own budgeted subprocess."""
-    env = dict(env)
-    env.pop("BENCH_SORT_MODE", None)
-    return _run_phase(env, "secondary", {"BENCH_SECONDARY": "1"}, timeout_s)
-
-
 def _run_with_watchdog() -> int:
-    """Run the real bench in per-mode subprocesses with hard timeouts.
-
-    One sort mode's compile can be pathologically slow (multisort's
-    26-operand sort network: ~16s/operand cold — round 2 lost its whole
-    hardware record to that single compile), so EACH sort mode runs in
-    its own subprocess with its own budget. The persistent XLA
+    """Run the real bench in two subprocesses with hard timeouts: the
+    primary phase (the TeraSort step's timing alone: ``BENCH_LIGHT``),
+    then the baseline and the secondary workloads. The persistent XLA
     compilation cache (enabled in main()) makes warm reruns cheap.
 
     A chip belongs to one process at a time: this parent never imports
@@ -94,43 +74,17 @@ def _run_with_watchdog() -> int:
     process holds the chip at any moment. A failed phase fails the run.
     """
     env = dict(os.environ)
-    mode_timeout_s = int(env.get("BENCH_TIMEOUT_S", "540"))
-    results: dict = {}
-    failures = []
-    # multisort's 26-operand sort network never finished a cold compile
-    # within 900s on the XLA:TPU compiler, and a failed phase fails the
-    # run: it is attempted only when the operator grants it a budget
-    # (BENCH_TIMEOUT_MULTISORT_S) or pins it (BENCH_SORT_MODE).
-    ms_timeout_s = int(env.get("BENCH_TIMEOUT_MULTISORT_S",
-                               str(mode_timeout_s)))
-    plan = [("gather", mode_timeout_s), ("colsort", mode_timeout_s)]
-    if "BENCH_TIMEOUT_MULTISORT_S" in env:
-        plan.append(("multisort", ms_timeout_s))
-    if env.get("BENCH_SORT_MODE"):
-        # operator pinned a mode: run exactly that one (e.g. skipping the
-        # multisort attempt entirely when its compile isn't cached yet),
-        # with the mode's own budget knob still honored
-        pinned = env["BENCH_SORT_MODE"]
-        plan = [(pinned,
-                 ms_timeout_s if pinned == "multisort" else mode_timeout_s)]
-    for mode, budget in plan:
-        # every mode runs "light" (terasort timing only); the baseline and
-        # secondary workloads get their own subprocess + budget below
-        res, failure = _run_inner(env, mode, budget, light=True)
-        if failure:
-            failures.append(failure)
-        else:
-            results[mode] = res
-    if not results:
-        print("bench.py: every sort mode failed: " + "; ".join(failures),
+    timeout_s = int(env.get("BENCH_TIMEOUT_S", "540"))
+    result, failure = _run_phase(env, "primary", {"BENCH_LIGHT": "1"},
+                                 timeout_s)
+    if failure:
+        print("bench.py: the primary phase failed: " + failure,
               file=sys.stderr)
         return 1
-    best_mode = max(results, key=lambda m: results[m]["value"])
-    result = results[best_mode]
     detail = result["detail"]
-    sec_timeout_s = int(env.get("BENCH_TIMEOUT_SECONDARY_S",
-                                str(mode_timeout_s)))
-    sec, sec_failure = _run_secondary(env, sec_timeout_s)
+    sec_timeout_s = int(env.get("BENCH_TIMEOUT_SECONDARY_S", str(timeout_s)))
+    sec, sec_failure = _run_phase(env, "secondary",
+                                  {"BENCH_SECONDARY": "1"}, sec_timeout_s)
     if sec is not None:
         for key, val in sec["detail"].items():
             if detail.get(key) is None:  # missing or a light run's null
@@ -139,19 +93,9 @@ def _run_with_watchdog() -> int:
             result["vs_baseline"] = round(
                 detail["cpu_baseline_s"] / detail["tpu_step_s"], 3)
     if sec_failure:
-        failures.append(sec_failure)
-    detail["sort_mode"] = best_mode
-    detail["sort_mode_step_s"] = {
-        m: r["detail"]["sort_mode_step_s"][m] for m, r in results.items()}
-    detail["sort_mode_gbps"] = {m: r["value"] for m, r in results.items()}
-    for m, r in results.items():
-        lat = r["detail"].get("tpu_step_latency_s")
-        if lat is not None:
-            detail.setdefault("sort_mode_latency_s", {})[m] = lat
-    if failures:
-        detail["mode_failures"] = failures
+        detail["mode_failures"] = [sec_failure]
     print(json.dumps(result))
-    return 1 if failures else 0
+    return 1 if sec_failure else 0
 
 
 def _bench_secondary(detail: dict, prefix: str, rate_key: str, build,
@@ -611,7 +555,7 @@ def _bench_topo_exchange(detail: dict) -> None:
         from sparkrdma_tpu.shuffle.topo_bench import run_topo_microbench
 
         # the same env knobs _round_provenance records steer the run
-        # (BENCH_IMPL / BENCH_SORT_MODE precedent): slice count from
+        # (BENCH_IMPL precedent): slice count from
         # BENCH_SLICE_TOPOLOGY ("N" form), cost ratio from the
         # coefficient pair — so recorded topology matches what ran
         kw = {}
@@ -965,10 +909,8 @@ def main() -> int:
 
     from sparkrdma_tpu.utils.compile_cache import enable_compile_cache
 
-    # Persistent compilation cache: the 26-operand multisort network costs
-    # ~400s to compile cold on the XLA:TPU compiler but replays from cache
-    # in seconds — without this, one cold compile eats the whole per-mode
-    # budget.
+    # Persistent compilation cache: a cold fused step compiles in ~40 s
+    # at 1 GiB and replays from the cache in under a second.
     enable_compile_cache()
 
     devs = jax.devices()
@@ -996,7 +938,7 @@ def main() -> int:
     if os.environ.get("BENCH_SECONDARY") == "1":
         # baseline + secondary phase: no terasort timing at all — this
         # subprocess's budget belongs to the numpy baseline and the three
-        # secondary workload compiles (see _run_secondary)
+        # secondary workload compiles (see _run_with_watchdog)
         detail = {}
         cpu_dt, was_cached = _cpu_baseline(size_mb, n, out_factor=out_factor)
         detail["cpu_baseline_s"] = round(cpu_dt, 4)
@@ -1009,77 +951,58 @@ def main() -> int:
                           "unit": "", "detail": detail}))
         return _phase_exit(detail)
 
-    # A/B the local-sort strategies on hardware (gather is latency-bound,
-    # the sorts bandwidth-bound — see TeraSortConfig.sort_mode); the best
-    # one is the headline, both are recorded.
-    env_mode = os.environ.get("BENCH_SORT_MODE", "")
-    modes = [env_mode] if env_mode else ["gather", "colsort"]
     impl = os.environ.get("BENCH_IMPL", "auto")
-    per_mode = {}
-    per_mode_latency = {}
-    per_mode_times = {}
-    rows_d = None
-    _progress(f"inner start: devices={n} platform={devs[0].platform} modes={modes}")
-    for mode in modes:
-        mode_cfg = TeraSortConfig(rows_per_device=rows_per_device,
-                                  payload_words=24, out_factor=out_factor,
-                                  sort_mode=mode)
-        if rows_d is None:
-            # generate the uniform-random dataset ON DEVICE: loading it
-            # is set-up, not what's being measured
-            import functools as _ft
+    cfg = TeraSortConfig(rows_per_device=rows_per_device, payload_words=24,
+                         out_factor=out_factor)
+    _progress(f"inner start: devices={n} platform={devs[0].platform}")
+    # generate the uniform-random dataset ON DEVICE: loading it is
+    # set-up, not what's being measured
+    import functools
 
-            import jax.numpy as jnp
+    import jax.numpy as jnp
 
-            shape = (n * rows_per_device, 1 + mode_cfg.payload_words)
+    shape = (n * rows_per_device, 1 + cfg.payload_words)
 
-            @_ft.partial(jax.jit, out_shardings=NamedSharding(
-                mesh, P("shuffle")))
-            def _gen():
-                return jax.random.bits(jax.random.PRNGKey(0), shape,
-                                       jnp.uint32)
+    @functools.partial(jax.jit,
+                       out_shardings=NamedSharding(mesh, P("shuffle")))
+    def _gen():
+        return jax.random.bits(jax.random.PRNGKey(0), shape, jnp.uint32)
 
-            rows_d = jax.block_until_ready(_gen())
-            _progress("on-device generation done")
-        step = make_terasort_step(mesh, "shuffle", mode_cfg, impl=impl)
-        jax.block_until_ready(step(rows_d))
-        _progress(f"{mode}: warmup done")
-        # per-step latency: host-synced each step (the single-round cost
-        # a caller sees)
-        times = []
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            out, counts, overflowed = jax.block_until_ready(step(rows_d))
-            times.append(time.perf_counter() - t0)
-        # steady-state throughput: keep TWO steps in flight (double
-        # buffering), syncing step i-1 while step i runs, exactly as the
-        # pipelined streamed runs do (run_terasort_streamed). Depth is
-        # capped at 2 on purpose: unbounded dispatch queues reps x (output
-        # + sort workspace) on the device at once, which OOMed the chip
-        # at the 1 GiB scale.
+    rows_d = jax.block_until_ready(_gen())
+    _progress("on-device generation done")
+    step = make_terasort_step(mesh, "shuffle", cfg, impl=impl)
+    jax.block_until_ready(step(rows_d))
+    _progress("warmup done")
+    # per-step latency: host-synced each step (the single-round cost a
+    # caller sees)
+    times = []
+    for _ in range(reps):
         t0 = time.perf_counter()
-        prev = None
-        for _ in range(reps):
-            out, counts, overflowed = step(rows_d)
-            if prev is not None:
-                jax.block_until_ready(prev)
-            prev = counts
-        jax.block_until_ready(prev)
-        pipelined = (time.perf_counter() - t0) / reps
-        _progress(f"{mode}: timed latency={min(times):.3f}s pipelined={pipelined:.3f}s")
-        assert not np.asarray(overflowed).any(), \
-            "receive-buffer overflow in bench"
-        per_mode[mode] = pipelined
-        per_mode_latency[mode] = min(times)
-        per_mode_times[mode] = times
-    best_mode = min(per_mode, key=per_mode.get)
-    tpu_dt = per_mode[best_mode]
+        out, counts, overflowed = jax.block_until_ready(step(rows_d))
+        times.append(time.perf_counter() - t0)
+    # steady-state throughput: keep TWO steps in flight (double
+    # buffering), syncing step i-1 while step i runs, exactly as the
+    # pipelined streamed runs do (run_terasort_streamed). Depth is
+    # capped at 2 on purpose: unbounded dispatch queues reps x (output
+    # + sort workspace) on the device at once, which OOMed the chip
+    # at the 1 GiB scale.
+    t0 = time.perf_counter()
+    prev = None
+    for _ in range(reps):
+        out, counts, overflowed = step(rows_d)
+        if prev is not None:
+            jax.block_until_ready(prev)
+        prev = counts
+    jax.block_until_ready(prev)
+    tpu_dt = (time.perf_counter() - t0) / reps
+    _progress(f"timed latency={min(times):.3f}s pipelined={tpu_dt:.3f}s")
+    assert not np.asarray(overflowed).any(), \
+        "receive-buffer overflow in bench"
     total_bytes = rows_d.nbytes
 
     # spot-verify on a subsample to keep bench time bounded
     small_cfg = TeraSortConfig(rows_per_device=4096, payload_words=24,
-                               out_factor=out_factor,
-                               sort_mode=best_mode)
+                               out_factor=out_factor)
     small_rows = generate_rows(small_cfg, n, seed=1)
     small_step = make_terasort_step(mesh, "shuffle", small_cfg, impl=impl)
     s_out, s_counts, _ = jax.block_until_ready(
@@ -1089,8 +1012,8 @@ def main() -> int:
 
     light = os.environ.get("BENCH_LIGHT") == "1"
     if light:
-        # a sort-mode run under the watchdog: the baseline belongs to the
-        # separate secondary phase (merged back in by the watchdog)
+        # the primary phase under the watchdog: the baseline belongs to
+        # the separate secondary phase (merged back in by the watchdog)
         cpu_dt = None
     else:
         # CPU baseline: identical pipeline, numpy, same distribution (the
@@ -1110,14 +1033,12 @@ def main() -> int:
         "cpu_baseline_s": round(cpu_dt, 4) if cpu_dt else None,
         "platform": devs[0].platform,
         "device_kind": devs[0].device_kind,
-        "sort_mode": best_mode,
-        "sort_mode_step_s": {m: round(t, 4) for m, t in per_mode.items()},
-        "tpu_step_latency_s": round(per_mode_latency[best_mode], 4),
+        "tpu_step_latency_s": round(min(times), 4),
         # repetitions + spread so a few-percent swing between rounds is
         # attributable (host noise vs real regression)
         "reps": reps,
-        "step_s_mean": round(float(np.mean(per_mode_times[best_mode])), 4),
-        "step_s_std": round(float(np.std(per_mode_times[best_mode])), 4),
+        "step_s_mean": round(float(np.mean(times)), 4),
+        "step_s_std": round(float(np.std(times)), 4),
         "data_gen": "on-device jax.random",
         # what actually ran, not the request: "auto" resolves per mesh
         "exchange_impl": exchange_impl,

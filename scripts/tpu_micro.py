@@ -5,9 +5,8 @@ non-zero anywhere else; ``sort`` runs anywhere, and off the chip its
 numbers mean nothing.
 
 ``python scripts/tpu_micro.py [sort] [n_rows]``
-    the two phases of the gather sort mode apart across row widths — the
-    (key, iota) sort and the row gather — plus narrow-payload multisort
-    scaling.
+    the two phases of the local sort apart across row widths: the
+    (key, iota) sort and the row gather.
 
 ``python scripts/tpu_micro.py rowmove [out.json]``
     the row move's sweep, N x W x form (``PERF.md`` section 6, PR 29):
@@ -88,26 +87,6 @@ def sort_main(n_rows):
         bw = rows.nbytes * 2 / dt / 1e9
         log(f"gather width={width:3d}: {dt*1e3:7.1f} ms "
             f"({dt/n_rows*1e9:6.2f} ns/row, {bw:5.1f} GB/s r+w)")
-        del rows
-
-    # multisort scaling in payload operand count (compile can explode at
-    # high operand counts: bound each with an alarm)
-    for width in (2, 4, 8):
-        rows = jnp.asarray(
-            rng.integers(0, 2**32, (n_rows, width), dtype=np.uint32))
-
-        def ms(k, r):
-            cols = tuple(r[:, j] for j in range(r.shape[1]))
-            out = jax.lax.sort((k,) + cols, num_keys=1)
-            return jnp.stack(out[1:], axis=1)
-
-        t0 = time.perf_counter()
-        try:
-            dt = timeit(ms, keys, rows)
-            log(f"multisort width={width}: {dt*1e3:.1f} ms "
-                f"(compile+warm {time.perf_counter()-t0:.0f}s)")
-        except Exception as e:  # noqa: BLE001
-            log(f"multisort width={width}: failed {e}")
         del rows
 
 

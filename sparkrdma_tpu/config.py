@@ -503,24 +503,16 @@ _KEYS = [
          doc="Per-device HBM byte budget for one fused exchange round: "
              "rounds auto-size to rows_per_round = budget / "
              "(row_bytes * (2 + 2*out_factor)) — input + grouped copy "
-             "+ receive + sorted copy — replacing the static "
-             "mesh_rows_per_round knob (still honored when set, "
-             "deprecated). Stages whose bytes fit one round run as a "
-             "single fused step; larger stages stream double-buffered "
-             "rounds (round k+1's collective dispatches while round "
-             "k's on-device sort runs)."),
+             "+ receive + sorted copy. Stages whose bytes fit one round "
+             "run as a single fused step; larger stages stream "
+             "double-buffered rounds (round k+1's collective dispatches "
+             "while round k's on-device sort runs)."),
     _Key("request_deadline_ms", 0, "int", 0, 3600_000,
          doc="Per-request completion deadline on the control plane "
              "(request/AsyncFetch waits); 0 = fall back to "
              "connect_timeout_ms. A response landing after the deadline is "
              "routed to the orphan path so flow-control credits still "
              "heal."),
-    _Key("mesh_rows_per_round", 0, "int", 0, 1 << 31,
-         doc="DEPRECATED: static per-device rows per fused exchange "
-             "round. 0 (the default) lets rounds auto-size from "
-             "device_hbm_budget — the preferred sizing; a nonzero value "
-             "still pins the round size (one deprecation warning per "
-             "process) so mixed-version configs stay parseable."),
     # --- tenancy / multi-tenant service (TPU-only: shuffle/tenancy.py,
     # docs/CONFIG.md "Tenancy")
     _Key("fair_share_serving", True, "bool",

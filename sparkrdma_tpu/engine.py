@@ -197,8 +197,7 @@ class DAGEngine:
                  speculation: bool = False,
                  speculation_multiplier: float = 1.5,
                  mesh=None, mesh_axis: str = "shuffle",
-                 mesh_impl: str = "auto", mesh_rows_per_round: int = 0,
-                 dataplane: str = "auto",
+                 mesh_impl: str = "auto", dataplane: str = "auto",
                  device_hbm_budget: int = 0,
                  dist_mesh_axis: Optional[str] = None,
                  dist_rows_per_round: int = 0,
@@ -216,14 +215,12 @@ class DAGEngine:
         # residency, estimated bytes vs the HBM budget, topology support)
         # rather than a flag; `dataplane` overrides it ("device"/"host"),
         # and a stage whose exchange overflows or loses an executor
-        # mid-stage degrades to the host dataplane by itself.
-        # mesh_rows_per_round > 0 pins the round size (DEPRECATED: rounds
-        # are auto-sized from device_hbm_budget / the device_hbm_budget
-        # conf key — see docs/CONFIG.md "Device exchange").
+        # mid-stage degrades to the host dataplane by itself. Rounds
+        # are sized from device_hbm_budget (the argument, else the conf
+        # key — see docs/CONFIG.md "Device exchange").
         self.mesh = mesh
         self.mesh_axis = mesh_axis
         self.mesh_impl = mesh_impl
-        self.mesh_rows_per_round = mesh_rows_per_round
         self.dataplane = dataplane
         self.device_hbm_budget = device_hbm_budget
         # stages forced onto the host dataplane mid-job (overflow or
@@ -1110,19 +1107,6 @@ class DAGEngine:
                             reason=plan.reason)
         if plan.plane not in ("device", "hierarchical"):
             return _HOST_PLANE
-        # deprecated escape hatch: an explicit mesh_rows_per_round (ctor
-        # arg or conf key) pins the round size over the budget-derived
-        # auto-sizing — one deprecation warning per process
-        conf = getattr(self.driver.native, "conf", None)
-        legacy_rows = self.mesh_rows_per_round or (
-            conf.mesh_rows_per_round if conf is not None else 0)
-        if legacy_rows:
-            from sparkrdma_tpu.parallel.device_plane import (
-                warn_mesh_rows_deprecated,
-            )
-
-            warn_mesh_rows_deprecated()
-        rows_per_round = legacy_rows or plan.rows_per_round
         try:
             if plan.plane == "hierarchical":
                 from sparkrdma_tpu.shuffle.mesh_service import (
@@ -1132,12 +1116,13 @@ class DAGEngine:
                 results = run_mesh_reduce_hier(
                     mgrs, handle, self.mesh, plan.topology,
                     axis_name=self.mesh_axis, impl=plan.impl,
-                    rows_per_round=rows_per_round, out_factor=out_factor,
-                    expect_maps=handle.num_maps, tracer=self.tracer)
+                    rows_per_round=plan.rows_per_round,
+                    out_factor=out_factor, expect_maps=handle.num_maps,
+                    tracer=self.tracer)
             else:
                 results = run_mesh_reduce_fused(
                     mgrs, handle, self.mesh, axis_name=self.mesh_axis,
-                    impl=plan.impl, rows_per_round=rows_per_round,
+                    impl=plan.impl, rows_per_round=plan.rows_per_round,
                     out_factor=out_factor, expect_maps=handle.num_maps,
                     tracer=self.tracer)
         except OverflowError as e:

@@ -4,17 +4,23 @@ The reference's headline benchmark is TeraSort-320GB, 2.63× faster than
 Spark's TCP shuffle on InfiniBand FDR (README.md:11-17; BASELINE.md). It is
 the canonical shuffle stress: every byte crosses the network exactly once.
 
-TPU-native design — the whole map/shuffle/reduce cycle is ONE jitted SPMD
-step per round:
+TPU-native design — the whole map/shuffle/reduce cycle of a round is ONE
+jitted SPMD step, the device plane's fused step in its range-partition
+mode (``parallel.device_plane.make_fused_step``). Rows are ``[N, 1+P]``
+uint32 matrices (key word + P payload words):
 
-1. **partition**: analytic or sampled range splitters; ``range_partition``
-   assigns each row a destination device (VPU compares, no host loop).
-2. **exchange**: ``shuffle_shard`` — size pre-exchange + ragged all-to-all
-   over ICI (see ``parallel.exchange``). Rows are ``[N, 1+P]`` uint32
-   matrices (key word + P payload words), so the collective moves one dense
-   buffer.
-3. **local sort**: co-sort received rows by key (padded rows sort to the
-   end via the key-max sentinel).
+1. **partition**: a local sort by key. The uniform u32 key-range split
+   is monotonic in key, so key-sorted rows are already grouped by
+   destination device; the per-destination counts are D-1 binary
+   searches on the sorted keys.
+2. **exchange**: the grouped rows cross ICI as one dense buffer through
+   ``parallel.exchange.ragged_exchange_shard`` (size pre-exchange +
+   ragged all-to-all).
+3. **local sort**: the received rows are sorted by key (padded rows sort
+   to the end via the key-max sentinel).
+
+Each local sort is a key sort of ``(key, iota)`` whose order the rows then
+follow (``ops.row_permute``). On one device the step is the one sort.
 
 The result is globally sorted by (device order, local order) — the same
 contract as TeraSort's output files. A numpy reference pipeline provides the
@@ -38,38 +44,15 @@ class TeraSortConfig:
     rows_per_device: int
     payload_words: int = 24  # 4B key word + 24*4B payload ≈ the classic 100B row
     out_factor: int = 2      # receive headroom (uniform keys -> mild skew)
-    # How payload follows its key through a local sort:
-    #   "gather"    — sort (key, iota), then the rows follow the order
-    #                 through ops.row_permute.permute_rows. On the v5e XLA
-    #                 keeps u32[N, 25] column-major (one row is 25 words
-    #                 in 25 different 512-byte sublane rows) and, past the
-    #                 size where operand and result fit VMEM, its gather
-    #                 cost 37.6 ns a row beside a 2.6 ns a row key sort:
-    #                 93 % of the one-chip step (ledger, PR 28). Wide rows
-    #                 at such sizes therefore move as contiguous 128-lane
-    #                 records (pack, one 512-byte copy a record, unpack:
-    #                 6.5 ns a row; PERF.md section 6, PR 29); rows under
-    #                 8 or over 64 words and small rounds stay with jnp.take
-    #                 (row_permute.row_move_form).
-    #   "multisort" — every payload column rides the sort network as an
-    #                 extra rank-1 lax.sort operand: no gather, but the
-    #                 XLA:TPU compile cost grows ~16s per operand and a
-    #                 26-operand network never finished a 900s cold
-    #                 compile — only usable behind a warm compilation
-    #                 cache.
-    #   "colsort"   — ONE variadic 2D sort along axis 0 of
-    #                 (broadcast keys [N,W], rows [N,W]) with
-    #                 is_stable=True: per-column comparators see identical
-    #                 keys, so the stable sort applies the SAME permutation
-    #                 to every lane and payload never leaves the sort
-    #                 network. Carries the key column W times (2x the
-    #                 multisort bytes) but compiles like a 2-operand sort
-    #                 and runs lane-parallel.
-    # On the chip colsort took 3.5x / 2.5x gather's time and multisort
-    # never finished a cold compile (PR 21's bench.py run, ROADMAP
-    # "Recent"); bench A/Bs via BENCH_SORT_MODE, and ROADMAP D8 keeps the
-    # two losers' deletion.
+    # The device plane has one local sort. The field stays, accepting
+    # only "gather", because the benchmark's driver still passes it
+    # (ROADMAP "Named debts" (a)); it goes once that driver stops.
     sort_mode: str = "gather"
+
+    def __post_init__(self):
+        if self.sort_mode != "gather":
+            raise ValueError(f"unknown sort_mode {self.sort_mode!r}: the "
+                             "device plane has one local sort, 'gather'")
 
     @property
     def row_bytes(self) -> int:
@@ -90,15 +73,14 @@ def make_terasort_step(mesh: Mesh, axis_name: str, cfg: TeraSortConfig,
     The step IS the device plane's fused op (``parallel.device_plane.
     make_fused_step``) in its range-partition mode: TeraSort's uniform
     u32 key-range split makes ONE key sort double as the destination
-    grouping; the generic op adds the caller-computed-destination mode
-    the mesh shuffle service rides.
+    grouping. The mesh shuffle service rides the same op in its
+    caller-computed-destination mode.
     """
     from sparkrdma_tpu.parallel.device_plane import make_fused_step
 
     return make_fused_step(mesh, axis_name, 1 + cfg.payload_words,
                            out_factor=cfg.out_factor, impl=impl,
-                           sort_mode=cfg.sort_mode, key_words=1,
-                           partition="range")
+                           key_words=1, partition="range")
 
 
 def generate_rows(cfg: TeraSortConfig, num_devices: int,

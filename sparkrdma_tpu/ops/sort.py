@@ -41,10 +41,3 @@ def sort_segments(keys: jnp.ndarray, valid: jnp.ndarray,
     sentinel = jnp.array(jnp.iinfo(keys.dtype).max, dtype=keys.dtype)
     masked = jnp.where(valid, keys, sentinel)
     return sort_kv(masked, values)
-
-
-def merge_sorted_padded(keys: jnp.ndarray, counts: jnp.ndarray):
-    """Given exchange output grouped by source (segments of sizes
-    ``counts``), produce a validity mask for the packed region."""
-    total = counts.sum()
-    return jnp.arange(keys.shape[0], dtype=jnp.int32) < total

@@ -1,54 +1,50 @@
-"""The unified exchange dataplane: one interface, two implementations,
-a cost model choosing per stage.
+"""The device dataplane: which plane carries a stage, the fused step,
+and the drivers that feed it rounds.
 
-The reference has exactly one accelerated dataplane (one-sided READs);
-this framework grew two — the HOST dataplane (writer -> resolver ->
-fetcher over the control plane, `shuffle/fetcher.py`) and the DEVICE
-dataplane (ragged/chunked/ring ICI collectives, `parallel/exchange.py`).
-Until now the choice was a config flag (`mesh_impl` / a mesh being
-configured at all) and the device path still round-tripped rows through
-host staging for the reduce-side sort. This module makes the ICI
-all-to-all the *primary* dataplane for on-mesh stages:
+A shuffle stage rides one of two planes. The HOST plane is the writer ->
+resolver -> fetcher path over the control plane (`shuffle/fetcher.py`);
+it carries any stage. The DEVICE plane is this module: rows are staged
+into HBM, grouped by destination device, exchanged over ICI and
+key-sorted where they land, so partitions never leave HBM between the
+map output and the sorted reduce input.
 
-* ``Exchange`` — the interface both planes implement: ``supports()``
-  (can this plane carry the stage at all) and ``plan()`` (what would it
-  cost / how would it run). The engine asks the COST MODEL
-  (``select_dataplane``), not a flag.
-* ``make_fused_step`` — the ``shard_map``-fused partition + exchange +
-  local-sort step, generalized from ``models/terasort.py``'s
-  ``make_terasort_step`` into a reusable op: rows are grouped to their
-  destination device, exchanged over ICI (ragged all-to-all by default,
-  dense/gather/ring fallbacks — `parallel/exchange.py`), and key-sorted
-  on the receiving device, so partitions never leave HBM between the
-  map output and the sorted reduce input. One-pass, no materialized
-  intermediates — the redistribution-plan recipe of "Memory-efficient
-  array redistribution through portable collective communication"
-  (PAPERS.md).
-* ``run_fused_exchange`` — the host driver: bounded rounds auto-sized
-  from the HBM byte budget (replacing the static ``mesh_rows_per_round``
-  knob), DOUBLE-BUFFERED so round ``k+1``'s collective is dispatched
-  while round ``k``'s device sort runs and its results drain
-  (``exchange.round`` spans + ``exchange.overlap`` instants prove the
-  overlap in the trace).
+* ``select_dataplane`` is the per-stage cost model. The engine asks it
+  for an ``ExchangePlan`` (plane, transport, round size, and the reason,
+  which the ``exchange.select`` trace instant carries) from what it can
+  observe: whether a mesh is configured, whether the stage's inputs are
+  resident to this process, the stage's bytes against
+  ``device_hbm_budget``, and the mesh's slice topology.
+* ``make_fused_step`` builds the ``shard_map``-fused partition +
+  exchange + local-sort program: one pass, no materialized
+  intermediates (the redistribution-plan recipe of "Memory-efficient
+  array redistribution through portable collective communication",
+  PAPERS.md). The local sort is one key sort of ``(keys..., iota)``
+  whose order the rows then follow (``ops/row_permute.py``); the
+  transport is `parallel/exchange.py`'s.
+* ``run_fused_exchange_rounds`` is the host driver: rounds sized by
+  ``auto_rows_per_round`` from the HBM byte budget, double-buffered so
+  round ``k+1``'s collective is dispatched while round ``k``'s device
+  sort runs and its results drain (``exchange.round`` spans and
+  ``exchange.overlap`` instants show the overlap in the trace).
 
 Overflow (per-pair skew past the dense slot, or a receive past the
-capacity headroom) raises ``OverflowError``; the ENGINE degrades exactly
-the overflowing stage to the host dataplane instead of failing the job
+capacity headroom) raises ``OverflowError``; the engine degrades exactly
+the overflowing stage to the host plane instead of failing the job
 (`engine.py` catches it and re-serves the stage through the fetcher).
 
-Multi-slice topologies (``parallel/topology.py``) add a THIRD plan kind:
-**hierarchical** — the fused ICI step runs per slice over its sub-mesh
-(bulk bytes stay on ICI), and only the slice-crossing residue moves over
-the host/DCN channel, re-homed into its destination slice's next round
-(local regroup -> cross-slice move -> local regroup: the factored
-redistribution of "Memory-efficient array redistribution through
-portable collective communication", PAPERS.md — no full intermediate is
-ever materialized). ``select_dataplane`` scores the candidates by the
-two-level link cost ``intra_bytes/ici_bw + inter_bytes/dcn_bw`` instead
-of a residency boolean; a single-slice (degenerate) topology reproduces
-the flat selector bit-for-bit. One slice's overflow (or a collective
-failure under a lost device) degrades ONLY that slice's residue to
-host-side serving, byte-identically — the other slices stay on ICI.
+On a multi-slice topology (``parallel/topology.py``) there is a third
+plan kind, **hierarchical**: the fused step runs per slice over its
+sub-mesh (bulk bytes stay on ICI), and only the slice-crossing residue
+moves over the host/DCN channel, re-homed into its destination slice's
+next round (local regroup -> cross-slice move -> local regroup, the
+factored redistribution of the same paper).
+``run_hierarchical_exchange`` drives it. ``select_dataplane`` scores it
+against the flat plan by the two-level link cost
+``intra_bytes/ici_bw + inter_bytes/dcn_bw``; a single-slice topology
+gives the flat selector's answer bit for bit. One slice's overflow (or
+a collective failure under a lost device) degrades only that slice's
+residue to host-side serving, byte-identically; the other slices stay
+on ICI.
 """
 
 from __future__ import annotations
@@ -67,16 +63,6 @@ HOST_PLANE = "host"
 HIERARCHICAL_PLANE = "hierarchical"
 
 
-def _resolve_plan_impl(mesh, impl: str, axis_name: str) -> str:
-    """The shared transport resolution (``exchange.resolve_transport``):
-    ring transports pass through verbatim, everything else goes through
-    the per-mesh probe — one helper so the override arm and the plane
-    planners can't drift apart."""
-    from sparkrdma_tpu.parallel.exchange import resolve_transport
-
-    return resolve_transport(mesh, impl, axis_name)
-
-
 def stage_to_device(arr: np.ndarray, sharding):
     """One staged round's host->device upload, donation-friendly: the
     host staging buffer — a BufferPool lease the native fetch engine
@@ -88,27 +74,6 @@ def stage_to_device(arr: np.ndarray, sharding):
 
     return jax.device_put(arr, sharding, may_alias=True)
 
-
-# one-time latch for the mesh_rows_per_round deprecation (engine ctor
-# arg or conf key): the knob still pins round sizes for mixed-version
-# configs, but auto-sizing from device_hbm_budget is the supported path
-_rows_knob_warned = False
-
-
-def warn_mesh_rows_deprecated(source: str = "mesh_rows_per_round") -> None:
-    """Emit the one-per-process deprecation warning for the legacy
-    static round-size knob; later calls are silent."""
-    global _rows_knob_warned
-    if _rows_knob_warned:
-        return
-    _rows_knob_warned = True
-    import warnings
-
-    warnings.warn(
-        f"{source} is deprecated: rounds auto-size from device_hbm_budget"
-        " (docs/CONFIG.md 'Device exchange'); the pinned value is still"
-        " honored for mixed-version configs", DeprecationWarning,
-        stacklevel=3)
 
 # conservative per-device HBM footprint of one fused round, in row
 # multiples: the input buffer + its destination-grouped copy (2 x cap)
@@ -163,90 +128,46 @@ class ExchangePlan:
     topology: Optional[topology_mod.Topology] = None
 
 
-class Exchange:
-    """The one interface both dataplanes implement.
-
-    ``supports`` answers "can this plane carry the stage at all";
-    ``plan`` answers "how would it run" (None = it shouldn't). The
-    cost model (`select_dataplane`) composes the implementations; the
-    engine only ever sees the resulting ``ExchangePlan``.
-    """
-
-    name: str = ""
-
-    def supports(self, mesh, axis_name: str,
-                 profile: StageProfile) -> Tuple[bool, str]:
-        raise NotImplementedError
-
-    def plan(self, mesh, axis_name: str, profile: StageProfile, *,
-             impl: str = "auto",
-             hbm_budget: int = 64 << 20) -> Optional[ExchangePlan]:
-        raise NotImplementedError
-
-
-class DeviceExchange(Exchange):
-    """The ICI collective dataplane (fused partition+exchange+sort)."""
-
-    name = DEVICE_PLANE
-
-    def supports(self, mesh, axis_name, profile):
-        if mesh is None:
-            return False, "no mesh configured"
-        if not profile.resident:
-            return False, "stage inputs not resident to this process"
-        return True, ""
-
-    def plan(self, mesh, axis_name, profile, *, impl="auto",
-             hbm_budget=64 << 20):
-        ok, why = self.supports(mesh, axis_name, profile)
-        if not ok:
-            return None
-        resolved = _resolve_plan_impl(mesh, impl, axis_name)
-        n = mesh.shape[axis_name]
-        rows_cap = auto_rows_per_round(profile.row_bytes, hbm_budget,
-                                       profile.out_factor)
-        if rows_cap < 1:
-            return None  # budget can't hold even one row per device
-        per_dev_rows = -(-max(0, profile.est_bytes)
-                         // max(1, profile.row_bytes) // n) or 1
-        if per_dev_rows <= rows_cap:
-            return ExchangePlan(
-                DEVICE_PLANE, resolved, 0,
-                f"fits budget one-shot ({per_dev_rows} rows/dev <= "
-                f"{rows_cap} cap)")
-        return ExchangePlan(
-            DEVICE_PLANE, resolved, rows_cap,
-            f"chunked: {per_dev_rows} rows/dev over {rows_cap}-row "
-            "budget rounds")
-
-
-class HostExchange(Exchange):
-    """The host dataplane (writer -> resolver -> fetcher): always
-    available — it is the fallback plane, the mixed-version plane, and
-    the off-mesh plane. The engine serves it through the ordinary
-    ``getReader`` path with all its retry/CRC machinery."""
-
-    name = HOST_PLANE
-
-    def supports(self, mesh, axis_name, profile):
-        return True, ""
-
-    def plan(self, mesh, axis_name, profile, *, impl="auto",
-             hbm_budget=64 << 20):
-        return ExchangePlan(HOST_PLANE, "", 0, "host dataplane")
-
-
 def auto_rows_per_round(row_bytes: int, hbm_budget: int,
                         out_factor: int = 2) -> int:
     """Rows per device per fused round that keep the round's footprint
     (input + grouped copy + receive + sorted copy) inside
-    ``hbm_budget`` — the auto-sizing that replaces the static
-    ``mesh_rows_per_round`` knob."""
+    ``hbm_budget``."""
     return max(0, int(hbm_budget) // _footprint_rows(max(1, row_bytes),
                                                      max(1, out_factor)))
 
 
-_PLANES = (DeviceExchange(), HostExchange())
+_NO_ROW_FITS = "device_hbm_budget below one row a device"
+
+
+def _device_plan(mesh, axis_name: str, profile: StageProfile, impl: str,
+                 hbm_budget: int) -> Tuple[Optional[ExchangePlan], str]:
+    """The flat device plan for one stage, or ``None`` and why the
+    device plane cannot carry it: no mesh, inputs not resident to this
+    process, or a budget that cannot hold one row a device."""
+    from sparkrdma_tpu.parallel.exchange import resolve_transport
+
+    if mesh is None:
+        return None, "no mesh configured"
+    if not profile.resident:
+        return None, "stage inputs not resident to this process"
+    resolved = resolve_transport(mesh, impl, axis_name)
+    rows_cap = auto_rows_per_round(profile.row_bytes, hbm_budget,
+                                   profile.out_factor)
+    if rows_cap < 1:
+        return None, _NO_ROW_FITS
+    n = mesh.shape[axis_name]
+    per_dev_rows = -(-max(0, profile.est_bytes)
+                     // max(1, profile.row_bytes) // n) or 1
+    if per_dev_rows <= rows_cap:
+        return ExchangePlan(
+            DEVICE_PLANE, resolved, 0,
+            f"fits budget one-shot ({per_dev_rows} rows/dev <= "
+            f"{rows_cap} cap)"), ""
+    return ExchangePlan(
+        DEVICE_PLANE, resolved, rows_cap,
+        f"chunked: {per_dev_rows} rows/dev over {rows_cap}-row "
+        "budget rounds"), ""
 
 
 def select_dataplane(mesh, axis_name: str, profile: StageProfile, *,
@@ -255,10 +176,13 @@ def select_dataplane(mesh, axis_name: str, profile: StageProfile, *,
                      topology: Optional[topology_mod.Topology] = None,
                      ) -> ExchangePlan:
     """The per-stage cost model: device plane when the stage is mesh-
-    resident and its bytes fit the HBM budget's round sizing, host
-    plane otherwise. ``override`` short-circuits: ``"device"`` /
-    ``"host"`` force a plane (the old ``mesh_impl``-flag behavior,
-    kept as the escape hatch); ``"auto"`` asks the cost model.
+    resident and the HBM budget holds at least one row a device (one
+    shot when the stage fits the budget, budget-sized rounds when it
+    does not), host plane otherwise: the host plane (writer -> resolver
+    -> fetcher, served through the ordinary ``getReader`` path with its
+    retry/CRC machinery) carries any stage. ``override`` short-circuits:
+    ``"device"`` / ``"host"`` force a plane; ``"auto"`` asks the cost
+    model.
 
     ``topology``: the mesh's two-level description. On a MULTI-slice
     topology a stage that would ride the device plane is scored by the
@@ -272,35 +196,28 @@ def select_dataplane(mesh, axis_name: str, profile: StageProfile, *,
     reproduces the flat selector bit-for-bit."""
     if override not in ("auto", DEVICE_PLANE, HOST_PLANE):
         # a typo'd escape hatch must not silently ride the cost model
-        # (same rule as make_fused_step's sort_mode)
         raise ValueError(f"unknown dataplane override {override!r} "
                          "(expected 'auto', 'device' or 'host')")
     if override == HOST_PLANE:
         return ExchangePlan(HOST_PLANE, "", 0, "forced by override")
-    device, host = _PLANES
+    dev, why = _device_plan(mesh, axis_name, profile, impl, hbm_budget)
     if override == DEVICE_PLANE:
-        ok, why = device.supports(mesh, axis_name, profile)
-        if not ok:
-            # forcing a plane that declared itself unable to carry the
-            # stage (no mesh, non-resident inputs) is a caller error —
-            # silently running host under a "device" ask would be worse
-            raise ValueError(f"dataplane override 'device': {why}")
-        dev = device.plan(mesh, axis_name, profile, impl=impl,
-                          hbm_budget=hbm_budget)
         if dev is not None:
             return dev
-        # supported but the budget can't hold a row: run minimum rounds
-        # rather than silently switching planes under an explicit ask
-        return ExchangePlan(DEVICE_PLANE, _resolve_plan_impl(
-            mesh, impl, axis_name), 1,
+        if why != _NO_ROW_FITS:
+            # forcing a plane that cannot carry the stage (no mesh,
+            # non-resident inputs) is a caller error — silently running
+            # host under a "device" ask would be worse
+            raise ValueError(f"dataplane override 'device': {why}")
+        # the budget can't hold a row: run minimum rounds rather than
+        # silently switching planes under an explicit ask
+        from sparkrdma_tpu.parallel.exchange import resolve_transport
+
+        return ExchangePlan(
+            DEVICE_PLANE, resolve_transport(mesh, impl, axis_name), 1,
             "forced by override (budget below one row)")
-    dev = device.plan(mesh, axis_name, profile, impl=impl,
-                      hbm_budget=hbm_budget)
     if dev is None:
-        # HostExchange.plan always returns a plan — it is the fallback
-        # plane by contract (no "no plane volunteered" tail needed)
-        return host.plan(mesh, axis_name, profile, impl=impl,
-                         hbm_budget=hbm_budget)
+        return ExchangePlan(HOST_PLANE, "", 0, "host dataplane")
     if (topology is not None and not topology.is_flat
             and dev.rows_per_round == 0):
         # one-shot plans only: the hierarchical runner stages the whole
@@ -337,11 +254,9 @@ def select_dataplane(mesh, axis_name: str, profile: StageProfile, *,
 # the fused step: partition + exchange + local sort, one shard_map program
 # ---------------------------------------------------------------------------
 
-def _local_sort(rows, keys, sort_mode: str, write_back_keys: bool, move):
-    """One local sort of full rows by (pre-masked) keys. The three
-    strategies and their trade-offs are documented on
-    ``models.terasort.TeraSortConfig.sort_mode``. ``gather`` sorts
-    ``(key, iota)`` and then lets the rows follow the order through
+def _local_sort(rows, keys, write_back_keys: bool, move):
+    """The local sort of full rows by (pre-masked) keys: one key sort of
+    ``(keys..., iota)``, then the rows follow the order through
     ``move(rows, order)``: ``ops.row_permute.permute_rows`` bound to the
     platform the step compiles for, which picks its data path from that
     and the shape.
@@ -365,49 +280,18 @@ def _local_sort(rows, keys, sort_mode: str, write_back_keys: bool, move):
         return sorted_rows
 
     # the leaf scopes name the kernels in a device profile, whatever XLA
-    # calls its fusions: ``key_sort`` is the sort proper (the whole of
-    # multisort / colsort), ``row_gather`` the gather mode's row move
-    if sort_mode == "multisort":
-        with jax.named_scope("key_sort"):
-            cols = tuple(rows[:, j] for j in range(rows.shape[1]))
-            # is_stable: all three modes must order duplicate keys
-            # identically (gather is stable via its iota tiebreak)
-            out = jax.lax.sort(keys + cols, num_keys=len(keys),
-                               is_stable=True)
-            sorted_keys = out[0]
-            sorted_rows = written_back(
-                jnp.stack(out[len(keys):], axis=1), sorted_keys)
-    elif sort_mode == "colsort":
-        # identical keys in every lane + a STABLE sort => every column
-        # receives the same permutation, so rows stay intact without a
-        # gather and without per-column operands. Multi-word keys run
-        # as LSD radix passes: one stable per-lane sort per key word,
-        # least significant first, remaining key words carried as
-        # broadcast value operands so they ride the same permutation.
-        with jax.named_scope("key_sort"):
-            carried = tuple(jnp.broadcast_to(k[:, None], rows.shape)
-                            for k in keys)
-            sorted_rows = rows
-            for w in range(len(keys) - 1, -1, -1):
-                out = jax.lax.sort((carried[w], sorted_rows)
-                                   + carried[:w] + carried[w + 1:],
-                                   dimension=0, num_keys=1, is_stable=True)
-                sorted_rows = out[1]
-                rest = out[2:]
-                carried = rest[:w] + (out[0],) + rest[w:]
-            sorted_keys = carried[0][:, 0]
-            sorted_rows = written_back(sorted_rows, sorted_keys)
-    else:
-        with jax.named_scope("key_sort"):
-            iota = jnp.arange(rows.shape[0], dtype=jnp.int32)
-            # iota as a FINAL KEY makes the order total: duplicate keys
-            # order by original position with no reliance on sort
-            # stability (a value-operand iota under an unstable sort
-            # could permute ties arbitrarily)
-            out = jax.lax.sort(keys + (iota,), num_keys=len(keys) + 1)
-            sorted_keys, order = out[0], out[-1]
-        with jax.named_scope("row_gather"):
-            sorted_rows = written_back(move(rows, order), sorted_keys)
+    # calls its fusions: ``key_sort`` is the sort proper, ``row_gather``
+    # the row move
+    with jax.named_scope("key_sort"):
+        iota = jnp.arange(rows.shape[0], dtype=jnp.int32)
+        # iota as a FINAL KEY makes the order total: duplicate keys
+        # order by original position with no reliance on sort
+        # stability (a value-operand iota under an unstable sort
+        # could permute ties arbitrarily)
+        out = jax.lax.sort(keys + (iota,), num_keys=len(keys) + 1)
+        sorted_keys, order = out[0], out[-1]
+    with jax.named_scope("row_gather"):
+        sorted_rows = written_back(move(rows, order), sorted_keys)
     return sorted_rows, sorted_keys
 
 
@@ -424,12 +308,9 @@ def _row_keys(rows, key_words: int):
 @functools.lru_cache(maxsize=64)
 def make_fused_step(mesh, axis_name: str, row_words: int, *,
                     out_factor: int = 2, impl: str = "auto",
-                    sort_mode: str = "gather", key_words: int = 1,
-                    partition: str = "range"):
-    """Build the jitted fused partition+exchange+local-sort step —
-    ``models/terasort.py``'s one-round step generalized into the
-    reusable device-plane op. Memoized per full signature so per-job
-    callers compile once.
+                    key_words: int = 1, partition: str = "range"):
+    """Build the jitted fused partition+exchange+local-sort step.
+    Memoized per full signature so per-job callers compile once.
 
     ``partition`` selects how rows find their destination device:
 
@@ -453,8 +334,7 @@ def make_fused_step(mesh, axis_name: str, row_words: int, *,
 
     ``step.row_moves`` lists the form each of the step's row moves took
     (``ops.row_permute``: ``"packed"`` / ``"take"``), in program order. It
-    is filled while the step is traced (its first call or ``lower``) and
-    stays empty under a ``sort_mode`` that moves no rows by an order.
+    is filled while the step is traced (its first call or ``lower``).
     """
     import jax
     import jax.numpy as jnp
@@ -470,10 +350,6 @@ def make_fused_step(mesh, axis_name: str, row_words: int, *,
         row_mover,
     )
 
-    if sort_mode not in ("gather", "multisort", "colsort"):
-        # a typo must not silently measure (and mislabel) the gather path
-        raise ValueError(f"unknown sort_mode {sort_mode!r} "
-                         "(expected 'gather', 'multisort' or 'colsort')")
     if partition not in ("range", "dest"):
         raise ValueError(f"unknown partition {partition!r} "
                          "(expected 'range' or 'dest')")
@@ -515,8 +391,7 @@ def make_fused_step(mesh, axis_name: str, row_words: int, *,
             idx = jnp.arange(received.shape[0], dtype=jnp.int32)
             keys = tuple(jnp.where(idx < total, k, sentinel)
                          for k in _row_keys(received, key_words))
-            sorted_rows = _local_sort(received, keys, sort_mode,
-                                      write_back, move)[0]
+            sorted_rows = _local_sort(received, keys, write_back, move)[0]
         return sorted_rows, recv_counts[None], overflowed[None]
 
     # pallas interpret-mode outputs confuse the vma checker when mixed
@@ -537,8 +412,8 @@ def make_fused_step(mesh, axis_name: str, row_words: int, *,
             if n == 1:
                 # single-device: no exchange, one sort is the whole job
                 with jax.named_scope("fused.receive_sort"):
-                    sorted_rows, _ = _local_sort(rows, keys, sort_mode,
-                                                 write_back, move)
+                    sorted_rows, _ = _local_sort(rows, keys, write_back,
+                                                 move)
                 counts = jnp.array([[rows.shape[0]]], dtype=jnp.int32)
                 return sorted_rows, counts, jnp.zeros((1,), bool)
 
@@ -547,8 +422,8 @@ def make_fused_step(mesh, axis_name: str, row_words: int, *,
                 # in key, so key-sorted rows are destination-grouped for
                 # free — this replaces the separate argsort-by-
                 # destination + gather entirely.
-                grouped, sorted_keys = _local_sort(rows, keys, sort_mode,
-                                                   write_back, move)
+                grouped, sorted_keys = _local_sort(rows, keys, write_back,
+                                                   move)
                 # per-destination counts: D-1 binary searches on sorted
                 # keys
                 bounds = jnp.searchsorted(sorted_keys, splitters,
@@ -570,8 +445,8 @@ def make_fused_step(mesh, axis_name: str, row_words: int, *,
                 with jax.named_scope("fused.receive_sort"):
                     idx_keys = tuple(jnp.where(valid, k, sentinel)
                                      for k in _row_keys(rows, key_words))
-                    sorted_rows, _ = _local_sort(rows, idx_keys, sort_mode,
-                                                 write_back, move)
+                    sorted_rows, _ = _local_sort(rows, idx_keys, write_back,
+                                                 move)
                 counts = jnp.sum(valid).astype(jnp.int32).reshape(1, 1)
                 return sorted_rows, counts, jnp.zeros((1,), bool)
             with jax.named_scope("fused.partition"):
@@ -589,8 +464,8 @@ def make_fused_step(mesh, axis_name: str, row_words: int, *,
 def run_fused_exchange(mesh, axis_name: str, rows: np.ndarray,
                        dest: np.ndarray, *, key_words: int = 2,
                        rows_per_round: int = 0, out_factor: int = 2,
-                       impl: str = "auto", sort_mode: str = "gather",
-                       tracer=None, pipeline_rounds: bool = True,
+                       impl: str = "auto", tracer=None,
+                       pipeline_rounds: bool = True,
                        ) -> Tuple[List[np.ndarray], int]:
     """Drive the fused step over fully-materialized arrays: bounded
     rounds of ``rows_per_round`` rows per device (0 = one shot) through
@@ -612,22 +487,20 @@ def run_fused_exchange(mesh, axis_name: str, rows: np.ndarray,
 
     return run_fused_exchange_rounds(
         mesh, axis_name, blocks(), row_words, cap, key_words=key_words,
-        out_factor=out_factor, impl=impl, sort_mode=sort_mode,
-        tracer=tracer, pipeline_rounds=pipeline_rounds)
+        out_factor=out_factor, impl=impl, tracer=tracer,
+        pipeline_rounds=pipeline_rounds)
 
 
 def run_fused_exchange_rounds(mesh, axis_name: str, blocks,
                               row_words: int, rows_per_round: int, *,
                               key_words: int = 2, out_factor: int = 2,
-                              impl: str = "auto",
-                              sort_mode: str = "gather", tracer=None,
+                              impl: str = "auto", tracer=None,
                               pipeline_rounds: bool = True,
                               ) -> Tuple[List[np.ndarray], int]:
     """Drive the fused step over a stream of round blocks: ``blocks``
     yields ``(rows u32[<= rows_per_round * D, row_words], dest i32)``
     per round, so HOST staging holds one round (plus the in-flight one
-    when pipelined) no matter how large the stage — the bounded-staging
-    discipline ``run_mesh_reduce_streamed`` had, kept. Rounds are
+    when pipelined) no matter how large the stage. Rounds are
     DOUBLE-BUFFERED: round ``k+1``'s collective is dispatched while
     round ``k``'s on-device sort runs and its results drain
     (``exchange.round`` spans per round, ``exchange.overlap`` instants
@@ -650,8 +523,7 @@ def run_fused_exchange_rounds(mesh, axis_name: str, blocks,
     per_round = max(1, rows_per_round) * n
     step = make_fused_step(mesh, axis_name, row_words,
                            out_factor=out_factor, impl=impl,
-                           sort_mode=sort_mode, key_words=key_words,
-                           partition="dest")
+                           key_words=key_words, partition="dest")
     sharding = NamedSharding(mesh, P(axis_name))
     runs: List[list] = [[] for _ in range(n)]
 
@@ -780,7 +652,7 @@ def run_hierarchical_exchange(mesh, axis_name: str,
                               home_slice: np.ndarray, *,
                               key_words: int = 2, rows_per_round: int = 0,
                               out_factor: int = 2, impl: str = "auto",
-                              sort_mode: str = "gather", tracer=None,
+                              tracer=None,
                               ) -> Tuple[List[np.ndarray], int]:
     """Drive the FACTORED two-phase redistribution over a multi-slice
     topology: local regroup -> cross-slice move -> local regroup, per
@@ -825,7 +697,7 @@ def run_hierarchical_exchange(mesh, axis_name: str,
         return run_fused_exchange(
             mesh, axis_name, rows, dest, key_words=key_words,
             rows_per_round=rows_per_round, out_factor=out_factor,
-            impl=impl, sort_mode=sort_mode, tracer=tracer)
+            impl=impl, tracer=tracer)
     dest = np.asarray(dest, dtype=np.int32)
     home = np.asarray(home_slice, dtype=np.int32)
     dev_slice = topology.device_slices()
@@ -874,8 +746,7 @@ def run_hierarchical_exchange(mesh, axis_name: str,
             submesh = topology_mod.slice_mesh(mesh, axis_name, topology, s)
             step = make_fused_step(submesh, axis_name, row_words,
                                    out_factor=out_factor, impl=impl,
-                                   sort_mode=sort_mode, key_words=key_words,
-                                   partition="dest")
+                                   key_words=key_words, partition="dest")
             sharding = NamedSharding(submesh, P(axis_name))
             chunks = [(rs[o:o + per_round], ds[o:o + per_round])
                       for o in range(0, len(rs), per_round)]

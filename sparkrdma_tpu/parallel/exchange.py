@@ -24,26 +24,27 @@ Everything is static-shape: ``data`` and ``output`` are fixed-capacity
 buffers; raggedness lives in the offset/size vectors, which is what keeps
 XLA happy (no dynamic shapes under jit).
 
-Four transports (``impl``):
+Transports (``impl``). ``"auto"``, what every caller passes by default,
+resolves per mesh (``resolve_impl``): ``native`` on a TPU mesh, ``dense``
+where the compiler rejects the probe compile of the ragged opcode,
+``gather`` off the TPU. The ring runs only on an explicit ask.
 
-* ``"native"`` — ``lax.ragged_all_to_all`` (TPU; switch-routed ICI; the
-  v5e compiler accepts it only up to 16 chips — larger slices have
-  limited ICI routing and reject the opcode, so ``resolve_impl``
-  probe-compiles per mesh).
+* ``"native"`` — ``lax.ragged_all_to_all`` (TPU; switch-routed ICI). The
+  v5e compiler accepts it up to 16 chips; larger slices have limited ICI
+  routing and reject the opcode, which is why ``resolve_impl``
+  probe-compiles per mesh.
 * ``"dense"`` — ``lax.all_to_all`` over fixed per-pair slots (supported
   at every scale): each (source, dest) pair gets ``out_capacity / D``
   slot rows; skew past a slot raises the callers' overflow flag exactly
   like a capacity overflow. Bandwidth = the padded capacity, i.e. an
-  ``out_factor``-bounded overhead instead of gather's D× — the auto
-  fallback where native is rejected.
+  ``out_factor``-bounded overhead instead of gather's D×.
 * ``"gather"`` — decomposed ``all_gather`` + mask-compaction, D×
-  bandwidth; the last-resort oracle (XLA:CPU validation meshes use it as
-  the reference semantics).
+  bandwidth: what XLA:CPU meshes run (XLA:CPU has no ragged opcode),
+  and the oracle the other transports are tested against.
 * ``"ring"`` / ``"ring_interpret"`` — the hand-scheduled Pallas ring kernel
   (``ops.ring_exchange``): explicit chip-to-chip async remote DMAs, the
-  closest structural analogue of the reference's one-sided verbs engine;
-  available through the chunked exchange, whose static per-pair quota gives
-  the ring its block shape.
+  closest structural analogue of the reference's one-sided verbs engine.
+  ``auto`` never picks it.
 """
 
 from __future__ import annotations

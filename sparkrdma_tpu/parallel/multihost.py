@@ -106,9 +106,8 @@ def run_multihost_mesh_reduce(managers: Sequence, handle, mesh,
     rounds of at most ``rows_per_round`` rows per device per round (R is
     agreed group-wide from the same metadata allgather, so every process
     enters the same number of collectives; one compile serves all
-    rounds). Host staging is unchanged — what streaming bounds is the
-    device-resident working set, the discipline
-    ``run_mesh_reduce_streamed`` applies in-process.
+    rounds). Host staging is unchanged — what the rounds bound is the
+    device-resident working set.
     """
     import jax
     from jax.experimental import multihost_utils

@@ -3,16 +3,23 @@ data plane.
 
 This closes the loop the reference closes with its NIC: committed map
 outputs (host spill files, ``shuffle/resolver.py``) are staged into device
-HBM through the buffer pool, ONE jitted ragged all-to-all redistributes
-every row to its reduce partition's owner device, and the reduce-side
-group/sort runs on-device. The host's only data-plane job is streaming
+HBM, the device plane's fused step (``parallel/device_plane.py``) groups
+every row by its reduce partition's owner device, moves it there in one
+ragged all-to-all and key-sorts it where it lands, and the sorted rows
+come back once per round. The host's only data-plane job is streaming
 sequential spill bytes up — the per-(map, reduce) scatter the reference
 does with one-sided READs (scala/RdmaShuffleFetcherIterator.scala:119-180)
 happens **on the mesh**, where it is a collective.
 
-Partition → device placement: partition ``p`` is owned by device
-``p % D`` (the same modulo placement the driver-table scheme uses for
-executors).
+There is one reduce driver per plan kind, and the engine picks by the
+plan ``device_plane.select_dataplane`` returns: ``run_mesh_reduce_fused``
+for flat plans (one shot, or rounds sized from ``device_hbm_budget`` with
+spills streamed into round blocks), ``run_mesh_reduce_hier`` for
+multi-slice plans.
+
+Partition → device placement on flat plans: partition ``p`` is owned by
+device ``p % D`` (the same modulo placement the driver-table scheme uses
+for executors).
 """
 
 from __future__ import annotations
@@ -21,7 +28,6 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from sparkrdma_tpu.parallel import device_plane as device_plane_mod
 from sparkrdma_tpu.shuffle.fetcher import ReadMetrics
 from sparkrdma_tpu.shuffle.manager import ShuffleHandle, TpuShuffleManager
 from sparkrdma_tpu.utils import trace as trace_mod
@@ -62,75 +68,6 @@ def _u32_to_rows(rows: np.ndarray, payload_bytes: int
     return keys, payload
 
 
-def run_mesh_reduce(managers: Sequence[TpuShuffleManager],
-                    handle: ShuffleHandle, mesh, axis_name: str = "shuffle",
-                    impl: str = "auto", sort_by_key: bool = True,
-                    out_factor: int = 2,
-                    expect_maps: Optional[int] = None,
-                    ) -> List[Tuple[np.ndarray, np.ndarray, np.ndarray]]:
-    """Reduce every partition of ``handle`` on the mesh.
-
-    ``managers``: the executor managers whose resolvers hold the committed
-    map outputs (single-host deployment: one process, many executor roles,
-    one mesh — remote spills would arrive via the DCN fetch path first).
-
-    ``out_factor``: receive headroom per device relative to the balanced
-    share (``total/D``); skew beyond it raises OverflowError — chunk with
-    ``parallel.exchange.chunked_exchange`` for unbounded skew.
-
-    Returns, per device ``d``: ``(keys u64[*], payload u8[*, W],
-    partition_ids i64[*])`` for the partitions ``{p : p % D == d}``, rows
-    key-sorted within the device when ``sort_by_key``.
-    """
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from sparkrdma_tpu.parallel import exchange as exchange_mod
-    from sparkrdma_tpu.parallel.exchange import make_shuffle_exchange
-
-    n_dev = mesh.shape[axis_name]
-    partitioner = handle.partitioner.build(handle.num_partitions)
-
-    keys, payload = _stage_all(managers, handle, expect_maps)
-    rows = _rows_to_u32(keys, payload)
-    dest_part = np.asarray(partitioner(keys), dtype=np.int32)
-
-    # pad to a device-divisible static capacity with headroom for skew
-    cap = max(1, -(-len(rows) // n_dev))
-    total_cap = cap * n_dev
-    rows_p = np.zeros((total_cap, rows.shape[1]), dtype=np.uint32)
-    rows_p[:len(rows)] = rows
-    dest_p = np.full(total_cap, -1, dtype=np.int32)
-    dest_p[:len(rows)] = dest_part % n_dev  # partition owner device
-
-    width = rows.shape[1]
-
-    # 2. the one shared jitted exchange (parallel/exchange.py)
-    exchange = make_shuffle_exchange(mesh, axis_name, impl=impl,
-                                     out_factor=out_factor)
-    sharding = NamedSharding(mesh, P(axis_name))
-    received, counts, _, overflowed = jax.block_until_ready(exchange(
-        device_plane_mod.stage_to_device(rows_p, sharding),
-        device_plane_mod.stage_to_device(dest_p, sharding)))
-    exchange_mod.record_exchange(len(rows))
-
-    # 3. unpack per device (host-side view of the device results)
-    received = np.asarray(received).reshape(n_dev, -1, width)
-    counts = np.asarray(counts)
-    if np.asarray(overflowed).any():
-        raise OverflowError("mesh reduce receive overflow")
-    results = []
-    for d in range(n_dev):
-        total = int(counts[d].sum())
-        k, p = _u32_to_rows(received[d][:total], handle.row_payload_bytes)
-        parts = np.asarray(partitioner(k), dtype=np.int64)
-        if sort_by_key:
-            order = np.argsort(k, kind="stable")
-            k, p, parts = k[order], p[order], parts[order]
-        results.append((k, p, parts))
-    return results
-
-
 def run_mesh_reduce_fused(managers: Sequence[TpuShuffleManager],
                           handle: ShuffleHandle, mesh,
                           axis_name: str = "shuffle", impl: str = "auto",
@@ -139,25 +76,31 @@ def run_mesh_reduce_fused(managers: Sequence[TpuShuffleManager],
                           tracer=None,
                           ) -> List[Tuple[np.ndarray, np.ndarray,
                                           np.ndarray]]:
-    """``run_mesh_reduce`` on the FUSED device plane: one
-    ``shard_map``-fused partition+exchange+local-sort step per round
-    (``parallel.device_plane``), so between the one staging upload and
-    the one result download partitions never leave HBM — the reduce-side
-    sort that ``run_mesh_reduce``/``run_mesh_reduce_streamed`` ran
-    host-side per round happens on the receiving device, and rounds are
-    double-buffered (round k+1's collective dispatches while round k's
-    on-device sort runs; ``exchange.round``/``exchange.overlap`` trace
-    the overlap).
+    """Reduce every partition of ``handle`` on the mesh through the
+    fused device plane: one ``shard_map``-fused partition+exchange+
+    local-sort step per round (``parallel.device_plane``), so between a
+    round's staging upload and its result download partitions never
+    leave HBM, and rounds are double-buffered (round k+1's collective
+    dispatches while round k's on-device sort runs;
+    ``exchange.round``/``exchange.overlap`` trace the overlap).
+
+    ``managers``: the executor managers whose resolvers hold the
+    committed map outputs (single-host deployment: one process, many
+    executor roles, one mesh).
 
     ``rows_per_round`` bounds each round's per-device rows (0 = one
-    shot) — the engine auto-sizes it from the HBM byte budget
-    (``device_plane.auto_rows_per_round``). With rounds bounded, host
-    staging is bounded too: spills stream straight into round blocks
-    (one round resident, plus the in-flight one), the discipline
-    ``run_mesh_reduce_streamed`` had. Raises ``OverflowError`` when
-    skew beats the ``out_factor`` headroom; the engine degrades exactly
-    this stage to the host dataplane. Same result contract as
-    ``run_mesh_reduce`` with ``sort_by_key=True``.
+    shot) — the engine takes it from the plan, which sizes it from the
+    HBM byte budget (``device_plane.auto_rows_per_round``). With rounds
+    bounded, host staging is bounded too: spills stream straight into
+    round blocks (one round resident, plus the in-flight one).
+
+    ``out_factor``: receive headroom per device relative to the balanced
+    share. Raises ``OverflowError`` when skew beats it; the engine
+    degrades exactly this stage to the host dataplane.
+
+    Returns, per device ``d``: ``(keys u64[*], payload u8[*, W],
+    partition_ids i64[*])`` for the partitions ``{p : p % D == d}``,
+    rows key-sorted within the device.
     """
     from sparkrdma_tpu.parallel.device_plane import (
         run_fused_exchange,
@@ -238,8 +181,8 @@ def run_mesh_reduce_hier(managers: Sequence[TpuShuffleManager],
     link-cost-aware partition->device layout (``i32[P]``); None derives
     the slice-aligned map from the staged per-slice byte histogram
     (``planner.slice_aligned_partition_map``) so cross-slice bytes are
-    minimized by construction — the flat reduces' ``p % D`` placement is
-    what it replaces. Same result contract as ``run_mesh_reduce_fused``
+    minimized by construction, in place of the flat reduce's ``p % D``
+    placement. Same result contract as ``run_mesh_reduce_fused``
     (per-device key-sorted rows; a different partition layout only moves
     WHICH device serves a partition, never its bytes).
 
@@ -319,8 +262,8 @@ def _stage_all(managers, handle, expect_maps: Optional[int]
     """Stage every committed local spill into one (keys, payload) pair:
     streamed sequentially (no host scatter) through the resolver's
     locked serving API (safe vs. concurrent re-commit/unregister
-    disposal), with the completeness check. Shared by the one-shot
-    reduces; the bounded-round paths stream instead."""
+    disposal), with the completeness check. The one-shot reduce's
+    staging; bounded rounds stream instead."""
     all_keys, all_payloads = [], []
     delivered: set = set()
     for k, p in _iter_committed_batches(managers, handle, delivered):
@@ -337,7 +280,7 @@ def _stage_all(managers, handle, expect_maps: Optional[int]
 def _iter_committed_batches(managers, handle, delivered: Optional[set] = None):
     """Decoded (keys, payload) batches of every committed local spill —
     ``_iter_committed_batches_indexed`` minus the staging-manager index
-    (the flat reduces don't care which executor held a map; the
+    (the flat reduce doesn't care which executor held a map; the
     hierarchical reduce does — the index names the home slice)."""
     for _, k, p in _iter_committed_batches_indexed(managers, handle,
                                                    delivered):
@@ -347,9 +290,9 @@ def _iter_committed_batches(managers, handle, delivered: Optional[set] = None):
 def _iter_committed_batches_indexed(managers, handle,
                                     delivered: Optional[set] = None):
     """Decoded (manager_index, keys, payload) batches of every committed
-    local spill — THE staging hook: every mesh reduce driver (one-shot,
-    streamed, fused, hierarchical) stages through this one generator,
-    so a shim or chaos injection wrapped around it covers them all.
+    local spill — THE staging hook: both mesh reduce drivers (fused,
+    hierarchical) stage through this one generator, so a shim or chaos
+    injection wrapped around it covers them both.
 
     Each map id is taken from the FIRST resolver holding it: stage retry
     and speculation can leave identical copies of one map output on two
@@ -399,125 +342,6 @@ def _check_staging_complete(delivered: set, expect_maps: Optional[int],
         raise FetchFailedError(
             shuffle_id, missing[0], -1,
             "map output disposed during mesh staging")
-
-
-def run_mesh_reduce_streamed(managers: Sequence[TpuShuffleManager],
-                             handle: ShuffleHandle, mesh,
-                             axis_name: str = "shuffle", impl: str = "auto",
-                             rows_per_round: int = 1 << 18,
-                             out_factor: int = 2,
-                             expect_maps: Optional[int] = None,
-                             pipeline_rounds: bool = True,
-                             ) -> List[Tuple[np.ndarray, np.ndarray,
-                                             np.ndarray]]:
-    """``run_mesh_reduce`` for datasets beyond one exchange's device (or
-    host staging) budget: spills stream through the SAME jitted exchange
-    step in bounded rounds of ``rows_per_round`` rows per device — device
-    memory is static per round, host staging holds one round — and each
-    device's key-sorted round outputs merge in one pass, each row written
-    once (`shuffle/external.py::merge_runs`). Same contract as
-    ``run_mesh_reduce`` with ``sort_by_key=True``.
-
-    ``pipeline_rounds``: double-buffer — round r+1 is decoded from the
-    spills, padded, and DISPATCHED (jax dispatch is async) before round
-    r's results are pulled back and unpacked, so host staging overlaps
-    the device exchange. The same inter-round pipeline the reference gets
-    from serving straight out of mmap'd registered memory while fetches
-    are in flight (java/RdmaMappedFile.java:163-189,
-    scala/RdmaShuffleFetcherIterator.scala:264-276).
-    """
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from sparkrdma_tpu.parallel import exchange as exchange_mod
-    from sparkrdma_tpu.parallel.exchange import make_shuffle_exchange
-    from sparkrdma_tpu.shuffle.external import merge_runs
-
-    n_dev = mesh.shape[axis_name]
-    partitioner = handle.partitioner.build(handle.num_partitions)
-    pw = device_row_words(handle.row_payload_bytes)
-    cap = rows_per_round
-    sharding = NamedSharding(mesh, P(axis_name))
-    # the one shared jitted exchange, compiled once for the round shape
-    exchange = make_shuffle_exchange(mesh, axis_name, impl=impl,
-                                     out_factor=out_factor)
-
-    runs: List[list] = [[] for _ in range(n_dev)]
-
-    def dispatch(rows_np: np.ndarray):
-        """Stage one round and launch its exchange; no blocking."""
-        dest = (np.asarray(partitioner(
-            rows_np[:, :2].copy().view(np.uint64).reshape(-1)),
-            dtype=np.int32) % n_dev)
-        total_cap = cap * n_dev
-        rows_p = np.zeros((total_cap, pw), np.uint32)
-        rows_p[:len(rows_np)] = rows_np
-        dest_p = np.full(total_cap, -1, np.int32)
-        dest_p[:len(rows_np)] = dest
-        exchange_mod.record_exchange(len(rows_np))
-        return exchange(device_plane_mod.stage_to_device(rows_p, sharding),
-                        device_plane_mod.stage_to_device(dest_p, sharding))
-
-    def collect(results) -> None:
-        # np.asarray blocks on the device
-        received, counts, _, overflowed = results
-        received = np.asarray(received).reshape(n_dev, -1, pw)
-        counts = np.asarray(counts)
-        if np.asarray(overflowed).any():
-            raise OverflowError("mesh reduce receive overflow; raise "
-                                "out_factor or shrink rows_per_round")
-        for d in range(n_dev):
-            got = received[d][:int(counts[d].sum())]
-            keys = got[:, :2].copy().view(np.uint64).reshape(-1)
-            runs[d].append(got[np.argsort(keys, kind="stable")].copy())
-
-    def round_chunks():
-        """Yield round-sized row blocks streamed off the committed spills
-        (plus the completeness check once staging is exhausted)."""
-        pending: List[np.ndarray] = []
-        pending_rows = 0
-        per_round = cap * n_dev
-        delivered: set = set()
-        for k, p in _iter_committed_batches(managers, handle, delivered):
-            rows = _rows_to_u32(k, p)
-            while len(rows):
-                take = min(len(rows), per_round - pending_rows)
-                pending.append(rows[:take])
-                pending_rows += take
-                rows = rows[take:]
-                if pending_rows == per_round:
-                    yield np.concatenate(pending)
-                    pending, pending_rows = [], 0
-        _check_staging_complete(delivered, expect_maps, handle.shuffle_id)
-        if pending_rows:
-            yield np.concatenate(pending)
-
-    if pipeline_rounds:
-        # round r's exchange runs on-device while round r+1 stages on the
-        # host (decode + pad + partition) — one round in flight
-        in_flight = None
-        for chunk in round_chunks():
-            nxt = dispatch(chunk)
-            if in_flight is not None:
-                collect(in_flight)
-            in_flight = nxt
-        if in_flight is not None:
-            collect(in_flight)
-    else:
-        for chunk in round_chunks():
-            collect(dispatch(chunk))
-
-    results = []
-    for d in range(n_dev):
-        if runs[d]:
-            _, merged = merge_runs([(r[:, :2].copy().view(np.uint64)
-                                     .reshape(-1), r) for r in runs[d]])
-        else:
-            merged = np.zeros((0, pw), np.uint32)
-        keys, payload = _u32_to_rows(merged, handle.row_payload_bytes)
-        parts = np.asarray(partitioner(keys), dtype=np.int64)
-        results.append((keys, payload, parts))
-    return results
 
 
 def split_by_partition(results, num_partitions: int, row_payload_bytes: int

@@ -57,10 +57,14 @@ def _no_tcp_fetchers(monkeypatch):
     return built
 
 
-@pytest.mark.parametrize("rows_per_round", [0, 256])
-def test_engine_job_rides_mesh(cluster, mesh, monkeypatch, rows_per_round):
+# 3-word rows at out_factor 4 (4 partitions on 8 devices): 256 rows a
+# round, where the job has 525 a device
+@pytest.mark.parametrize("device_hbm_budget", [0, 12 * (2 + 2 * 4) * 256])
+def test_engine_job_rides_mesh(cluster, mesh, monkeypatch,
+                               device_hbm_budget):
     """Sum-by-partition job: exact results, exchanges dispatched, zero TCP
-    fetchers built (one-shot and streamed-round mesh reduces)."""
+    fetchers built (one-shot under the default budget, and rounds under
+    one the stage does not fit)."""
     driver, execs = cluster
     P, maps, rows, key_space = 4, 6, 700, 5000
 
@@ -83,7 +87,7 @@ def test_engine_job_rides_mesh(cluster, mesh, monkeypatch, rows_per_round):
     stage = MapStage(maps, ShuffleDependency(
         P, PartitionerSpec("modulo"), row_payload_bytes=4), map_fn)
     engine = DAGEngine(driver, execs, mesh=mesh,
-                       mesh_rows_per_round=rows_per_round)
+                       device_hbm_budget=device_hbm_budget)
     out = engine.run(ResultStage(P, reduce_fn, parents=[stage]))
 
     # exact per-partition sums vs. host truth
@@ -99,7 +103,7 @@ def test_engine_job_rides_mesh(cluster, mesh, monkeypatch, rows_per_round):
     assert exchange_mod.DATA_PLANE["exchanges"] > before, \
         "no collective exchange dispatched — bytes did not ride the mesh"
     assert built["n"] == 0, "TCP fetcher constructed in mesh mode"
-    if rows_per_round:  # streamed mode must have taken multiple rounds
+    if device_hbm_budget:  # must have taken multiple rounds
         assert exchange_mod.DATA_PLANE["exchanges"] - before > 1
 
 

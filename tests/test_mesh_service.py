@@ -1,7 +1,7 @@
 """The full end-to-end slice (SURVEY.md §7): engine-facing writers commit
-spills -> staged to the mesh -> ONE ICI ragged all-to-all redistributes ->
-device-side reduce. Verified against both a host-side reader and the raw
-input multiset."""
+spills -> staged to the mesh -> the fused step redistributes over ICI and
+sorts on the device. Verified against both a host-side reader and the raw
+input multiset, one shot (``rows_per_round`` 0) and in bounded rounds."""
 
 import jax
 import numpy as np
@@ -9,8 +9,9 @@ import pytest
 from jax.sharding import Mesh
 
 from sparkrdma_tpu.config import TpuShuffleConf
+from sparkrdma_tpu.parallel import exchange as exchange_mod
 from sparkrdma_tpu.shuffle.manager import PartitionerSpec, TpuShuffleManager
-from sparkrdma_tpu.shuffle.mesh_service import run_mesh_reduce
+from sparkrdma_tpu.shuffle.mesh_service import run_mesh_reduce_fused
 
 D = 8
 CONF = TpuShuffleConf(connect_timeout_ms=5000)
@@ -36,7 +37,9 @@ def cluster(tmp_path):
     driver.stop()
 
 
-def test_manager_to_mesh_reduce(cluster, mesh):
+# 10,000 rows over 8 devices: one shot, or 4 rounds of 400 rows a device
+@pytest.mark.parametrize("rows_per_round", [0, 400])
+def test_manager_to_mesh_reduce(cluster, mesh, rows_per_round):
     driver, execs = cluster
     num_partitions = 16
     handle = driver.register_shuffle(1, num_maps=4,
@@ -56,7 +59,11 @@ def test_manager_to_mesh_reduce(cluster, mesh):
     truth_k = np.concatenate(truth_k)
     truth_p = np.concatenate(truth_p)
 
-    results = run_mesh_reduce(execs, handle, mesh)
+    before = exchange_mod.DATA_PLANE["exchanges"]
+    results = run_mesh_reduce_fused(execs, handle, mesh,
+                                    rows_per_round=rows_per_round)
+    assert exchange_mod.DATA_PLANE["exchanges"] - before \
+        == (4 if rows_per_round else 1)
 
     got_k, got_p = [], []
     for d, (k, p, parts) in enumerate(results):
@@ -86,13 +93,18 @@ def test_manager_to_mesh_reduce(cluster, mesh):
                                   np.sort(results[0][0]))
 
 
-def test_mesh_reduce_empty_shuffle(cluster, mesh):
+@pytest.mark.parametrize("rows_per_round", [0, 128])
+def test_mesh_reduce_empty_shuffle(cluster, mesh, rows_per_round):
+    """No rows: the one-shot staging and the block stream both come up
+    empty, and every device answers with nothing."""
     driver, execs = cluster
     handle = driver.register_shuffle(2, num_maps=1, num_partitions=4,
                                      partitioner=PartitionerSpec("modulo"))
     w = execs[0].get_writer(handle, 0)
     w.close()  # empty map output
-    results = run_mesh_reduce(execs, handle, mesh)
+    results = run_mesh_reduce_fused(execs, handle, mesh,
+                                    rows_per_round=rows_per_round)
+    assert len(results) == D
     assert all(len(k) == 0 for k, _, _ in results)
 
 
@@ -127,7 +139,9 @@ def test_spark_compat_surface(tmp_path):
         driver.stop()
 
 
-def test_mesh_reduce_overflow_detected(cluster, mesh):
+# 4,096 rows: one shot, or 4 rounds of 128 rows a device
+@pytest.mark.parametrize("rows_per_round", [0, 128])
+def test_mesh_reduce_overflow_detected(cluster, mesh, rows_per_round):
     """All keys hit one partition: skew beyond out_factor must raise, not
     silently truncate."""
     driver, execs = cluster
@@ -137,7 +151,8 @@ def test_mesh_reduce_overflow_detected(cluster, mesh):
     w.write_batch(np.zeros(4096, dtype=np.uint64))  # all -> partition 0
     w.close()
     with pytest.raises(OverflowError):
-        run_mesh_reduce(execs, handle, mesh, out_factor=2)
+        run_mesh_reduce_fused(execs, handle, mesh, out_factor=2,
+                              rows_per_round=rows_per_round)
 
 
 def test_compat_writer_two_record_iterable(tmp_path):
@@ -162,12 +177,10 @@ def test_compat_writer_two_record_iterable(tmp_path):
         driver.stop()
 
 
-def test_streamed_mesh_reduce_matches_one_shot(cluster, mesh):
-    """Bounded-round staging produces the same per-device reduce as the
-    one-shot path (same keys in order, same full-row multiset), with
-    rounds small enough to force many exchanges."""
-    from sparkrdma_tpu.shuffle.mesh_service import run_mesh_reduce_streamed
-
+def test_fused_rounds_match_one_shot(cluster, mesh):
+    """Bounded rounds produce the same per-device reduce as one shot
+    (same keys in order, same full-row multiset), with rounds small
+    enough to force many exchanges."""
     driver, execs = cluster
     handle = driver.register_shuffle(31, num_maps=4, num_partitions=16,
                                      partitioner=PartitionerSpec("modulo"),
@@ -179,59 +192,19 @@ def test_streamed_mesh_reduce_matches_one_shot(cluster, mesh):
                       rng.integers(0, 255, (1500, 8)).astype(np.uint8))
         w.close()
 
-    one_shot = run_mesh_reduce(execs, handle, mesh)
-    streamed = run_mesh_reduce_streamed(execs, handle, mesh,
-                                        rows_per_round=128)  # ~6 rounds
+    one_shot = run_mesh_reduce_fused(execs, handle, mesh, expect_maps=4)
+    rounds = run_mesh_reduce_fused(execs, handle, mesh, expect_maps=4,
+                                   rows_per_round=128)  # 6 rounds
     for d in range(D):
         k1, p1, parts1 = one_shot[d]
-        k2, p2, parts2 = streamed[d]
+        k2, p2, parts2 = rounds[d]
         np.testing.assert_array_equal(k1, k2)
         np.testing.assert_array_equal(parts1, parts2)
         # payload multiset per device (duplicate-key order may differ
-        # between a global stable sort and a merge of the rounds' runs)
+        # between one sort and a merge of the rounds' runs)
         rows1 = np.concatenate([k1[:, None].astype(np.uint64),
                                 p1.astype(np.uint64)], axis=1)
         rows2 = np.concatenate([k2[:, None].astype(np.uint64),
                                 p2.astype(np.uint64)], axis=1)
         np.testing.assert_array_equal(rows1[np.lexsort(rows1.T[::-1])],
                                       rows2[np.lexsort(rows2.T[::-1])])
-
-
-def test_streamed_mesh_reduce_pipelined_matches_sequential(cluster, mesh):
-    """Double-buffered rounds (stage r+1 while r's exchange runs) must be
-    byte-identical to strictly sequential rounds; the A/B times are logged
-    as the overlap evidence this environment can produce."""
-    import time
-
-    from sparkrdma_tpu.shuffle.mesh_service import run_mesh_reduce_streamed
-
-    driver, execs = cluster
-    handle = driver.register_shuffle(41, num_maps=4, num_partitions=16,
-                                     partitioner=PartitionerSpec("modulo"),
-                                     row_payload_bytes=8)
-    rng = np.random.default_rng(11)
-    for m in range(4):
-        w = execs[m % 2].get_writer(handle, m)
-        w.write_batch(rng.integers(0, 1 << 30, 20_000).astype(np.uint64),
-                      rng.integers(0, 255, (20_000, 8)).astype(np.uint8))
-        w.close()
-
-    kw = dict(rows_per_round=1024, expect_maps=4)  # ~10 rounds
-    # warm the compile, then time both modes
-    run_mesh_reduce_streamed(execs, handle, mesh, **kw)
-    t0 = time.monotonic()
-    piped = run_mesh_reduce_streamed(execs, handle, mesh,
-                                     pipeline_rounds=True, **kw)
-    t_piped = time.monotonic() - t0
-    t0 = time.monotonic()
-    seq = run_mesh_reduce_streamed(execs, handle, mesh,
-                                   pipeline_rounds=False, **kw)
-    t_seq = time.monotonic() - t0
-    for d in range(D):
-        np.testing.assert_array_equal(piped[d][0], seq[d][0])
-        np.testing.assert_array_equal(piped[d][1], seq[d][1])
-        np.testing.assert_array_equal(piped[d][2], seq[d][2])
-    total = sum(len(k) for k, _, _ in piped)
-    assert total == 4 * 20_000
-    print(f"\nstreamed mesh reduce ~10 rounds: pipelined {t_piped:.3f}s "
-          f"vs sequential {t_seq:.3f}s")

@@ -120,28 +120,34 @@ def test_streamed_terasort_sentinel_keys_survive(mesh):
     assert int((got[:, 0] == 0xFFFFFFFF).sum()) == n_max
 
 
-def test_sort_modes_match_gather(mesh):
-    """sort_mode='multisort' (payload through the sort network as rank-1
-    operands) and 'colsort' (one stable 2D sort with broadcast keys) are
-    bit-identical to the gather path — the stable per-column permutation
-    argument colsort relies on is proven here, duplicate keys included
-    (payload_words=6, 4096 rows over a 2^32 key space has collisions
-    across devices; seed 9 also collides within)."""
-    from sparkrdma_tpu.models.terasort import (TeraSortConfig, generate_rows,
-                                               run_terasort, verify_terasort)
+def test_one_local_sort_orders_ties_by_arrival(mesh):
+    """The device plane has one local sort. ``TeraSortConfig`` still
+    carries ``sort_mode`` for the benchmark driver's sake and takes
+    nothing but "gather"; the step builder and the drivers have no such
+    parameter. The sort's ``iota`` tiebreak orders duplicate keys by
+    position, so with duplicates forced the step's output is numpy's
+    stable pipeline record for record (payload_words=6, keys quantized
+    to their top 12 bits: ~4k distinct keys over 4k rows, still uniform
+    across the device ranges)."""
+    import inspect
 
-    rows = generate_rows(TeraSortConfig(rows_per_device=512, payload_words=6),
-                         8, seed=9)
-    # force key duplicates so tie-handling differences would surface
-    # (quantize to the top 12 bits: ~4k distinct keys over 4k rows, still
-    # uniform across the device ranges)
+    from sparkrdma_tpu.parallel.device_plane import (
+        make_fused_step,
+        run_fused_exchange_rounds,
+    )
+
+    with pytest.raises(ValueError, match="sort_mode"):
+        TeraSortConfig(rows_per_device=512, sort_mode="colsort")
+    for fn in (make_fused_step, run_fused_exchange_rounds):
+        assert "sort_mode" not in inspect.signature(fn).parameters
+
+    cfg = TeraSortConfig(rows_per_device=512, payload_words=6, out_factor=2,
+                         sort_mode="gather")
+    rows = generate_rows(cfg, D, seed=9)
     rows[:, 0] &= 0xFFF00000
-    outs = {}
-    for mode in ("gather", "multisort", "colsort"):
-        cfg = TeraSortConfig(rows_per_device=512, payload_words=6,
-                             out_factor=2, sort_mode=mode)
-        out, counts, _ = run_terasort(mesh, cfg, rows=rows)
-        verify_terasort(out, counts, rows, 8)
-        outs[mode] = out
-    np.testing.assert_array_equal(outs["gather"], outs["multisort"])
-    np.testing.assert_array_equal(outs["gather"], outs["colsort"])
+    out, counts, _ = run_terasort(mesh, cfg, rows=rows)
+    verify_terasort(out, counts, rows, D)
+    per_dev = out.reshape(D, -1, out.shape[-1])
+    got = np.concatenate([per_dev[d][:int(counts[d].sum())]
+                          for d in range(D)])
+    np.testing.assert_array_equal(got, numpy_terasort(rows, D))

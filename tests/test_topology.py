@@ -3,13 +3,11 @@ the generalized cost model (hierarchical plan kind, single-slice
 degenerate parity), the factored hierarchical exchange (byte parity vs
 the flat device plan and a host reference across uniform / zipfian /
 slice-affine inputs, empty slices, per-slice degrade), the link-cost-
-aware partition layout and planner placement, the
-``mesh_rows_per_round`` deprecation latch, bench provenance, and the
+aware partition layout and planner placement, bench provenance, and the
 topo microbench acceptance gates. Seed swept by
 ``scripts/run_topo_bench.sh`` via ``TOPO_SEED``."""
 
 import os
-import warnings
 
 import jax
 import numpy as np
@@ -19,7 +17,6 @@ from jax.sharding import Mesh
 from engine_helpers import u32_payload as _u32_payload
 from sparkrdma_tpu.config import TpuShuffleConf
 from sparkrdma_tpu.engine import DAGEngine, MapStage, ResultStage
-from sparkrdma_tpu.parallel import device_plane as device_plane_mod
 from sparkrdma_tpu.parallel import exchange as exchange_mod
 from sparkrdma_tpu.parallel import topology as topology_mod
 from sparkrdma_tpu.parallel.device_plane import (
@@ -439,21 +436,6 @@ def test_planner_link_cost_placement():
 
 
 # -- satellites ----------------------------------------------------------
-
-def test_mesh_rows_per_round_deprecation_warns_once():
-    device_plane_mod._rows_knob_warned = False
-    with pytest.warns(DeprecationWarning, match="mesh_rows_per_round"):
-        device_plane_mod.warn_mesh_rows_deprecated()
-    with warnings.catch_warnings(record=True) as later:
-        warnings.simplefilter("always")
-        device_plane_mod.warn_mesh_rows_deprecated()
-    assert not later, "deprecation warning not latched once per process"
-    # the conf key parses (mixed-version configs stay loadable) and
-    # defaults to auto-sizing
-    assert TpuShuffleConf().mesh_rows_per_round == 0
-    assert TpuShuffleConf(mesh_rows_per_round=256).mesh_rows_per_round \
-        == 256
-
 
 def test_bench_round_provenance_records_topology():
     import bench as bench_mod

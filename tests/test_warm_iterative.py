@@ -292,8 +292,7 @@ def test_dense_exchange_bench_guard():
     devs = jax.devices()
     mesh = Mesh(np.array(devs), ("shuffle",))
     cfg = TeraSortConfig(rows_per_device=512, payload_words=24,
-                         out_factor=1 if len(devs) == 1 else 2,
-                         sort_mode="gather")
+                         out_factor=1 if len(devs) == 1 else 2)
     rows = generate_rows(cfg, len(devs), seed=1)
     detail = {}
     bench_mod._bench_dense_guard(detail, mesh, "dense", cfg, rows)
