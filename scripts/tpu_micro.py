@@ -1,8 +1,9 @@
-"""Micro-benchmarks for the TeraSort local-sort bottleneck on hardware.
+"""Micro-benchmarks of single kernels on hardware: the TeraSort local sort,
+the row move, PageRank's per-edge gather.
 
-Two modes, both for a TPU: they time the device. ``rowmove`` exits
-non-zero anywhere else; ``sort`` runs anywhere, and off the chip its
-numbers mean nothing.
+Three modes, all for a TPU: they time the device. ``rowmove`` and
+``gather`` exit non-zero anywhere else; ``sort`` runs anywhere, and off
+the chip its numbers mean nothing.
 
 ``python scripts/tpu_micro.py [sort] [n_rows]``
     the two phases of the local sort apart across row widths: the
@@ -14,6 +15,14 @@ numbers mean nothing.
     parts (pack, permute, unpack) timed apart, ns a row. One JSON object a
     line on stdout, and the whole table in ``out.json`` (default
     ``chiprun_out/rowmove.json``). No benchmark cell runs it.
+
+``python scripts/tpu_micro.py gather [out.json]``
+    PageRank's contribution phase alone at ``pagerank_1chip``'s shape
+    (16,777,280 indices into ``f32[468750]``; ``PERF.md`` section 6,
+    PR 32): one gather of a table against the two gathers and the per-edge
+    divide, and the table built in the program as the superstep builds
+    it; random indices against sorted ones, ns an index. Output as
+    ``rowmove``'s (default ``chiprun_out/gather.json``).
 """
 
 import json
@@ -30,6 +39,7 @@ import jax.numpy as jnp
 
 ROWMOVE_N = (1 << 17, 1 << 18, 1 << 20, 1 << 22, 10_737_418)
 ROWMOVE_W = (2, 8, 16, 25, 32)
+GATHER_INDICES, GATHER_TABLE = 16_777_280, 468_750
 
 
 def timeit(fn, *args, reps=5):
@@ -135,30 +145,81 @@ def rowmove_point(n, w, seed=0):
     return point
 
 
-def rowmove_main(out_path):
+def _tpu_table(mode, why):
+    """The output table's head; exits where the device is no TPU: a time
+    taken anywhere else must not stand under the names of the chip's."""
     device = jax.devices()[0]
     if device.platform != "tpu":
-        # the kernels would have to be interpreted, and an interpreter's
-        # times must not stand under the names of the chip's
-        sys.exit(f"tpu_micro.py rowmove: needs a TPU, found "
-                 f"{device.platform!r}; tests/test_row_permute.py runs the "
-                 "kernels interpreted")
-    table = {"device": {"platform": device.platform,
-                        "kind": device.device_kind}, "points": []}
+        sys.exit(f"tpu_micro.py {mode}: needs a TPU, found "
+                 f"{device.platform!r}; {why}")
+    return {"device": {"platform": device.platform,
+                       "kind": device.device_kind}, "points": []}
+
+
+def _write_table(table, out_path):
+    os.makedirs(os.path.dirname(out_path) or ".", exist_ok=True)
+    with open(out_path, "w") as f:
+        json.dump(table, f, indent=1)
+
+
+def rowmove_main(out_path):
+    table = _tpu_table("rowmove", "tests/test_row_permute.py runs the "
+                       "kernels interpreted")
     for w in ROWMOVE_W:
         for n in ROWMOVE_N:
             point = rowmove_point(n, w)
             table["points"].append(point)
             print(json.dumps(point), flush=True)
-    os.makedirs(os.path.dirname(out_path) or ".", exist_ok=True)
-    with open(out_path, "w") as f:
-        json.dump(table, f, indent=1)
+    _write_table(table, out_path)
+
+
+# the contribution phase's three forms: what the superstep computed an edge
+# before PR 32, what it computes now, and the gather alone
+GATHER_FORMS = {
+    "two_gathers": lambda ranks, deg, idx:
+        ranks[idx] / jnp.maximum(deg[idx], 1.0),
+    "table_then_gather": lambda ranks, deg, idx:
+        (ranks / jnp.maximum(deg, 1.0))[idx],
+    "one_gather": lambda ranks, deg, idx: ranks[idx],
+}
+
+
+def gather_points(n_indices, n_table, seed=0):
+    """ns an index of each form, for uniform random indices (the graph's
+    sources as ``powerlaw_graph`` draws them) and for the same indices
+    sorted (edges ordered by source at placement)."""
+    k_idx, k_ranks, k_deg = jax.random.split(jax.random.key(seed), 3)
+    ranks = jax.random.uniform(k_ranks, (n_table,), jnp.float32)
+    deg = jax.random.randint(k_deg, (n_table,), 0, 72).astype(jnp.float32)
+    random = jax.random.randint(k_idx, (n_indices,), 0, n_table, jnp.int32)
+    want = jax.jit(GATHER_FORMS["two_gathers"])(ranks, deg, random)
+    got = jax.jit(GATHER_FORMS["table_then_gather"])(ranks, deg, random)
+    equal = bool(jnp.array_equal(got, want))   # the same float32 quotient
+    del got, want
+    for order, idx in (("random", random), ("sorted", jnp.sort(random))):
+        for form, fn in GATHER_FORMS.items():
+            yield {"n_indices": n_indices, "n_table": n_table,
+                   "indices": order, "form": form,
+                   "table_equals_two_gathers": equal,
+                   "ns_index": time_queued(fn, ranks, deg, idx)
+                   / n_indices * 1e9}
+
+
+def gather_main(out_path):
+    table = _tpu_table("gather", "the CPU's gather is another program")
+    for point in gather_points(GATHER_INDICES, GATHER_TABLE):
+        table["points"].append(point)
+        print(json.dumps(point), flush=True)
+    _write_table(table, out_path)
 
 
 def main():
     args = sys.argv[1:]
     if args and args[0] == "rowmove":
         rowmove_main(args[1] if len(args) > 1 else "chiprun_out/rowmove.json")
+        return
+    if args and args[0] == "gather":
+        gather_main(args[1] if len(args) > 1 else "chiprun_out/gather.json")
         return
     if args and args[0] == "sort":
         args = args[1:]

@@ -8,8 +8,9 @@ that stresses *repeated* exchange with stable routing.
 TPU-native design: vertices are range-sharded over the mesh; edges live on
 their source vertex's device. One iteration is one jitted SPMD step:
 
-1. contribution per local edge = rank[src] / out_degree[src] (local gather
-   — src is local by construction);
+1. contribution per local edge: the per-vertex divide ``rank /
+   out_degree`` makes one table, and one per-edge gather reads it at
+   ``src`` (local by construction): one random read an edge;
 2. ragged exchange of ``(dst, contribution)`` rows to dst's owner device
    (the GraphX shuffle);
 3. segment-sum received contributions into local ranks (one scatter-add),
@@ -92,7 +93,8 @@ def make_pagerank_step(mesh: Mesh, axis_name: str, cfg: PageRankConfig,
     land, so the receiver needs no count of them.
 
     A device profile names the step's three phases by scope:
-    ``pagerank.contrib`` (the two per-edge gathers and the divide),
+    ``pagerank.contrib`` (one per-edge gather of ``rank / out_degree``
+    and the per-vertex divide that makes that table),
     ``pagerank.exchange`` (grouping, with its ``row_gather``, and the
     transport) and ``pagerank.accumulate`` (masking, scatter-add,
     damping). ``step.row_moves`` lists the form the grouping's row move
@@ -121,10 +123,10 @@ def make_pagerank_step(mesh: Mesh, axis_name: str, cfg: PageRankConfig,
             valid = src >= 0
             # local rank lookup: src ids are local to this shard
             src_local = jnp.where(valid, src - me * v_local, 0)
-            contrib = jnp.where(
-                valid,
-                ranks[src_local] / jnp.maximum(out_deg[src_local], 1.0),
-                0.0)
+            # the quotient once a vertex (the float32 division an edge
+            # would make), then ONE random read an edge
+            share = ranks / jnp.maximum(out_deg, 1.0)
+            contrib = jnp.where(valid, share[src_local], 0.0)
             dest_dev = jnp.where(valid, dst // v_local, -1)
         with jax.named_scope("pagerank.exchange"):
             # fill records: the first ``(-count) % 64`` of each

@@ -3,11 +3,13 @@ the job entry over a resident graph, its spans, counters and scopes, and
 the system against the benchmark's float64 reference on one virtual device
 and on four (the one-chip cut's tie to the deployment)."""
 
+import functools
 import os
 import re
 import sys
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh
@@ -200,3 +202,83 @@ def test_the_three_scopes_name_the_steps_ops():
                    for n in names), (scope, kernel)
     assert not any(n.endswith("scatter-add") and "pagerank.exchange" not in n
                    and "pagerank.accumulate" not in n for n in names)
+
+
+# -- the contribution phase: one gather of the per-vertex table ---------------
+
+def _awkward_graph(cfg, devices):
+    """Padding rows, vertices nobody leaves, and a hub most edges enter."""
+    edges, _, _ = powerlaw_graph(cfg, devices, seed=2**31 + 7, zipf_s=ZIPF_S)
+    edges[::3, 1] = 5                       # the hub: a long float32 sum
+    edges[7::cfg.edges_per_device // 5, 0] = -1
+    valid = edges[:, 0] >= 0
+    out_deg = np.bincount(edges[valid, 0],
+                          minlength=cfg.num_vertices).astype(np.float32)
+    assert (~valid).any() and (out_deg == 0).any()
+    return edges, out_deg
+
+
+@functools.partial(jax.jit, static_argnames="damping")
+def _two_gather_superstep(edges, ranks, out_deg, damping):
+    """The superstep as it stood: ``ranks`` and ``out_deg`` gathered apart
+    and divided once an edge. One device's plain program; contributions
+    reach a vertex in the edges' order, as the stable grouping and the
+    exchange deliver them."""
+    src, dst = edges[:, 0], edges[:, 1]
+    valid = src >= 0
+    src = jnp.where(valid, src, 0)
+    contrib = jnp.where(
+        valid, ranks[src] / jnp.maximum(out_deg[src], 1.0), 0.0)
+    sums = jnp.zeros_like(ranks).at[jnp.where(valid, dst, 0)].add(contrib)
+    return (1.0 - damping) / ranks.shape[0] + damping * sums
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+def test_one_table_gather_gives_the_two_gather_ranks_bit_for_bit(devices):
+    num_v, total = 1024, 4096
+    cfg = PageRankConfig(num_vertices=num_v,
+                         edges_per_device=total // devices, out_factor=4)
+    edges, out_deg = _awkward_graph(cfg, devices)
+    step = make_pagerank_step(_mesh(devices), AXIS, cfg)
+    ranks = want = np.full(num_v, 1.0 / num_v, np.float32)
+    for _ in range(3):
+        ranks, received, overflowed = step(edges, ranks, out_deg)
+        assert not np.asarray(overflowed).any()
+        assert np.asarray(received)[:, 0].sum() == (edges[:, 0] >= 0).sum()
+        want = _two_gather_superstep(edges, want, out_deg, cfg.damping)
+    ranks, want = np.asarray(ranks), np.asarray(want)
+    assert len(np.unique(ranks)) > num_v // 4     # no trivial fixed point
+    np.testing.assert_array_equal(ranks.view(np.uint32),
+                                  want.view(np.uint32))
+
+
+def _equations(jaxpr, scope=""):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it, with the
+    path of named scopes it lies under."""
+    for eqn in jaxpr.eqns:
+        path = f"{scope}/{eqn.source_info.name_stack}"
+        yield path, eqn
+        for param in eqn.params.values():
+            inner = getattr(param, "jaxpr", param)
+            if hasattr(inner, "eqns"):
+                yield from _equations(inner, path)
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+def test_contrib_is_one_gather_of_a_per_vertex_quotient(devices):
+    num_v, per_dev = 256 * devices, 1024
+    cfg = PageRankConfig(num_vertices=num_v, edges_per_device=per_dev)
+    edges, ranks, out_deg = powerlaw_graph(cfg, devices, seed=1,
+                                           zipf_s=ZIPF_S)
+    step = make_pagerank_step(_mesh(devices), AXIS, cfg)
+    contrib = [(path, eqn) for path, eqn in _equations(
+        jax.make_jaxpr(step)(edges, ranks, out_deg).jaxpr)
+        if "pagerank.contrib" in path]
+    gather, = [eqn for _, eqn in contrib if eqn.primitive.name == "gather"]
+    # the float divide is the table's: once a vertex, none an edge
+    divide, = [eqn for _, eqn in contrib if eqn.primitive.name == "div"
+               and eqn.outvars[0].aval.dtype == np.float32]
+    table = divide.outvars[0]
+    assert table.aval.shape == (num_v // devices,)
+    assert gather.invars[0] is table
+    assert gather.outvars[0].aval.shape == (per_dev,)
