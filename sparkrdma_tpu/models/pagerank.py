@@ -35,11 +35,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from sparkrdma_tpu.ops.row_permute import forms_label
 from sparkrdma_tpu.parallel.exchange import (
-    group_by_destination,
-    ragged_exchange_shard,
+    record_capacity,
     record_exchange,
     resolve_impl,
     row_mover,
+    shuffle_records_shard,
 )
 from sparkrdma_tpu.utils import trace
 
@@ -50,21 +50,6 @@ class PageRankConfig:
     edges_per_device: int      # local edge capacity (padded)
     damping: float = 0.85
     out_factor: int = 2
-
-
-# Records a wire row carries. The TPU's ragged all-to-all moves a row as
-# 128 32-bit lanes (512 bytes) whatever its width: an 8-byte record sent as
-# a row of its own is padded 64-fold, in the send buffer and in the receive
-# buffer (1,536 bytes of HBM an edge at ``out_factor`` 2, so a chip could
-# not hold 10^7 edges). 64 records fill the lanes exactly.
-WIRE_RECORDS = 64
-
-
-def wire_rows(cfg: PageRankConfig, num_devices: int) -> int:
-    """Wire rows a device sends at most: its edges in whole rows, and one
-    more for each destination's last, partly filled row. The receive
-    buffer holds ``out_factor`` times as many."""
-    return -(-cfg.edges_per_device // WIRE_RECORDS) + num_devices
 
 
 def make_pagerank_step(mesh: Mesh, axis_name: str, cfg: PageRankConfig,
@@ -80,17 +65,18 @@ def make_pagerank_step(mesh: Mesh, axis_name: str, cfg: PageRankConfig,
 
     Returns ``(ranks, received[D, 2], overflowed[D])``: ``received[d]``
     is the number of contributions device d was sent and the number of
-    wire rows they came in; ``overflowed[d]`` flags a receive buffer too
+    records they came as (the exchange's fill included);
+    ``overflowed[d]`` flags a receive buffer too
     small for that fan-in (results invalid — raise ``out_factor``),
     mirroring the TeraSort/join steps.
 
     The shuffle's record is 8 bytes, ``(u32 dst, f32 contribution)``,
-    grouped by destination device as rows ``u32[E, 2]``
-    (``group_by_destination``). On the wire ``WIRE_RECORDS`` records of
-    one destination travel as one row of 128 lanes: each destination's
-    group is first filled up to whole wire rows with records ``(the
-    destination's first vertex, 0.0)``, which add nothing where they
-    land, so the receiver needs no count of them.
+    rows ``u32[E, 2]`` through ``exchange.shuffle_records_shard``: narrow
+    rows, so 64 records of one destination travel as one
+    wire row of 128 lanes (``exchange.pack_exchange_shard``). The fill
+    record the exchange asks for is ``(the destination's first vertex,
+    0.0)``, which adds nothing where it lands, so the receiver needs no
+    count of them.
 
     A device profile names the step's three phases by scope:
     ``pagerank.contrib`` (one per-edge gather of ``rank / out_degree``
@@ -104,9 +90,6 @@ def make_pagerank_step(mesh: Mesh, axis_name: str, cfg: PageRankConfig,
     impl = resolve_impl(mesh, impl, axis_name)
     v_local = cfg.num_vertices // n
     spec = P(axis_name)
-    fill = n * WIRE_RECORDS   # records that may be needed to fill groups up
-    rows_out = wire_rows(cfg, n)
-    slack = rows_out * WIRE_RECORDS - cfg.edges_per_device - fill
     # the form the grouping's row move took, filled while the step is
     # traced (ops.row_permute): step.row_moves
     row_moves: list = []
@@ -129,50 +112,25 @@ def make_pagerank_step(mesh: Mesh, axis_name: str, cfg: PageRankConfig,
             contrib = jnp.where(valid, share[src_local], 0.0)
             dest_dev = jnp.where(valid, dst // v_local, -1)
         with jax.named_scope("pagerank.exchange"):
-            # fill records: the first ``(-count) % 64`` of each
-            # destination's 64 are sent to it, the others to nobody
-            devices = jnp.arange(n, dtype=jnp.int32)
-            # a compare and a sum: a bincount is a scatter-add of E rows
-            counts = jnp.sum(dest_dev[:, None] == devices[None, :], axis=0,
-                             dtype=jnp.int32)
-            lane = jnp.arange(WIRE_RECORDS, dtype=jnp.int32)
-            fill_dest = jnp.where(
-                lane[None, :] < (-counts % WIRE_RECORDS)[:, None],
-                devices[:, None], -1).reshape(-1)
-            fill_dst = jnp.repeat(devices * v_local, WIRE_RECORDS)
             # rows: (dst, contribution bits) — one u32 matrix to group
             rows = jnp.stack([
-                jnp.concatenate([dst, fill_dst, jnp.zeros(slack, jnp.int32)]
-                                ).astype(jnp.uint32),
-                jax.lax.bitcast_convert_type(
-                    jnp.concatenate([contrib.astype(jnp.float32),
-                                     jnp.zeros(fill + slack, jnp.float32)]),
-                    jnp.uint32)], axis=1)
-            dest_dev = jnp.concatenate(
-                [dest_dev, fill_dest, jnp.full(slack, -1, jnp.int32)])
-            grouped, sent = group_by_destination(rows, dest_dev, n, move)
-            wire = jnp.concatenate(
-                [grouped[:, 0].reshape(rows_out, WIRE_RECORDS),
-                 grouped[:, 1].reshape(rows_out, WIRE_RECORDS)], axis=1)
-            output = jnp.zeros((rows_out * cfg.out_factor,
-                                2 * WIRE_RECORDS), jnp.uint32)
-            received, recv_rows, _, overflowed = ragged_exchange_shard(
-                wire, sent // WIRE_RECORDS, axis_name, output=output,
-                impl=impl)
-            contributions = jax.lax.all_gather(counts, axis_name)[:, me].sum()
+                dst.astype(jnp.uint32),
+                jax.lax.bitcast_convert_type(contrib.astype(jnp.float32),
+                                             jnp.uint32)], axis=1)
+            devices = jnp.arange(n, dtype=jnp.uint32)
+            fill = jnp.stack([devices * v_local, jnp.zeros_like(devices)],
+                             axis=1)
+            received, recv_counts, contributions, overflowed = \
+                shuffle_records_shard(rows, dest_dev, fill, axis_name, n,
+                                      cfg.out_factor, impl, move)
         with jax.named_scope("pagerank.accumulate"):
-            total = recv_rows.sum()
-            rvalid = jnp.repeat(
-                jnp.arange(received.shape[0], dtype=jnp.int32) < total,
-                WIRE_RECORDS)
+            total = recv_counts.sum()
+            rvalid = jnp.arange(received.shape[0], dtype=jnp.int32) < total
             rdst = jnp.where(
-                rvalid,
-                received[:, :WIRE_RECORDS].reshape(-1).astype(jnp.int32)
-                - me * v_local, 0)
+                rvalid, received[:, 0].astype(jnp.int32) - me * v_local, 0)
             rcontrib = jnp.where(
                 rvalid,
-                jax.lax.bitcast_convert_type(
-                    received[:, WIRE_RECORDS:].reshape(-1), jnp.float32),
+                jax.lax.bitcast_convert_type(received[:, 1], jnp.float32),
                 0.0)
             sums = jnp.zeros(v_local, jnp.float32).at[rdst].add(rcontrib)
             new_ranks = ((1.0 - cfg.damping) / cfg.num_vertices
@@ -292,8 +250,9 @@ class PageRankJob:
     at its end ``received``, the contributions delivered in each
     superstep, and ``row_move``, the form the rows followed their order
     in) around ``pagerank.dispatch`` and ``pagerank.wait``.
-    Counters, per job: ``pagerank.recv_fill`` (most wire rows any device
-    received over its receive capacity) and ``pagerank.max_in_degree``.
+    Counters, per job: ``pagerank.recv_fill`` (most records any device
+    received, the exchange's fill among them, over its receive capacity)
+    and ``pagerank.max_in_degree``.
     """
 
     def __init__(self, mesh: Mesh, axis_name: str, cfg: PageRankConfig,
@@ -302,9 +261,9 @@ class PageRankJob:
         self.iterations = iterations
         self.tracer = tracer
         self._step = make_pagerank_step(mesh, axis_name, cfg, impl)
-        # the receive buffer, in wire rows (the step's ``output``)
-        self._capacity = cfg.out_factor * wire_rows(
-            cfg, mesh.shape[axis_name])
+        # the receive buffer, in records (the exchange's ``output``)
+        self._capacity = record_capacity(
+            cfg.edges_per_device, 2, mesh.shape[axis_name], cfg.out_factor)
         self._reset = jax.jit(
             lambda: jnp.full(cfg.num_vertices, 1.0 / cfg.num_vertices,
                              jnp.float32),

@@ -24,6 +24,16 @@ Everything is static-shape: ``data`` and ``output`` are fixed-capacity
 buffers; raggedness lives in the offset/size vectors, which is what keeps
 XLA happy (no dynamic shapes under jit).
 
+Narrow rows. The TPU's ragged all-to-all moves every row as 128 lanes, so
+rows under ``ops.row_permute.MIN_PACKED_WORDS`` words travel
+``wire_records`` to a wire row (``wire_form``, ``pack_exchange_shard``,
+``shuffle_records_shard``): the one packer in the tree, chosen at trace
+time from the row's width alone, on every platform, so the CPU tests run
+the program the chip runs. PageRank's 2-word records and q95's 3-, 2- and
+4-word rows ride it; ``models/join.py``, ``models/tpcds.py`` and q64 keep
+the unpacked ``shuffle_shard`` by name (toy sizes, no fill record of
+their own).
+
 Transports (``impl``). ``"auto"``, what every caller passes by default,
 resolves per mesh (``resolve_impl``): ``native`` on a TPU mesh, ``dense``
 where the compiler rejects the probe compile of the ragged opcode,
@@ -59,7 +69,7 @@ import numpy as np
 from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from sparkrdma_tpu.ops.row_permute import permute_rows
+from sparkrdma_tpu.ops.row_permute import MIN_PACKED_WORDS, permute_rows
 
 # Host-side dispatch tally for the ICI data plane. Callers that launch a
 # collective exchange (mesh_service, models) record here so tests and the
@@ -342,6 +352,144 @@ def shuffle_shard(data: jnp.ndarray, dest: jnp.ndarray, axis_name: str,
     ``ragged_exchange_shard``."""
     grouped, counts = group_by_destination(data, dest, num_devices, move)
     return ragged_exchange_shard(grouped, counts, axis_name, output, impl)
+
+
+# --- narrow rows: records to a wire row ---------------------------------
+# The TPU's ragged all-to-all moves a row as 128 32-bit lanes (512 bytes)
+# whatever its width: an 8-byte record sent as a row of its own is padded
+# 64-fold, in the send buffer and in the receive buffer (1,536 bytes of HBM
+# a record at ``out_factor`` 2: a chip could not hold 10^7 of them; PERF.md
+# section 6, PR 28).
+WIRE_LANES = 128
+
+
+def wire_records(row_words: int) -> int:
+    """Records of ``row_words`` 32-bit words that one wire row carries."""
+    return WIRE_LANES // row_words
+
+
+def wire_form(row_words: int) -> str:
+    """``"packed"`` or ``"rows"``: how ``shuffle_records_shard`` sends rows
+    of ``row_words`` words. The edge is ``ops.row_permute.row_move_form``'s,
+    one constant and not a second: a row under ``MIN_PACKED_WORDS`` words
+    is narrow, and narrow rows travel ``wire_records`` to a wire row, on
+    any platform (the 128-lane padding is the TPU's; one program
+    everywhere is the tests'). Pure; decided at trace time."""
+    return "packed" if row_words < MIN_PACKED_WORDS else "rows"
+
+
+def wire_rows(n_rows: int, row_words: int, num_devices: int) -> int:
+    """Wire rows a device sends at most in the packed form: its
+    ``n_rows`` records in whole rows, and one more for each destination's
+    last, partly filled row."""
+    return -(-n_rows // wire_records(row_words)) + num_devices
+
+
+def record_capacity(n_rows: int, row_words: int, num_devices: int,
+                    out_factor: int) -> int:
+    """Records the receive buffer of ``shuffle_records_shard`` holds for a
+    device that sends ``n_rows``: ``out_factor`` times what it sends at
+    most, which in the packed form is ``wire_rows`` whole wire rows."""
+    if wire_form(row_words) == "packed":
+        return (out_factor * wire_rows(n_rows, row_words, num_devices)
+                * wire_records(row_words))
+    return out_factor * n_rows
+
+
+def pack_exchange_shard(rows: jnp.ndarray, dest: jnp.ndarray,
+                        fill: jnp.ndarray, axis_name: str, num_devices: int,
+                        out_factor: int = 1, impl: str = "native",
+                        move=permute_rows):
+    """The shuffle of narrow rows, ``wire_records`` records to a wire row.
+    Call inside ``shard_map``; plain ``jax.numpy``, any platform, any
+    transport. ``shuffle_records_shard`` picks it by ``wire_form``.
+
+    ``rows u32[N, W]`` go to ``dest i32[N]`` (outside ``[0,
+    num_devices)``: padding, not sent). Each destination's group is
+    filled up to whole wire rows with copies of ``fill[d] u32[W]``, the
+    CALLER's record for destination ``d`` that does no harm where it
+    lands (PageRank: ``(d's first vertex, 0.0)``; a join: its dead key).
+    The fill rows are appended before the grouping, so one
+    ``group_by_destination`` orders records and fill alike and the wire
+    rows are a reshape of its result: lanes ``[k * R, (k + 1) * R)`` of a
+    wire row hold word ``k`` of its ``R = wire_records(W)`` records, and
+    the lanes past ``W * R`` are zero.
+
+    Returns ``(records, recv_counts, delivered, overflowed)``:
+    ``records u32[record_capacity, W]``, grouped by source as
+    ``ragged_exchange_shard`` delivers them, each source's records in
+    their sender's stable order and followed by at most ``R - 1`` copies
+    of ``fill[me]``; ``recv_counts i32[D]`` in records, FILL INCLUDED (a
+    multiple of ``R`` each): the records at positions under
+    ``recv_counts.sum()`` are senders' records or fill, and the receiver
+    does not tell them apart: that is what the harmless fill is for;
+    ``delivered``, the senders' own records this device was sent, fill
+    excluded; ``overflowed`` as ``ragged_exchange_shard`` states it, in
+    wire rows."""
+    n = num_devices
+    n_rows, words = rows.shape
+    per_row = wire_records(words)
+    rows_out = wire_rows(n_rows, words, n)
+    slack = rows_out * per_row - n_rows - n * per_row
+    devices = jnp.arange(n, dtype=jnp.int32)
+    # a compare and a sum: a bincount is a scatter-add of N rows
+    counts = jnp.sum(dest[:, None] == devices[None, :], axis=0,
+                     dtype=jnp.int32)
+    # fill records: the first ``(-count) % R`` of each destination's R are
+    # sent to it, the others to nobody
+    lane = jnp.arange(per_row, dtype=jnp.int32)
+    fill_dest = jnp.where(lane[None, :] < (-counts % per_row)[:, None],
+                          devices[:, None], -1).reshape(-1)
+    # a column at a time, not the matrix at once: the same values, but the
+    # chip's compiler then keeps the result of PageRank's per-edge gather,
+    # which feeds a column, in fast memory (PERF.md section 6, PR 33)
+    fill = fill.astype(rows.dtype)
+    rows = jnp.stack([jnp.concatenate(
+        [rows[:, k], jnp.repeat(fill[:, k], per_row),
+         jnp.zeros(slack, rows.dtype)]) for k in range(words)], axis=1)
+    dest = jnp.concatenate(
+        [dest.astype(jnp.int32), fill_dest, jnp.full(slack, -1, jnp.int32)])
+    grouped, sent = group_by_destination(rows, dest, n, move)
+    lanes = [grouped[:, k].reshape(rows_out, per_row) for k in range(words)]
+    if words * per_row < WIRE_LANES:
+        lanes.append(jnp.zeros((rows_out, WIRE_LANES - words * per_row),
+                               rows.dtype))
+    wire = jnp.concatenate(lanes, axis=1)
+    output = jnp.zeros((rows_out * out_factor, WIRE_LANES), rows.dtype)
+    received, recv_rows, _, overflowed = ragged_exchange_shard(
+        wire, sent // per_row, axis_name, output=output, impl=impl)
+    records = jnp.stack(
+        [received[:, k * per_row:(k + 1) * per_row].reshape(-1)
+         for k in range(words)], axis=1)
+    me = lax.axis_index(axis_name)
+    delivered = lax.all_gather(counts, axis_name)[:, me].sum()
+    return records, recv_rows * per_row, delivered, overflowed
+
+
+def shuffle_records_shard(rows: jnp.ndarray, dest: jnp.ndarray,
+                          fill: jnp.ndarray, axis_name: str,
+                          num_devices: int, out_factor: int = 1,
+                          impl: str = "native", move=permute_rows):
+    """``shuffle_shard`` for records that may be narrow: group by
+    destination device, exchange, and hand back records. The form is
+    ``wire_form(W)``'s: ``pack_exchange_shard`` for rows under
+    ``MIN_PACKED_WORDS`` words, else ``shuffle_shard`` into a buffer of
+    ``record_capacity`` rows (``fill`` then goes unused). No option and
+    no platform selects the form.
+
+    Returns ``pack_exchange_shard``'s four, and a caller that keeps to
+    them is right at any width: it treats every position under
+    ``recv_counts.sum()`` as a record, gives a ``fill`` that does no harm
+    there, and counts what it was sent by ``delivered``."""
+    if wire_form(rows.shape[1]) == "packed":
+        return pack_exchange_shard(rows, dest, fill, axis_name, num_devices,
+                                   out_factor, impl, move)
+    output = jnp.zeros((out_factor * rows.shape[0], rows.shape[1]),
+                       rows.dtype)
+    received, recv_counts, _, overflowed = shuffle_shard(
+        rows, dest, axis_name, num_devices, output=output, impl=impl,
+        move=move)
+    return received, recv_counts, recv_counts.sum(), overflowed
 
 
 # the one compile-time rejection that selects the dense transport (see

@@ -42,6 +42,9 @@ SPANS = frozenset({
     "pagerank.wait",
     "push.map",
     "push.planned",
+    "q95.dispatch",
+    "q95.job",
+    "q95.wait",
     "write.merge",
     "write.scatter",
     "write.spill",
@@ -103,6 +106,8 @@ COUNTERS = frozenset({
     "pagerank.max_in_degree",
     "pagerank.recv_fill",
     "peer.suspects",
+    "q95.recv_fill",
+    "q95.survivors",
 })
 
 ALL = SPANS | INSTANTS | COUNTERS

@@ -25,8 +25,8 @@ from sparkrdma_tpu.models.pagerank import (  # noqa: E402
     make_pagerank_step,
     place_graph,
     powerlaw_graph,
-    wire_rows,
 )
+from sparkrdma_tpu.parallel import exchange  # noqa: E402
 from sparkrdma_tpu.utils.trace import Tracer  # noqa: E402
 
 AXIS = "shuffle"
@@ -178,10 +178,13 @@ def test_job_spans_and_counters(tmp_path):
     counters = {e["name"]: e["args"]["value"] for e in events
                 if e.get("ph") == "C"}
     assert counters["pagerank.max_in_degree"] == graph.max_in_degree
-    # wire rows: most any device received over its receive capacity
-    assert wire_rows(cfg, devices) == 16 + devices   # 1000 -> 16 rows of 64
-    capacity = cfg.out_factor * wire_rows(cfg, devices)
-    assert 1 / capacity <= counters["pagerank.recv_fill"] <= 1.0
+    # most records any device received over its receive capacity, which
+    # the exchange states: 64 records to a wire row (1000 -> 16 rows of
+    # 64, and one a destination)
+    assert exchange.wire_rows(per_dev, 2, devices) == 16 + devices
+    capacity = exchange.record_capacity(per_dev, 2, devices, cfg.out_factor)
+    assert capacity == cfg.out_factor * (16 + devices) * 64
+    assert 64 / capacity <= counters["pagerank.recv_fill"] <= 1.0
 
 
 def test_the_three_scopes_name_the_steps_ops():
@@ -233,18 +236,26 @@ def _two_gather_superstep(edges, ranks, out_deg, damping):
     return (1.0 - damping) / ranks.shape[0] + damping * sums
 
 
+@pytest.mark.parametrize("impl", ["auto", "dense"])
 @pytest.mark.parametrize("devices", [1, 4])
-def test_one_table_gather_gives_the_two_gather_ranks_bit_for_bit(devices):
+def test_one_table_gather_gives_the_two_gather_ranks_bit_for_bit(
+        devices, impl):
+    """64 records to a wire row, the form the chip runs, over the
+    transport the CPU mesh resolves to and over fixed per-pair slots."""
     num_v, total = 1024, 4096
     cfg = PageRankConfig(num_vertices=num_v,
                          edges_per_device=total // devices, out_factor=4)
     edges, out_deg = _awkward_graph(cfg, devices)
-    step = make_pagerank_step(_mesh(devices), AXIS, cfg)
+    step = make_pagerank_step(_mesh(devices), AXIS, cfg, impl)
     ranks = want = np.full(num_v, 1.0 / num_v, np.float32)
     for _ in range(3):
         ranks, received, overflowed = step(edges, ranks, out_deg)
         assert not np.asarray(overflowed).any()
-        assert np.asarray(received)[:, 0].sum() == (edges[:, 0] >= 0).sum()
+        received = np.asarray(received)
+        assert received[:, 0].sum() == (edges[:, 0] >= 0).sum()
+        # the fill makes whole wire rows of each pair's records
+        assert (received[:, 1] >= received[:, 0]).all()
+        assert (received[:, 1] % 64 == 0).all()
         want = _two_gather_superstep(edges, want, out_deg, cfg.damping)
     ranks, want = np.asarray(ranks), np.asarray(want)
     assert len(np.unique(ranks)) > num_v // 4     # no trivial fixed point

@@ -406,3 +406,48 @@ def test_pagerank_contrib_compiles_to_one_gather_of_the_table(v5e_host):
     assert "/pagerank.contrib/" in divide
     assert not any(" divide(" in line and f" f32[{num_e}]" in line
                    for line in lines)
+
+
+@pytest.mark.parametrize("row_words,wire_words", [
+    (2, 128), (3, 128), (4, 128), (7, 128), (8, 8), (25, 25)])
+def test_narrow_rows_cross_a_tpu_mesh_as_wire_rows(v5e_host, row_words,
+                                                   wire_words):
+    """On four described chips ``shuffle_records_shard`` hands the ragged
+    all-to-all rows of 128 lanes where the records are under 8 words, and
+    the rows as they are from 8 words on: the rule reads the row's width,
+    no option. (Lowered only: the form is chosen while tracing.)"""
+    import re
+
+    from sparkrdma_tpu.parallel import exchange
+
+    mesh = Mesh(np.array(v5e_host), (AXIS,))
+    sh = NamedSharding(mesh, P(AXIS))
+    per_chip = 1000
+    move = exchange.row_mover(mesh)
+
+    @jax.jit
+    @functools.partial(shard_map, mesh=mesh, in_specs=(P(AXIS), P(AXIS)),
+                       out_specs=(P(AXIS), P(AXIS)))
+    def shuffle(rows, dest):
+        fill = jnp.zeros((4, row_words), jnp.uint32)
+        records, counts, _, _ = exchange.shuffle_records_shard(
+            rows, dest, fill, AXIS, 4, 2, "native", move)
+        return records, counts
+
+    lowered = shuffle.lower(
+        jax.ShapeDtypeStruct((4 * per_chip, row_words), jnp.uint32,
+                             sharding=sh),
+        jax.ShapeDtypeStruct((4 * per_chip,), jnp.int32, sharding=sh))
+    text = lowered.as_text()
+    sent, = re.findall(
+        r"ragged_all_to_all.*?\(tensor<(\d+)x(\d+)xui32>, tensor<(\d+)x",
+        text)
+    capacity = exchange.record_capacity(per_chip, row_words, 4, 2)
+    if wire_words == 128:
+        rows_out = exchange.wire_rows(per_chip, row_words, 4)
+        assert sent == (str(rows_out), "128", str(2 * rows_out))
+        assert capacity == 2 * rows_out * (128 // row_words)
+    else:
+        assert sent == (str(per_chip), str(row_words), str(2 * per_chip))
+        assert capacity == 2 * per_chip
+    assert lowered.out_info[0].shape == (4 * capacity, row_words)
