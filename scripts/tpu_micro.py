@@ -1,9 +1,9 @@
 """Micro-benchmarks of single kernels on hardware: the TeraSort local sort,
-the row move, PageRank's per-edge gather.
+the row move, PageRank's per-edge gather, the grouping of narrow rows.
 
-Three modes, all for a TPU: they time the device. ``rowmove`` and
-``gather`` exit non-zero anywhere else; ``sort`` runs anywhere, and off
-the chip its numbers mean nothing.
+Four modes, all for a TPU: they time the device. ``rowmove``, ``gather``
+and ``groupsort`` exit non-zero anywhere else; ``sort`` runs anywhere, and
+off the chip its numbers mean nothing.
 
 ``python scripts/tpu_micro.py [sort] [n_rows]``
     the two phases of the local sort apart across row widths: the
@@ -23,6 +23,15 @@ the chip its numbers mean nothing.
     divide, and the table built in the program as the superstep builds
     it; random indices against sorted ones, ns an index. Output as
     ``rowmove``'s (default ``chiprun_out/gather.json``).
+
+``python scripts/tpu_micro.py groupsort [out.json]``
+    ``exchange.group_by_destination``'s two carriers at 10,737,418 rows of
+    2 to 8 words (``PERF.md`` section 6, PR 34): one stable sort with the
+    row's words as value operands and the counts read off the sorted
+    destinations, against the stable argsort, ``jnp.take`` and
+    ``jnp.bincount`` it replaced under 8 words, ns a row. The argsort and
+    the bincount do not depend on the width and are timed once. Output as
+    ``rowmove``'s (default ``chiprun_out/groupsort.json``).
 """
 
 import json
@@ -40,6 +49,7 @@ import jax.numpy as jnp
 ROWMOVE_N = (1 << 17, 1 << 18, 1 << 20, 1 << 22, 10_737_418)
 ROWMOVE_W = (2, 8, 16, 25, 32)
 GATHER_INDICES, GATHER_TABLE = 16_777_280, 468_750
+GROUPSORT_N, GROUPSORT_W, GROUPSORT_PARTS = 10_737_418, range(2, 9), 4
 
 
 def timeit(fn, *args, reps=5):
@@ -213,6 +223,60 @@ def gather_main(out_path):
     _write_table(table, out_path)
 
 
+def groupsort_points(n, widths, parts, seed=0):
+    """ns a row of each part of the grouping. ``sort`` is the whole of the
+    narrow form (sort, stack, binary searches: ``group_by_destination``'s
+    own lines, repeated here because the sweep runs them past the rule's
+    edge too); ``argsort`` + ``take`` + ``bincount`` the whole of the
+    other, a program each."""
+    from jax import lax
+
+    k_dest, k_rows = jax.random.split(jax.random.key(seed))
+    dest = jax.random.randint(k_dest, (n,), 0, parts + 1, jnp.int32)
+    order = jnp.argsort(dest, stable=True)
+
+    def sort_form(rows, dest):
+        sorted_dest, *columns = lax.sort(
+            (dest, *(rows[:, k] for k in range(rows.shape[1]))),
+            num_keys=1, is_stable=True)
+        bounds = jnp.searchsorted(
+            sorted_dest, jnp.arange(parts + 1, dtype=jnp.int32), side="left")
+        return jnp.stack(columns, axis=1), jnp.diff(bounds)
+
+    shared = {
+        "argsort": time_queued(lambda d: jnp.argsort(d, stable=True), dest),
+        "bincount": time_queued(
+            lambda d: jnp.bincount(d, length=parts + 1)[:parts], dest),
+    }
+    for w in widths:
+        rows = jax.random.bits(jax.random.fold_in(k_rows, w), (n, w),
+                               jnp.uint32)
+        got, counts = jax.jit(sort_form)(rows, dest)
+        equal = bool(jnp.array_equal(got, jnp.take(rows, order, axis=0))
+                     and jnp.array_equal(
+                         counts, jnp.bincount(dest, length=parts + 1)[:parts]))
+        del got
+        seconds = dict(shared, sort=time_queued(sort_form, rows, dest),
+                       take=time_queued(lambda r, o: jnp.take(r, o, axis=0),
+                                        rows, order))
+        point = {"n_rows": n, "row_words": w, "partitions": parts,
+                 "equal": equal}
+        point.update({f"{name}_ns_row": sec / n * 1e9
+                      for name, sec in seconds.items()})
+        point["argsort_take_ns_row"] = (point["argsort_ns_row"]
+                                        + point["take_ns_row"])
+        yield point
+
+
+def groupsort_main(out_path):
+    table = _tpu_table("groupsort", "tests/test_packed_exchange.py holds "
+                       "the two forms equal on the CPU")
+    for point in groupsort_points(GROUPSORT_N, GROUPSORT_W, GROUPSORT_PARTS):
+        table["points"].append(point)
+        print(json.dumps(point), flush=True)
+    _write_table(table, out_path)
+
+
 def main():
     args = sys.argv[1:]
     if args and args[0] == "rowmove":
@@ -220,6 +284,10 @@ def main():
         return
     if args and args[0] == "gather":
         gather_main(args[1] if len(args) > 1 else "chiprun_out/gather.json")
+        return
+    if args and args[0] == "groupsort":
+        groupsort_main(args[1] if len(args) > 1
+                       else "chiprun_out/groupsort.json")
         return
     if args and args[0] == "sort":
         args = args[1:]

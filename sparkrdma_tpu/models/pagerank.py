@@ -81,7 +81,7 @@ def make_pagerank_step(mesh: Mesh, axis_name: str, cfg: PageRankConfig,
     A device profile names the step's three phases by scope:
     ``pagerank.contrib`` (one per-edge gather of ``rank / out_degree``
     and the per-vertex divide that makes that table),
-    ``pagerank.exchange`` (grouping, with its ``row_gather``, and the
+    ``pagerank.exchange`` (grouping, with its ``row_sort``, and the
     transport) and ``pagerank.accumulate`` (masking, scatter-add,
     damping). ``step.row_moves`` lists the form the grouping's row move
     took (``ops.row_permute``), once the step has been traced.

@@ -409,7 +409,7 @@ def make_q95_step(mesh: Mesh, axis_name: str, cfg: Q95Config,
     ``q95.exchange`` — three row sets to ``hash(order) % devices``, the
     hash over both words of the bigint, each through
     ``exchange.shuffle_records_shard`` (``group_by_destination`` with its
-    ``row_gather``, ``ragged_exchange_shard``; packed):
+    ``row_sort``, ``ragged_exchange_shard``; packed):
     pairs ``(order, warehouse)`` of EVERY ``web_sales`` row, 3 words;
     ``(order)`` of every ``web_returns`` row, 2 words; the survivors'
     ``(order, cost, profit)``, 4 words. The fill record is the dead row,

@@ -3,8 +3,8 @@
 ``permute_rows(rows, order)`` is ``jnp.take(rows, order, axis=0)`` — the
 row move after a ``(key, iota)`` sort (``parallel.device_plane.
 _local_sort``) and after the argsort by destination (``parallel.exchange.
-group_by_destination``): the one place in the tree where rows follow an
-order.
+group_by_destination``, whose narrow rows ride their sort and follow no
+order): the one place in the tree where rows follow an order.
 
 Why a second data path. On the chip XLA keeps ``u32[N, W]`` with narrow
 ``W`` column-major (``{0,1:T(8,128)}``, ``W`` padded to a multiple of 8
@@ -116,9 +116,9 @@ def row_move_form(n_rows: int, row_words: int, platform: str | None) -> str:
 
 
 def forms_label(chosen) -> str:
-    """One word for the forms a step's row moves took (``permute_rows``'
-    ``chosen``): ``"packed"``, ``"take"``, ``"packed+take"`` where they
-    differ, ``"none"`` where no rows followed an order."""
+    """One word for the forms a step's row moves took (``RowMover``'s
+    ``chosen``): ``"packed"``, ``"take"``, ``"sort"``, ``"packed+take"``
+    where they differ, ``"none"`` where no rows followed an order."""
     return "+".join(sorted(set(chosen))) or "none"
 
 
@@ -367,3 +367,28 @@ def permute_rows(rows, order, platform: str | None = None,
         moved = permute_packed(packed, order, slots, interpret)
     with jax.named_scope("unpack"):
         return unpack_rows(moved, n, words, interpret)
+
+
+class RowMover(functools.partial):
+    """``move(rows, order)``: ``permute_rows`` for one step, bound to the
+    platform the step compiles for and to ``chosen``, the list that gains
+    the form of each of the step's row moves while it is traced
+    (``parallel.exchange.row_mover`` makes one for a mesh). ``note`` adds
+    the form of a move that followed no order vector:
+    ``group_by_destination``'s ``"sort"``. Unbound (``RowMover()``) it is
+    ``jnp.take`` and keeps no list.
+
+    A ``functools.partial`` and not a class with a ``__call__`` of its own,
+    so that a call adds no Python frame: a Mosaic kernel's lowered text
+    holds its call stack, and the fused steps' programs stay byte for byte
+    what they were (for the same reason nothing above this line moves)."""
+
+    def __new__(cls, platform: str | None = None,
+                chosen: list | None = None):
+        return super().__new__(cls, permute_rows, platform=platform,
+                               chosen=chosen)
+
+    def note(self, form: str) -> None:
+        chosen = self.keywords["chosen"]
+        if chosen is not None:
+            chosen.append(form)

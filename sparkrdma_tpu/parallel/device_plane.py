@@ -333,7 +333,7 @@ def make_fused_step(mesh, axis_name: str, row_words: int, *,
     degrade the stage to the host dataplane).
 
     ``step.row_moves`` lists the form each of the step's row moves took
-    (``ops.row_permute``: ``"packed"`` / ``"take"``), in program order. It
+    (``ops.row_permute``: ``"packed"`` / ``"take"`` / ``"sort"``), in order. It
     is filled while the step is traced (its first call or ``lower``).
     """
     import jax
@@ -365,7 +365,7 @@ def make_fused_step(mesh, axis_name: str, row_words: int, *,
     n = mesh.shape[axis_name]
     impl = resolve_transport(mesh, impl, axis_name)
     # how the step's rows follow an order, and the form each such move took
-    # ("packed" / "take"), filled while the step is traced: step.row_moves
+    # ("packed" / "take" / "sort"), filled while it is traced: step.row_moves
     row_moves: list = []
     move = row_mover(mesh, row_moves)
     spec = P(axis_name)

@@ -24,15 +24,19 @@ Everything is static-shape: ``data`` and ``output`` are fixed-capacity
 buffers; raggedness lives in the offset/size vectors, which is what keeps
 XLA happy (no dynamic shapes under jit).
 
-Narrow rows. The TPU's ragged all-to-all moves every row as 128 lanes, so
-rows under ``ops.row_permute.MIN_PACKED_WORDS`` words travel
-``wire_records`` to a wire row (``wire_form``, ``pack_exchange_shard``,
-``shuffle_records_shard``): the one packer in the tree, chosen at trace
-time from the row's width alone, on every platform, so the CPU tests run
-the program the chip runs. PageRank's 2-word records and q95's 3-, 2- and
-4-word rows ride it; ``models/join.py``, ``models/tpcds.py`` and q64 keep
-the unpacked ``shuffle_shard`` by name (toy sizes, no fill record of
-their own).
+Narrow rows. Rows under ``ops.row_permute.MIN_PACKED_WORDS`` words are
+narrow, and the one constant decides two things at trace time, from the
+row's width alone, on every platform, so the CPU tests run the program the
+chip runs. They are grouped by riding the sort that orders their
+destinations, as its value operands (``grouping_form``,
+``group_by_destination``: no order vector, no gather, the counts read off
+the sorted destinations). And since the TPU's ragged all-to-all moves
+every row as 128 lanes, they travel ``wire_records`` to a wire row
+(``wire_form``, ``pack_exchange_shard``, ``shuffle_records_shard``): the
+one packer in the tree. PageRank's 2-word records and q95's 3-, 2- and
+4-word rows take both; ``models/join.py``, ``models/tpcds.py`` and q64
+keep the unpacked ``shuffle_shard`` by name (toy sizes, no fill record of
+their own), and their narrow rows ride the sort too.
 
 Transports (``impl``). ``"auto"``, what every caller passes by default,
 resolves per mesh (``resolve_impl``): ``native`` on a TPU mesh, ``dense``
@@ -69,7 +73,7 @@ import numpy as np
 from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from sparkrdma_tpu.ops.row_permute import MIN_PACKED_WORDS, permute_rows
+from sparkrdma_tpu.ops.row_permute import MIN_PACKED_WORDS, RowMover
 
 # Host-side dispatch tally for the ICI data plane. Callers that launch a
 # collective exchange (mesh_service, models) record here so tests and the
@@ -315,8 +319,32 @@ def _gather_exchange(data: jnp.ndarray, mat: jnp.ndarray, my: jnp.ndarray,
     return jnp.where(mask, packed, output)
 
 
+# The grouping's edge is ``MIN_PACKED_WORDS`` because the sort that carries
+# the rows wins at every width under it. ``scripts/tpu_micro.py groupsort``,
+# 10,737,418 rows to 4 destinations and padding, ns a row (my chip run,
+# PR 34; PERF.md section 6): the whole narrow form (one stable sort of
+# dest + W operands, the binary searches) | stable argsort 2.50 +
+# ``jnp.take``, and ``jnp.bincount`` (8.77 at any width) on top of that:
+#   W   2     3      4      5      6      7      8
+#   4.36  4.86   6.31   6.97   8.77   9.19   10.83
+#   8.62  17.84  17.84  19.74  19.76  21.19  21.19
+# about 2.3 + 1.05 W against 2.5 + ~15-19 + 8.8. At 8 words the rows
+# follow their order packed (2.50 + 5.90: ``ops/row_permute.py``), under
+# the sort's 10.83 by less than the bincount that path still pays; no cell
+# runs rows that wide through ``group_by_destination``, so it stays as it is.
+def grouping_form(row_words: int) -> str:
+    """``"sort"`` or ``"order"``: how ``group_by_destination`` brings rows
+    of ``row_words`` 32-bit words into destination order. The edge is
+    ``ops.row_permute.row_move_form``'s and ``wire_form``'s, one constant:
+    a row under ``MIN_PACKED_WORDS`` words is narrow, and narrow rows ride
+    the sort that orders the destinations, as its value operands; wider
+    rows follow an order vector (``ops.row_permute.permute_rows``). Pure;
+    decided at trace time, on any platform."""
+    return "sort" if row_words < MIN_PACKED_WORDS else "order"
+
+
 def group_by_destination(data: jnp.ndarray, dest: jnp.ndarray,
-                         num_partitions: int, move=permute_rows,
+                         num_partitions: int, move: RowMover = RowMover(),
                          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Stable local grouping of rows by destination partition.
 
@@ -326,15 +354,34 @@ def group_by_destination(data: jnp.ndarray, dest: jnp.ndarray,
     ``dest >= num_partitions`` or ``dest < 0`` are treated as padding: they
     sort to the end and don't count.
 
-    The rows follow the order through ``move(rows, order)``:
-    ``ops.row_permute.permute_rows``, which a caller that knows its mesh
-    binds to the mesh's platform (``row_mover``); unbound it is
-    ``jnp.take``.
+    One algorithm, order by destination, and two carriers for the rows,
+    chosen by ``grouping_form`` from the row's width alone. Narrow rows
+    (``u32[N, W]`` or another 4-byte type, ``W < MIN_PACKED_WORDS``) are
+    the value operands of ONE stable ``lax.sort`` keyed on the destination
+    (no order vector, no gather), and the counts are ``num_partitions + 1``
+    binary searches on the sorted destinations; ``move.note`` records the
+    form, ``"sort"``. Any other rows follow a stable argsort through
+    ``move(rows, order)``: ``ops.row_permute.permute_rows``, which a caller
+    that knows its mesh binds to the mesh's platform (``row_mover``);
+    unbound it is ``jnp.take``. Both give the same rows and counts,
+    element for element.
 
     Returns ``(grouped_rows, counts)`` with ``counts: i32[num_partitions]``.
     """
     dest = jnp.where((dest < 0) | (dest >= num_partitions),
                      num_partitions, dest.astype(jnp.int32))
+    if (data.ndim == 2 and data.dtype.itemsize == 4
+            and grouping_form(data.shape[1]) == "sort"):
+        move.note("sort")
+        with jax.named_scope("row_sort"):  # a device profile's kernel name
+            sorted_dest, *columns = lax.sort(
+                (dest, *(data[:, k] for k in range(data.shape[1]))),
+                num_keys=1, is_stable=True)
+        bounds = jnp.searchsorted(
+            sorted_dest, jnp.arange(num_partitions + 1, dtype=jnp.int32),
+            side="left")
+        return (jnp.stack(columns, axis=1),
+                jnp.diff(bounds).astype(jnp.int32))
     order = jnp.argsort(dest, stable=True)
     with jax.named_scope("row_gather"):   # a device profile's kernel name
         grouped = move(data, order)
@@ -345,7 +392,7 @@ def group_by_destination(data: jnp.ndarray, dest: jnp.ndarray,
 def shuffle_shard(data: jnp.ndarray, dest: jnp.ndarray, axis_name: str,
                   num_devices: int,
                   output: Optional[jnp.ndarray] = None,
-                  impl: str = "native", move=permute_rows):
+                  impl: str = "native", move: RowMover = RowMover()):
     """Full per-shard shuffle step: group locally by destination device
     (``move``: see ``group_by_destination``), then ragged-exchange.
     Returns (received, recv_counts, recv_offsets, overflowed) — see
@@ -399,7 +446,7 @@ def record_capacity(n_rows: int, row_words: int, num_devices: int,
 def pack_exchange_shard(rows: jnp.ndarray, dest: jnp.ndarray,
                         fill: jnp.ndarray, axis_name: str, num_devices: int,
                         out_factor: int = 1, impl: str = "native",
-                        move=permute_rows):
+                        move: RowMover = RowMover()):
     """The shuffle of narrow rows, ``wire_records`` records to a wire row.
     Call inside ``shard_map``; plain ``jax.numpy``, any platform, any
     transport. ``shuffle_records_shard`` picks it by ``wire_form``.
@@ -469,7 +516,7 @@ def pack_exchange_shard(rows: jnp.ndarray, dest: jnp.ndarray,
 def shuffle_records_shard(rows: jnp.ndarray, dest: jnp.ndarray,
                           fill: jnp.ndarray, axis_name: str,
                           num_devices: int, out_factor: int = 1,
-                          impl: str = "native", move=permute_rows):
+                          impl: str = "native", move: RowMover = RowMover()):
     """``shuffle_shard`` for records that may be narrow: group by
     destination device, exchange, and hand back records. The form is
     ``wire_form(W)``'s: ``pack_exchange_shard`` for rows under
@@ -539,12 +586,11 @@ def mesh_platform(mesh: Mesh) -> str:
     return next(iter(mesh.devices.flat)).platform
 
 
-def row_mover(mesh: Mesh, chosen: Optional[list] = None):
-    """``ops.row_permute.permute_rows`` for a step compiled for ``mesh``:
+def row_mover(mesh: Mesh, chosen: Optional[list] = None) -> RowMover:
+    """``ops.row_permute.RowMover`` for a step compiled for ``mesh``:
     ``move(rows, order)``. ``chosen`` gains the form of each move traced
-    through it."""
-    return functools.partial(permute_rows, platform=mesh_platform(mesh),
-                             chosen=chosen)
+    through it (``"packed"`` / ``"take"``) or noted on it (``"sort"``)."""
+    return RowMover(mesh_platform(mesh), chosen)
 
 
 def resolve_impl(mesh: Mesh, impl: str = "auto",

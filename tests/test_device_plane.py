@@ -343,9 +343,10 @@ def test_round_overlap_traces(mesh):
     assert len(spans) == rounds
     assert len(overlaps) == rounds - 1, \
         "rounds did not overlap (no double buffering)"
-    # every round says which form its step's rows followed their order in
-    # (what the step's trace chose: a CPU mesh, so jnp.take both times)
-    assert {e["args"]["row_move"] for e in spans} == {"take"}
+    # every round says which forms its step's rows followed their order in
+    # (what the step's trace chose: the 3-word rows rode the grouping's
+    # sort, and on a CPU mesh the receive sort's rows followed jnp.take)
+    assert {e["args"]["row_move"] for e in spans} == {"sort+take"}
     # sequential mode: same bytes, zero overlap instants
     res_s, _, spans_s, overlaps_s = run(False)
     assert len(spans_s) == rounds and not overlaps_s

@@ -1,8 +1,10 @@
 """The exchange's way with narrow rows (``parallel/exchange.py``): the
-rule that picks it, the packer against the unpacked shuffle on one and on
-four virtual devices over the ``gather`` transport, and the rows the rule
-leaves alone. The packer is plain ``jax.numpy`` and the rule reads the
-row's width alone, so the CPU runs the form the chip runs."""
+rule that picks it, the grouping whose rows ride the sort against the
+argsort + take + bincount formulation, the packer against the unpacked
+shuffle on one and on four virtual devices over the ``gather`` transport,
+and the rows the rule leaves alone. Grouping and packer are plain
+``jax.numpy`` and the rule reads the row's width alone, so the CPU runs
+the form the chip runs."""
 
 import functools
 
@@ -13,7 +15,7 @@ import pytest
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from sparkrdma_tpu.ops.row_permute import MIN_PACKED_WORDS
+from sparkrdma_tpu.ops.row_permute import MIN_PACKED_WORDS, RowMover
 from sparkrdma_tpu.parallel import exchange
 
 AXIS = "shuffle"
@@ -76,6 +78,13 @@ def test_the_rule_draws_the_row_moves_edge():
     assert exchange.wire_form(MIN_PACKED_WORDS - 1) == "packed"
     assert exchange.wire_form(MIN_PACKED_WORDS) == "rows"
     assert exchange.wire_form(25) == "rows"
+    # the grouping's carrier turns on the same edge: narrow rows ride the
+    # sort, the others follow an order vector
+    assert [exchange.grouping_form(w) for w in (1, 2, 3, 4, 7)] == [
+        "sort"] * 5
+    assert exchange.grouping_form(MIN_PACKED_WORDS - 1) == "sort"
+    assert exchange.grouping_form(MIN_PACKED_WORDS) == "order"
+    assert exchange.grouping_form(25) == "order"
     assert [exchange.wire_records(w) for w in (1, 2, 3, 4, 7)] == [
         128, 64, 42, 32, 18]
     # whole wire rows, and one more a destination
@@ -84,6 +93,72 @@ def test_the_rule_draws_the_row_moves_edge():
     assert exchange.record_capacity(1000, 3, 4, 2) == 2 * 28 * 42
     assert exchange.record_capacity(1000, 8, 4, 2) == 2000
     assert exchange.record_capacity(1000, 25, 4, 2) == 2000
+
+
+def _group_plain(data, dest, num_partitions):
+    """``group_by_destination`` as it was at every width: a stable argsort,
+    ``jnp.take`` and ``jnp.bincount``. The plain reference."""
+    dest = jnp.where((dest < 0) | (dest >= num_partitions), num_partitions,
+                     dest.astype(jnp.int32))
+    grouped = jnp.take(data, jnp.argsort(dest, stable=True), axis=0)
+    counts = jnp.bincount(dest, length=num_partitions + 1)[:num_partitions]
+    return grouped, counts.astype(jnp.int32)
+
+
+def _group_traffic(shape, num_partitions, seed, dtype=np.uint32):
+    """Rows whose destinations repeat (613 rows over at most 5
+    destinations), with padding of both kinds (``dest`` < 0 and >= P) and,
+    past one partition, a destination nobody sends to."""
+    rng = np.random.default_rng(seed)
+    data = rng.integers(0, 2**16, shape).astype(dtype)
+    dest = rng.integers(0, num_partitions, shape[0]).astype(np.int32)
+    if num_partitions > 1:
+        dest[dest == num_partitions - 2] = 0        # an empty destination
+    dest[rng.random(shape[0]) < 0.05] = -1
+    dest[rng.random(shape[0]) < 0.03] = -7
+    dest[rng.random(shape[0]) < 0.05] = num_partitions
+    dest[rng.random(shape[0]) < 0.03] = num_partitions + 9
+    return data, dest
+
+
+@pytest.mark.parametrize("words", [1, 2, 3, 4, 5, 6, 7, 8, 25])
+@pytest.mark.parametrize("num_partitions", [1, 5])
+def test_grouping_equals_argsort_take_bincount(num_partitions, words):
+    """Rows and counts element for element, padding rows included: the
+    sort that carries narrow rows is stable, so every sender's records
+    keep their order, as under the argsort."""
+    data, dest = _group_traffic((613, words), num_partitions, seed=words)
+    chosen = []
+    grouped, counts = jax.jit(
+        lambda d, t: exchange.group_by_destination(
+            d, t, num_partitions, RowMover(None, chosen)))(data, dest)
+    want_rows, want_counts = _group_plain(data, dest, num_partitions)
+    np.testing.assert_array_equal(grouped, want_rows)
+    np.testing.assert_array_equal(counts, want_counts)
+    assert counts.dtype == jnp.int32 and grouped.dtype == jnp.uint32
+    live = (dest >= 0) & (dest < num_partitions)
+    assert counts.sum() == live.sum() and 0 < live.sum() < len(dest)
+    if num_partitions > 1:
+        assert counts[num_partitions - 2] == 0
+    assert chosen == ["sort" if words < MIN_PACKED_WORDS else "take"]
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((613,), np.uint32),            # no rows of words at all
+    ((613, 2), np.uint16),          # narrow, but not 4-byte words
+    ((613, 2, 2), np.uint32),       # rows that are no vector of words
+    ((613, 3), np.float32),         # 4-byte words of another type: sorted
+])
+def test_grouping_of_rows_that_are_no_narrow_word_rows(shape, dtype):
+    data, dest = _group_traffic(shape, 3, seed=11, dtype=dtype)
+    chosen = []
+    grouped, counts = exchange.group_by_destination(
+        data, dest, 3, RowMover(None, chosen))
+    want_rows, want_counts = _group_plain(data, dest, 3)
+    np.testing.assert_array_equal(grouped, want_rows)
+    np.testing.assert_array_equal(counts, want_counts)
+    assert grouped.dtype == dtype
+    assert chosen == ["sort" if dtype == np.float32 else "take"]
 
 
 @pytest.mark.parametrize("words", [1, 2, 3, 4, 7])

@@ -170,7 +170,7 @@ def test_job_spans_and_counters(tmp_path):
                            "vertices": num_v,
                            "received": [len(edges) - 10] * 3,
                            # 8-byte rows: jnp.take on any platform
-                           "row_move": "take"}
+                           "row_move": "sort"}
     for inner in ("pagerank.dispatch", "pagerank.wait"):
         assert job["ts"] <= spans[inner]["ts"]
         assert (spans[inner]["ts"] + spans[inner]["dur"]
@@ -194,17 +194,19 @@ def test_the_three_scopes_name_the_steps_ops():
     text = step.lower(edges, ranks, out_deg).compile().as_text()
     names = set(re.findall(r'op_name="([^"]*)"', text))
     for scope in ("pagerank.contrib", "pagerank.exchange",
-                  "pagerank.accumulate", "pagerank.exchange/row_gather"):
+                  "pagerank.accumulate", "pagerank.exchange/row_sort"):
         assert any(f"/{scope}/" in n for n in names), scope
     # the kernels the scopes are for lie under them, and nowhere else
     for scope, kernel in (("pagerank.accumulate", "scatter-add"),
                           ("pagerank.contrib", "gather"),
-                          ("pagerank.exchange/row_gather", "gather"),
-                          ("pagerank.exchange", "sort")):
+                          ("pagerank.exchange/row_sort", "sort")):
         assert any(f"/{scope}/" in n and n.endswith(kernel)
                    for n in names), (scope, kernel)
-    assert not any(n.endswith("scatter-add") and "pagerank.exchange" not in n
+    # the grouping's rows ride its sort: no bincount's scatter-add and no
+    # row gather under the exchange
+    assert not any(n.endswith("scatter-add")
                    and "pagerank.accumulate" not in n for n in names)
+    assert not any("row_gather" in n for n in names)
 
 
 # -- the contribution phase: one gather of the per-vertex table ---------------

@@ -317,7 +317,7 @@ def test_q95_job_spans_and_counters(tmp_path):
                            "received": [6144, 608, survivors],
                            "survivors": survivors,
                            # 2- to 4-word rows: jnp.take on any platform
-                           "row_move": "take"}
+                           "row_move": "sort"}
     assert survivors > 20
     for inner in ("q95.dispatch", "q95.wait"):
         assert job["ts"] <= spans[inner]["ts"]
@@ -340,15 +340,16 @@ def test_q95_scopes_name_the_steps_ops():
                       resident.dimensions).compile().as_text()
     names = set(re.findall(r'op_name="([^"]*)"', text))
     for scope in ("q95.filter", "q95.exchange", "q95.join",
-                  "q95.exchange/row_gather"):
+                  "q95.exchange/row_sort"):
         assert any(f"/{scope}/" in n for n in names), scope
+    assert not any("row_gather" in n for n in names)
     for scope, kernel in (("q95.filter", "gather"),
-                          ("q95.exchange/row_gather", "gather"),
-                          ("q95.exchange", "sort"), ("q95.join", "sort"),
+                          ("q95.exchange/row_sort", "sort"),
+                          ("q95.join", "sort"),
                           ("q95.join", "scatter")):
         assert any(f"/{scope}/" in n and n.endswith(kernel)
                    for n in names), (scope, kernel)
-    assert step.row_moves == ["take"] * 3
+    assert step.row_moves == ["sort"] * 3
 
 
 def test_q95_and_pagerank_ride_one_packer(monkeypatch):

@@ -345,9 +345,9 @@ def test_permute_rows_compiles_at_every_width_class(v5e_host, row_words,
     assert compiled.as_text().count(MOSAIC_CALL) == mosaic_calls
 
 
-def test_pagerank_step_holds_no_mosaic_call(v5e_host):
-    """``group_by_destination`` on 8-byte rows stays with ``jnp.take`` on
-    a TPU mesh too."""
+def test_narrow_rows_ride_the_sort_on_a_tpu_mesh(v5e_host):
+    """``group_by_destination`` on 8-byte rows: on a TPU mesh too the rows
+    are operands of the one stable sort, no Mosaic call, no gather."""
     from sparkrdma_tpu.parallel.exchange import (
         group_by_destination,
         row_mover,
@@ -366,8 +366,103 @@ def test_pagerank_step_holds_no_mosaic_call(v5e_host):
     move = row_mover(mesh, chosen)
     rows = jax.ShapeDtypeStruct((16_777_280, 2), jnp.uint32, sharding=sh)
     dest = jax.ShapeDtypeStruct((16_777_280,), jnp.int32, sharding=sh)
-    assert "tpu_custom_call" not in grouped.lower(rows, dest).as_text()
-    assert chosen == ["take"]
+    text = grouped.lower(rows, dest).as_text()
+    assert "tpu_custom_call" not in text
+    assert '"stablehlo.gather"(' not in text
+    assert text.count('"stablehlo.sort"(') == 1
+    assert chosen == ["sort"]
+
+
+def _pagerank_step_args(devices, num_e=1 << 16, num_v=1000):
+    """``make_pagerank_step`` for described chips, ``num_e`` edges and
+    ``num_v`` vertices a chip, and the shapes it takes."""
+    from sparkrdma_tpu.models.pagerank import (
+        PageRankConfig,
+        make_pagerank_step,
+    )
+
+    n = len(devices)
+    mesh = Mesh(np.array(devices), (AXIS,))
+    sh = NamedSharding(mesh, P(AXIS))
+    step = make_pagerank_step(
+        mesh, AXIS, PageRankConfig(num_vertices=n * num_v,
+                                   edges_per_device=num_e))
+    return step, (
+        jax.ShapeDtypeStruct((n * num_e, 2), jnp.int32, sharding=sh),
+        jax.ShapeDtypeStruct((n * num_v,), jnp.float32, sharding=sh),
+        jax.ShapeDtypeStruct((n * num_v,), jnp.float32, sharding=sh))
+
+
+def _q95_step_args(devices):
+    from sparkrdma_tpu.models.tpcds_queries import Q95Config, make_q95_step
+
+    n, ws, wr = len(devices), 6144, 614
+    mesh = Mesh(np.array(devices), (AXIS,))
+    sh, whole = NamedSharding(mesh, P(AXIS)), NamedSharding(mesh, P())
+    cfg = Q95Config(ws_rows_per_device=ws, wr_rows_per_device=wr,
+                    num_orders=n * 512, survivor_capacity=512)
+    u32, i32 = jnp.uint32, jnp.int32
+    return make_q95_step(mesh, AXIS, cfg), (
+        tuple(jax.ShapeDtypeStruct((n * ws,), d, sharding=sh)
+              for d in (u32, u32) + (i32,) * 6),
+        tuple(jax.ShapeDtypeStruct((n * wr,), u32, sharding=sh)
+              for _ in range(2)),
+        tuple(jax.ShapeDtypeStruct((k,), i32, sharding=whole)
+              for k in (cfg.num_dates, cfg.num_addresses, cfg.num_sites)))
+
+
+# sorts: the grouping's one a row set (it was its argsort), and the q95
+# join's two. scatters: PageRank's scatter-add of the contributions, q95's
+# mark of the qualifying orders; a ``bincount`` would be one more a row set
+@pytest.mark.parametrize("chips", [1, 4])
+@pytest.mark.parametrize("make,groupings,sorts,scatters", [
+    (_pagerank_step_args, 1, 1, 1), (_q95_step_args, 3, 5, 1)])
+def test_narrow_row_steps_group_with_one_sort_and_nothing_else(
+        v5e_host, make, groupings, sorts, scatters, chips):
+    """The PageRank and q95 steps as lowered for described chips: every
+    grouping rode its sort (``row_moves``), so the program holds no gather
+    under ``row_gather``, no ``bincount``'s scatter-add and no argsort,
+    and exactly the sorts it held before. (Lowered only: the form is
+    chosen while tracing.)"""
+    step, args = make(v5e_host[:chips])
+    text = step.lower(*args).as_text(debug_info=True)
+    assert step.row_moves == ["sort"] * groupings
+    assert "/row_sort/sort" in text
+    for gone in ("row_gather", "bincount", "argsort", "tpu_custom_call"):
+        assert gone not in text, gone
+    assert text.count('"stablehlo.sort"(') == sorts
+    assert text.count('"stablehlo.scatter"(') == scatters
+
+
+def _without_kernel_bodies(text):
+    """A lowered text less its Mosaic kernels' serialized bodies, which
+    hold the checkout's path and the call stack's line numbers."""
+    import re
+
+    return re.sub(r'\\22body\\22: \\22[^\\]*\\22', "BODY", text)
+
+
+# sha256 of the lowered text (kernel bodies masked) of the three TeraSort
+# cells' steps at their sizes, as PR 33's tree lowers them: these cells
+# bypass ``group_by_destination`` (range mode never calls it; ``dest`` mode
+# on one device sorts by key), so a change to the grouping leaves them
+# byte for byte. A PR that means to change a fused step renews the pins.
+@pytest.mark.parametrize("chips,rows_per_chip,partition,pinned", [
+    (1, 10_737_418, "range",        # fused_1chip
+     "75fa51e8bc039efa0b3bf005eb7025ef61917638b6134d2249e8fa79bab2bce1"),
+    (4, 5_368_709, "range",         # fused_4chip
+     "49777b4d7ecbaba8be349ccb82226b10b1d3a266d67759ec5116bd1c66315575"),
+    (1, 111_848, "dest",            # a round of spi_device_1chip
+     "44aedb15a8e0ff87186b0d7005e8e0eaa4d27c261a5b03a23b3aad017c075a31"),
+])
+def test_bypass_cells_lower_to_the_pinned_programs(
+        v5e_host, chips, rows_per_chip, partition, pinned):
+    import hashlib
+
+    step, args = _fused_step_args(v5e_host[:chips], rows_per_chip, 25,
+                                  partition)
+    text = _without_kernel_bodies(step.lower(*args).as_text())
+    assert hashlib.sha256(text.encode()).hexdigest() == pinned
 
 
 def test_pagerank_contrib_compiles_to_one_gather_of_the_table(v5e_host):
@@ -376,22 +471,9 @@ def test_pagerank_contrib_compiles_to_one_gather_of_the_table(v5e_host):
     it does not pull the divide back into the gather, which would read
     ``ranks`` and ``out_deg`` an edge again. (The cell's degree, 35.8, at a
     sixteenth of its edges: half a minute of compile.)"""
-    from sparkrdma_tpu.models.pagerank import (
-        PageRankConfig,
-        make_pagerank_step,
-    )
-
     num_e, num_v = 1_048_576, 29_297
-    mesh = Mesh(np.array(v5e_host[:1]), (AXIS,))
-    sh = NamedSharding(mesh, P(AXIS))
-    step = make_pagerank_step(
-        mesh, AXIS, PageRankConfig(num_vertices=num_v,
-                                   edges_per_device=num_e))
-    lines = step.lower(
-        jax.ShapeDtypeStruct((num_e, 2), jnp.int32, sharding=sh),
-        jax.ShapeDtypeStruct((num_v,), jnp.float32, sharding=sh),
-        jax.ShapeDtypeStruct((num_v,), jnp.float32, sharding=sh),
-    ).compile().as_text().splitlines()
+    step, args = _pagerank_step_args(v5e_host[:1], num_e, num_v)
+    lines = step.lower(*args).compile().as_text().splitlines()
     gathers = [i for i, line in enumerate(lines)
                if " gather(" in line and "/pagerank.contrib/" in line]
     assert len(gathers) == 1
