@@ -261,34 +261,32 @@ def _secondary_workloads(detail: dict, mesh, n: int) -> None:
 
 
 def _bench_als(detail: dict, mesh, n: int) -> None:
-    """ALS skewed half-step (BASELINE config #5, the skew stress): the
-    zipf-hammered item side routed through the bounded-round chunked
-    exchange, timed as ratings routed per second. Host-driven (grouping
-    and solves live on the host like the rehearsal), so it can't ride
-    ``_bench_secondary``'s jitted-step contract."""
+    """MLlib's blocked ALS (BASELINE config #5) at a small size: one job
+    of two sweeps over resident blocks (``models.als.ALSJob``: factor
+    rows shuffled, a gather a rating, normal equations and solves on the
+    device), timed as ratings visited per second (each twice a sweep).
+    ``benchmark/run.py --workload als_4chip`` is the measurement."""
     try:
-        from sparkrdma_tpu.models.als import (
-            ALSConfig, als_half_step, generate_ratings)
+        import jax
 
-        per_dev = 1 << 16
-        acfg = ALSConfig(num_users=64 * n, num_items=max(16, per_dev // 64),
-                         rank=8, zipf_a=1.3)
-        ratings = generate_ratings(acfg, n, per_dev, seed=0)
-        rng = np.random.default_rng(0)
-        user_factors = (rng.standard_normal((acfg.num_users, acfg.rank))
-                        .astype(np.float32) / np.sqrt(acfg.rank))
-        # quota sized so zipf skew forces multiple bounded rounds (the
-        # point of config #5) without degenerating to per-row rounds
-        quota = max(64, per_dev // 8)
-        als_half_step(mesh, acfg, ratings, user_factors, quota)  # compile
+        from sparkrdma_tpu.models.als import (
+            ALSConfig, ALSJob, block_ratings, netflix_like_ratings,
+            place_als)
+
+        acfg = ALSConfig(num_users=4096 * n, num_items=256 * n)
+        ratings = netflix_like_ratings(acfg, n << 18, seed=0,
+                                       item_top_share=0.01,
+                                       user_top_share=0.001)
+        resident = place_als(mesh, "shuffle", block_ratings(acfg, ratings, n))
+        job = ALSJob(mesh, "shuffle", acfg, iterations=2)
+        jax.block_until_ready(job(resident))  # compile
         reps = 3
         t0 = time.perf_counter()
         for _ in range(reps):
-            _, rounds = als_half_step(mesh, acfg, ratings, user_factors,
-                                      quota)
+            jax.block_until_ready(job(resident))
         dt = (time.perf_counter() - t0) / reps
-        detail["als_ratings_per_s"] = round(len(ratings) / dt, 0)
-        detail["als_rounds"] = rounds
+        detail["als_ratings_per_s"] = round(
+            2 * job.iterations * resident.num_ratings / dt, 0)
     except Exception as e:  # noqa: BLE001
         detail["als_error"] = f"{type(e).__name__}: {e}"[:120]
 

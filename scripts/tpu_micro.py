@@ -1,7 +1,7 @@
 """Micro-benchmarks of single kernels on hardware: the TeraSort local sort,
 the row move, PageRank's per-edge gather, the grouping of narrow rows.
 
-Four modes, all for a TPU: they time the device. ``rowmove``, ``gather``
+Five modes, all for a TPU: they time the device. ``rowmove``, ``gather``
 and ``groupsort`` exit non-zero anywhere else; ``sort`` runs anywhere, and
 off the chip its numbers mean nothing.
 
@@ -15,6 +15,16 @@ off the chip its numbers mean nothing.
     parts (pack, permute, unpack) timed apart, ns a row. One JSON object a
     line on stdout, and the whole table in ``out.json`` (default
     ``chiprun_out/rowmove.json``). No benchmark cell runs it.
+
+``python scripts/tpu_micro.py rowmove mn [out.json]``
+    the row move where the order is not the operand's length (``PERF.md``
+    section 6, PR 35): ALS's per-rating gather, M = 25,120,127 indices,
+    repeated, into N rows of 10 words (N = 960,376 and 35,540, the two
+    receive buffers at ``out_factor`` 2; 480,189 and 17,770, the rows that
+    can arrive), by ``jnp.take`` in chunks of 2^20 indices as the
+    half-step's scan takes them, ns an index; and the packed form at
+    N = M, which bounds what a packed gather of M out of N would cost
+    (its operand would be smaller). Default ``chiprun_out/rowmove_mn.json``.
 
 ``python scripts/tpu_micro.py gather [out.json]``
     PageRank's contribution phase alone at ``pagerank_1chip``'s shape
@@ -48,6 +58,8 @@ import jax.numpy as jnp
 
 ROWMOVE_N = (1 << 17, 1 << 18, 1 << 20, 1 << 22, 10_737_418)
 ROWMOVE_W = (2, 8, 16, 25, 32)
+ROWMOVE_MN_N, ROWMOVE_MN_M, ROWMOVE_MN_W = (960_376, 480_189, 35_540,
+                                            17_770), 25_120_127, 10
 GATHER_INDICES, GATHER_TABLE = 16_777_280, 468_750
 GROUPSORT_N, GROUPSORT_W, GROUPSORT_PARTS = 10_737_418, range(2, 9), 4
 
@@ -183,6 +195,42 @@ def rowmove_main(out_path):
     _write_table(table, out_path)
 
 
+def rowmove_mn_main(out_path):
+    """``jnp.take`` of M indices out of N rows, M >> N, chunk by chunk."""
+    from sparkrdma_tpu.ops import row_permute as rp
+
+    table = _tpu_table("rowmove mn", "the CPU's gather is another program")
+    w, chunk = ROWMOVE_MN_W, 1 << 20
+    chunks = -(-ROWMOVE_MN_M // chunk)
+
+    def chunked_take(rows, order):
+        # every chunk's rows are summed so that none is dropped as dead;
+        # straight-line code, as the half-step's chunk loop is (as a
+        # ``while`` the v5e read the small tables wrongly, and 7x slower)
+        def body(acc, idx):
+            return acc + jnp.take(rows, idx, axis=0).T.sum(axis=1), None
+        return jax.lax.scan(body, jnp.zeros(w, jnp.uint32), order,
+                            unroll=True)[0]
+
+    for n in ROWMOVE_MN_N:
+        k_rows, k_order = jax.random.split(jax.random.key(n))
+        rows = jax.random.bits(k_rows, (n, w), jnp.uint32)
+        order = jax.random.randint(k_order, (chunks, chunk), 0, n, jnp.int32)
+        point = {"n_rows": n, "n_indices": chunks * chunk, "row_words": w,
+                 "form_by_rule": rp.row_move_form(n, w, "tpu"),
+                 "take_ns_index": time_queued(chunked_take, rows, order,
+                                              reps=2)
+                 / (chunks * chunk) * 1e9}
+        table["points"].append(point)
+        print(json.dumps(point), flush=True)
+    _write_table(table, out_path)
+    point = rowmove_point(ROWMOVE_MN_M, w)
+    point["note"] = "N = M: the packed form's cost a row, operand and all"
+    table["points"].append(point)
+    print(json.dumps(point), flush=True)
+    _write_table(table, out_path)
+
+
 # the contribution phase's three forms: what the superstep computed an edge
 # before PR 32, what it computes now, and the gather alone
 GATHER_FORMS = {
@@ -279,6 +327,10 @@ def groupsort_main(out_path):
 
 def main():
     args = sys.argv[1:]
+    if args[:2] == ["rowmove", "mn"]:
+        rowmove_mn_main(args[2] if len(args) > 2
+                        else "chiprun_out/rowmove_mn.json")
+        return
     if args and args[0] == "rowmove":
         rowmove_main(args[1] if len(args) > 1 else "chiprun_out/rowmove.json")
         return

@@ -329,28 +329,28 @@ def permute_packed(packed, order, slots: int, interpret=False):
 
 def permute_rows(rows, order, platform: str | None = None,
                  chosen: list | None = None):
-    """``jnp.take(rows, order, axis=0)`` for ``order`` with every index in
-    ``[0, N)``: a permutation as ``_local_sort`` and
-    ``group_by_destination`` send it, and repeated indices are honoured
-    too (each output row reads the row its index names). An index outside
-    the range is clipped into it, where ``jnp.take`` would fill.
+    """``jnp.take(rows, order, axis=0)`` for an ``order`` of ANY length
+    with indices in ``[0, N)``: a permutation as ``_local_sort`` and
+    ``group_by_destination`` send it, or M indices into N rows, repeated
+    (ALS reads a factor row once a rating). An index outside the range is
+    clipped into it, where ``jnp.take`` would fill. The packed kernels
+    shape their result as their operand, so only an order of the operand's
+    length takes them; any other goes to ``take``: 25 M indices of 10 words
+    read 4.62 / 2.54 ns each out of 480,189 / 17,770 rows, the packed permute
+    alone 5.19 at M = N (``tpu_micro.py rowmove mn``, PR 35; PERF.md 6).
 
     ``platform`` is the platform the caller compiles for (a mesh's
-    devices', ``parallel.exchange.mesh_platform``). A caller that does not
-    say gets ``jnp.take``: the process's backend is not asked, since a CPU
-    mesh in a TPU-backed process must not be handed a Mosaic call. The
-    packed form runs where ``row_move_form`` says so; off the TPU only a
-    test that forces the form sees it, in Pallas interpret mode (whose
-    unvarying scratch fails ``shard_map``'s ``check_vma``: such a test
-    runs the step with a ring transport, which turns the check off).
-
-    ``chosen``, where given, is a list that gains the form this move took
-    (``"packed"`` / ``"take"``) while the caller's step is traced: the
-    counter that says when the packed form engages
-    (``make_fused_step(...).row_moves``).
+    devices', ``parallel.exchange.mesh_platform``); without one the move
+    is ``jnp.take``: the process's backend is not asked, since a CPU mesh
+    in a TPU-backed process must not be handed a Mosaic call. Off the TPU
+    only a test that forces the form sees the packed one, interpreted
+    (its unvarying scratch fails ``shard_map``'s ``check_vma``: such a
+    test runs the step with a ring transport, which turns the check off).
+    ``chosen``, where given, gains the form this move took (``"packed"``
+    / ``"take"``) while the caller's step is traced (``step.row_moves``).
     """
-    packable = (rows.ndim == 2 and rows.dtype.itemsize == 4
-                and rows.shape[1] <= MAX_PACKED_WORDS)
+    packable = (rows.ndim == 2 and rows.dtype.itemsize == 4 and len(order)
+                == len(rows) and rows.shape[1] <= MAX_PACKED_WORDS)
     form = row_move_form(*rows.shape, platform) if packable else "take"
     if chosen is not None:
         chosen.append(form)

@@ -19,6 +19,9 @@ from __future__ import annotations
 # Duration spans: ``tracer.span(...)`` context managers and the
 # explicit-boundary ``complete_span`` emissions of the async fetcher.
 SPANS = frozenset({
+    "als.dispatch",
+    "als.job",
+    "als.wait",
     "engine.dist_reduce",
     "engine.mesh_reduce",
     "engine.stage",
@@ -101,6 +104,9 @@ INSTANTS = frozenset({
 
 # Chrome "C"-phase counter series.
 COUNTERS = frozenset({
+    "als.max_segment",
+    "als.out_links",
+    "als.recv_fill",
     "ha_failovers",
     "oplog_lag_entries",
     "pagerank.max_in_degree",

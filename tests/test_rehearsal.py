@@ -122,116 +122,3 @@ def test_streamed_terasort_gb_class_rehearsal():
     # test report even on success
     print("\nrehearsal phases:", json.dumps(phases))
     assert phases["rounds"] >= 32
-
-
-_ALS_SCRIPT = r"""
-import json, os, resource, sys, time
-sys.path.insert(0, {repo!r})
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                           + " --xla_force_host_platform_device_count=8")
-import jax
-jax.config.update("jax_platforms", "cpu")
-import numpy as np
-from jax.sharding import Mesh
-from sparkrdma_tpu.models.als import (
-    ALSConfig, als_half_step, generate_ratings, rmse)
-
-D = 8
-size_mb = {size_mb}
-rows_total = (size_mb << 20) // 12          # (item, user, rating) u32 rows
-per_device = rows_total // D
-num_items = max(1 << 14, rows_total // 64)
-num_users = max(D, (rows_total // 10) // D * D)
-cfg = ALSConfig(num_users=num_users, num_items=num_items, rank=8,
-                zipf_a=1.3)
-ratings = generate_ratings(cfg, D, per_device, seed=11)
-data_bytes = ratings.nbytes
-
-# pick the quota so the SKEWED (item) side streams in MANY bounded
-# rounds (rounds = ceil(max pair count / quota)). Small rounds are also
-# what keeps the 8 virtual devices' collective rendezvous tight on a
-# low-core host: participants arrive within the per-round work spread,
-# and XLA:CPU aborts a collective whose participants stagger > 40s.
-pair_max = 0
-for d in range(D):
-    seg = ratings[d * per_device:(d + 1) * per_device]
-    pair_max = max(pair_max, int(np.bincount(
-        (seg[:, 0] % D).astype(np.int64), minlength=D).max()))
-quota = max(1024, -(-pair_max // 400))
-
-mesh = Mesh(np.array(jax.devices()[:D]), ("shuffle",))
-rng = np.random.default_rng(11)
-user_factors = (rng.standard_normal((cfg.num_users, cfg.rank))
-                .astype(np.float32) / np.sqrt(cfg.rank))
-
-# warm/compile both chunked-exchange directions on a small slice BEFORE
-# the cap (XLA compilation transiently maps large address ranges)
-warm_rows = ratings[: D * 4096].copy()
-als_half_step(mesh, cfg, warm_rows, user_factors, quota, key_col=0)
-als_half_step(mesh, cfg, warm_rows, user_factors, quota, key_col=1)
-
-with open("/proc/self/status") as f:
-    vm_kb = next(int(l.split()[1]) for l in f if l.startswith("VmSize"))
-# legitimate peaks: grouped copy (~1x data), device-resident accumulator
-# + host view (~2.5x with skew), per-device received copies (~1x),
-# solve transients + fresh shape compiles (slack)
-headroom = int(5.0 * data_bytes) + (1536 << 20)
-cap = (vm_kb << 10) + headroom
-resource.setrlimit(resource.RLIMIT_AS, (cap, cap))
-try:
-    np.zeros(headroom + (64 << 20), np.uint8)
-    print("CAP-NOT-EFFECTIVE")
-except MemoryError:
-    pass
-
-t0 = time.perf_counter()
-item_factors, rounds_i = als_half_step(mesh, cfg, ratings, user_factors,
-                                       quota, key_col=0)
-user_factors2, rounds_u = als_half_step(mesh, cfg, ratings, item_factors,
-                                        quota, key_col=1)
-wall = time.perf_counter() - t0
-assert rounds_i >= 32, rounds_i
-
-e0 = rmse(ratings, user_factors, np.zeros_like(item_factors), 100_000)
-e1 = rmse(ratings, user_factors2, item_factors, 100_000)
-assert e1 < e0 * 0.6, (e0, e1)
-
-print("ALS=" + json.dumps({{
-    "data_mb": size_mb, "ratings": rows_total,
-    "rounds_item": rounds_i, "rounds_user": rounds_u,
-    "wall_s": round(wall, 2),
-    "ratings_per_s": round(rows_total * 2 / wall, 0),
-    "rmse_init": round(e0, 4), "rmse_after_sweep": round(e1, 4)}}))
-print("ALS-REHEARSAL-OK")
-"""
-
-
-@pytest.mark.slow
-def test_als_zipf_rehearsal_memory_bounded():
-    """Config #5 at environment scale: >=512 MB of zipf-skewed ratings
-    through one full alternating sweep (two skewed shuffles) with the
-    address space capped — the bounded-round exchange must hold its
-    memory contract at data sizes where a leak aborts the run.
-
-    Marked slow: the sweep's ~800 bounded exchange rounds take longer
-    than the entire tier-1 wall-clock budget on a CPU host, which
-    starved every alphabetically-later test file out of the tier-1 run
-    entirely. The default `-m 'not slow'` filter skips it; run it
-    explicitly (or at reduced REHEARSAL_ALS_MB) when touching the
-    exchange or ALS paths."""
-    size_mb = int(os.environ.get("REHEARSAL_ALS_MB", "512"))
-    script = _ALS_SCRIPT.format(repo=_REPO, size_mb=size_mb)
-    env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
-    proc = subprocess.run([sys.executable, "-c", script],
-                          capture_output=True, text=True, timeout=880,
-                          env=env)
-    assert proc.returncode == 0, (proc.stdout[-1000:], proc.stderr[-3000:])
-    if "CAP-NOT-EFFECTIVE" in proc.stdout:
-        pytest.skip("RLIMIT_AS not enforceable on this platform")
-    assert "ALS-REHEARSAL-OK" in proc.stdout
-    stats = json.loads(next(
-        ln for ln in proc.stdout.splitlines()
-        if ln.startswith("ALS=")).split("=", 1)[1])
-    assert stats["rounds_item"] >= 32
-    assert stats["rmse_after_sweep"] < stats["rmse_init"]

@@ -88,6 +88,36 @@ def test_repeated_indices_are_honoured_and_strays_clipped(forced_permute):
         rows[np.clip(stray, 0, 299)])
 
 
+@pytest.mark.parametrize("forced", (True, False), ids=("forced", "rule"))
+@pytest.mark.parametrize("m", (1_000, 4_099, 9_000),
+                         ids=("M<N", "M=N", "M>N"))
+def test_an_order_of_any_length_is_take(monkeypatch, m, forced):
+    """``permute_rows`` is ``jnp.take`` for M indices into N rows, in every
+    form the rule can choose: the packed kernels shape their result as
+    their operand, so only M = N can take them (forced here, as on the
+    chip), and any other length goes to ``take``. ALS's per-rating gather
+    is M = 26 N, repeated."""
+    # (the module's ``forced_permute`` may have the rule patched already)
+    monkeypatch.setattr(rp, "row_move_form", (
+        lambda n, w, p: "packed") if forced else ROW_MOVE_FORM)
+    n, w = 4_099, 10
+    rows = _rows(n, w)
+    order = np.random.default_rng(m).integers(0, n, m).astype(np.int32)
+    assert len(np.unique(order)) < m
+    chosen = []
+    move = jax.jit(lambda rows, order: rp.permute_rows(rows, order,
+                                                       chosen=chosen))
+    got = np.asarray(move(rows, order))
+    assert chosen == ["packed" if forced and m == n else "take"]
+    assert got.shape == (m, w)
+    np.testing.assert_array_equal(got, rows[order])
+    stray = order.copy()
+    stray[:2] = (-5, 10_000)
+    want = (rows[np.clip(stray, 0, n - 1)] if chosen == ["packed"]
+            else np.asarray(jnp.take(rows, stray, axis=0)))
+    np.testing.assert_array_equal(np.asarray(move(rows, stray)), want)
+
+
 @pytest.mark.parametrize("dtype", (np.int32, np.float32))
 def test_any_32_bit_rows_ride_the_packed_form(forced_permute, dtype):
     rows = _rows(500, 9).view(np.int32).astype(dtype)

@@ -533,3 +533,63 @@ def test_narrow_rows_cross_a_tpu_mesh_as_wire_rows(v5e_host, row_words,
         assert sent == (str(per_chip), str(row_words), str(2 * per_chip))
         assert capacity == 2 * per_chip
     assert lowered.out_info[0].shape == (4 * capacity, row_words)
+
+
+def _als_half_step_args(devices, side, links, chunks):
+    """``make_als_half_step`` for described chips at ``als_4chip``'s size
+    (480,189 users, 17,770 items, rank 10), and the shapes it takes: an
+    OutBlock of ``links`` rows and ``chunks`` chunks of 32 x 32,768 rating
+    slots a chip."""
+    from sparkrdma_tpu.models.als import (
+        TILE,
+        ALSConfig,
+        ids_per_block,
+        make_als_half_step,
+    )
+
+    n, nt = len(devices), 1 << 15
+    cfg = ALSConfig(num_users=480_189, num_items=17_770)
+    num_dst, num_src = ((cfg.num_items, cfg.num_users) if side == "item"
+                        else (cfg.num_users, cfg.num_items))
+    mesh = Mesh(np.array(devices), (AXIS,))
+    sh = NamedSharding(mesh, P(AXIS))
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sh)
+
+    return make_als_half_step(mesh, AXIS, cfg, side), (
+        shape((n * ids_per_block(num_src, n), cfg.rank), jnp.float32),
+        shape((n * links,), jnp.int32), shape((n * links,), jnp.int32),
+        shape((n * chunks, TILE, nt), jnp.int32),
+        shape((n * chunks, TILE, nt), jnp.float32),
+        shape((n * chunks * nt,), jnp.int32),
+        shape((n * ids_per_block(num_dst, n),), jnp.int32))
+
+
+# the compiler's own count of a chip's temporaries: 0.877 GB where items
+# are solved (the 492 MB receive buffer at 128 lanes is most of it), 1.347
+# GB where users are (the tile sums: 851,968 rows of 65 words at 128
+# lanes, twice), when written. 25 M gathered rows of 10 words whole and
+# padded to 128 lanes would be 12.8 GB: a chunk of them is live at a time.
+@pytest.mark.parametrize("side,links,chunks,table_rows,temp_limit", [
+    ("item", 480_188, 25, 480_189, 1_200_000_000),
+    ("user", 17_770, 26, 17_770, 1_800_000_000)])
+def test_als_half_steps_compile_at_the_cells_size(
+        v5e_host, side, links, chunks, table_rows, temp_limit):
+    """An ALS half-step on the four described chips of one v5e host at
+    ``als_4chip``'s size: the factor rows cross through the ragged
+    all-to-all one to a 128-lane wire row, the grouping and the
+    per-rating gather are ``take`` (one gather a chunk, in straight-line
+    code: no ``while``; its operand is the rows that can arrive, not the
+    whole ``out_factor`` 2 buffer), and no Mosaic kernel runs."""
+    step, args = _als_half_step_args(v5e_host, side, links, chunks)
+    compiled = step.lower(*args).compile()
+    assert step.row_moves == ["take", "take"]
+    text = compiled.as_text()
+    assert MOSAIC_CALL not in text and " while(" not in text
+    assert f"u32[{2 * links},1,128]" in text      # the receive buffer
+    gathers = [line for line in text.splitlines()
+               if " gather(" in line and "/als.gather/" in line]
+    assert len(gathers) == chunks
+    assert f"u32[{table_rows},10]" in text        # the gather's operand
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_limit
