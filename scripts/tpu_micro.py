@@ -18,11 +18,12 @@ off the chip its numbers mean nothing.
 
 ``python scripts/tpu_micro.py rowmove mn [out.json]``
     the row move where the order is not the operand's length (``PERF.md``
-    section 6, PR 35): ALS's per-rating gather, M = 25,120,127 indices,
-    repeated, into N rows of 10 words (N = 960,376 and 35,540, the two
-    receive buffers at ``out_factor`` 2; 480,189 and 17,770, the rows that
-    can arrive), by ``jnp.take`` in chunks of 2^20 indices as the
-    half-step's scan takes them, ns an index; and the packed form at
+    section 6, PR 35 and 37): ALS's per-rating gather, M = 25,120,127
+    indices, repeated, into N rows of 10 words (N = 960,376 and 35,540,
+    the two receive buffers at ``out_factor`` 2; 480,189 and 17,770, the
+    rows that can arrive; 240,095 and 120,048, a half and a quarter of the
+    users), by ``jnp.take`` in chunks of 2^20 indices as the half-step's
+    scan takes them, ns an index; and the packed form at
     N = M, which bounds what a packed gather of M out of N would cost
     (its operand would be smaller). Default ``chiprun_out/rowmove_mn.json``.
 
@@ -58,8 +59,8 @@ import jax.numpy as jnp
 
 ROWMOVE_N = (1 << 17, 1 << 18, 1 << 20, 1 << 22, 10_737_418)
 ROWMOVE_W = (2, 8, 16, 25, 32)
-ROWMOVE_MN_N, ROWMOVE_MN_M, ROWMOVE_MN_W = (960_376, 480_189, 35_540,
-                                            17_770), 25_120_127, 10
+ROWMOVE_MN_N, ROWMOVE_MN_M, ROWMOVE_MN_W = (960_376, 480_189, 240_095, 120_048,
+                                            35_540, 17_770), 25_120_127, 10
 GATHER_INDICES, GATHER_TABLE = 16_777_280, 468_750
 GROUPSORT_N, GROUPSORT_W, GROUPSORT_PARTS = 10_737_418, range(2, 9), 4
 

@@ -26,10 +26,12 @@ scopes (``make_als_half_step``):
    send them through ``exchange.shuffle_shard``, the generic path: rows of
    ``rank`` 4-byte words, grouped by an order vector, one wire row a row;
 2. ``als.gather``: every rating reads its source's vector out of the
-   receive buffer through the step's ``row_mover``; the InBlock holds the
-   source's place as ``(source device, rank in that device's OutBlock for
-   me)``, so the step adds ``recv_offsets[source device]`` and nothing is
-   scattered on arrival;
+   receive buffer by one clipped ``take`` a chunk (every index is in the
+   table by construction: ``take``'s default, a fill of what lies outside,
+   is a select over all that was gathered); the InBlock holds the source's
+   place as ``(source device, rank in that device's OutBlock for me)``, so
+   the step adds ``recv_offsets[source device]`` and nothing is scattered
+   on arrival;
 3. ``als.normal``: the ``k(k+1)/2 + k`` products a rating and their sums a
    destination id, in float32 on the vector unit (no ``dot``: nothing for
    the matrix unit's bfloat16 passes to round);
@@ -406,7 +408,11 @@ def make_als_half_step(mesh: Mesh, axis_name: str, cfg: ALSConfig,
             total = recv_counts.sum()
         # a block needs no vector twice, so no more rows than there are
         # source ids can have arrived: the gather's operand is that long
-        table = received[:min(received.shape[0], num_src)]
+        with jax.named_scope("als.gather"):
+            # as float32 here, not after the gather: a bit-cast of what is
+            # gathered is a pass over a chunk of 128-lane rows
+            table = jax.lax.bitcast_convert_type(
+                received[:min(received.shape[0], num_src)], jnp.float32)
 
         def chunk_sums(_, chunk):
             pos, r = chunk                           # [TILE, NT] each
@@ -419,8 +425,14 @@ def make_als_half_step(mesh: Mesh, axis_name: str, cfg: ALSConfig,
                 offset = sum(jnp.where(device == s, recv_offsets[s], 0)
                              for s in range(n))
                 at = jnp.where(valid, jax.lax.div(place, n) + offset, 0)
-                y = jax.lax.bitcast_convert_type(
-                    move(table, at.reshape(-1)), jnp.float32)
+                # clipped, not filled: ``jnp.take``'s default masks what is
+                # gathered against the table's bounds, and out of a table
+                # small enough to be gathered as rows that select runs over
+                # 2^20 rows padded to 128 lanes, 512 MB a chunk: 1.63 ms
+                # of a 4.3 ms chunk on the v5e (PERF.md section 6, PR 37).
+                # The padding slots' rows are masked below, as columns.
+                move.note("take")
+                y = jnp.take(table, at.reshape(-1), axis=0, mode="clip")
             with jax.named_scope("als.normal"):
                 # columns: y[a] is f32[TILE, NT], the tiles along the lanes
                 y = jnp.where(valid[None], y.T.reshape((k,) + pos.shape), 0.0)

@@ -212,6 +212,93 @@ def test_run_als_returns_the_history(ratings):
     assert history[0] > history[1] > history[2]
 
 
+def _ratings_of_the_layout(side, n):
+    """``(dst id, src id, rating)`` of every valid slot of ``side``'s
+    InBlocks, read back through the OutBlocks; asserts on the way that a
+    slot's rank is under the length of its source's OutBlock for the
+    device, and that a device's tiles ascend by destination id."""
+    out = []
+    for d in range(n):
+        _, _, nt = side.src_pos[d].shape
+        c, j, t = np.nonzero(side.src_pos[d] >= 0)
+        pos = side.src_pos[d][c, j, t]
+        rank, device = pos // n, pos % n
+        src = np.empty_like(pos)
+        for s in range(n):
+            # s's OutBlock for d
+            sent = side.out_idx[s][side.out_dest[s] == d]
+            of_s = device == s
+            assert (rank[of_s] < len(sent)).all()
+            src[of_s] = sent[rank[of_s]] * n + s
+        assert (np.diff(side.tile_dst[d]) >= 0).all()
+        dst = side.tile_dst[d][c * nt + t] * n + d
+        out.append(np.stack([dst, src, side.rating[d][c, j, t]], axis=1))
+    return np.concatenate(out)
+
+
+@pytest.mark.parametrize("n", [1, 2, 4])
+def test_every_rating_sits_in_exactly_one_slot(ratings, n):
+    user_side, item_side = block_ratings(CFG, ratings, n)
+    for side, dst, src in ((item_side, ratings.item, ratings.user),
+                           (user_side, ratings.user, ratings.item)):
+        got = _ratings_of_the_layout(side, n)
+        want = np.stack([dst, src, ratings.rating], axis=1)
+        assert len(got) == len(want)
+        np.testing.assert_array_equal(got[np.lexsort(got.T)],
+                                      want[np.lexsort(want.T)])
+        np.testing.assert_array_equal(
+            side.count.T.reshape(-1)[:dst.max() + 1], np.bincount(dst))
+
+
+def test_shapes_do_not_follow_the_seed(monkeypatch):
+    """Sixteen seeds, sixteen sets of block lengths, ONE set of shapes:
+    the draws enter through a maximum rounded up to whole chunks, so a
+    compile cache keyed on the program's text is warm under a new seed
+    (a program's shapes may follow the traffic file, never the seed)."""
+    # over these seeds the fullest device of the items' half-step has
+    # 1,734-2,115 tiles and that of the users' 1,629-1,758: two chunks of
+    # 1,100 each
+    monkeypatch.setattr(als, "_CHUNK_TILES", 1100)
+    shapes, lengths = set(), set()
+    for seed in range(16):
+        sides = block_ratings(CFG, _ratings(num=200_000, seed=seed), 4)
+        shapes.add(tuple(a.shape for side in sides for a in side[:6]))
+        lengths.add(tuple(int((side.tile_dst[d] < side.count.shape[1]).sum())
+                          for side in sides for d in range(4)))
+    assert len(shapes) == 1 and len(lengths) == 16
+    # the rounding is what holds the shape
+    assert next(iter(shapes))[2] == next(iter(shapes))[8] == (
+        4, 2, als.TILE, 1100)
+
+
+@pytest.mark.parametrize("side", als.SIDES)
+def test_a_half_step_lowers_to_one_text_with_no_select_over_gathered_rows(
+        ratings, side):
+    """The compile cache's key is the program's text: two builds of one
+    shape lower to the same text. And the gather is clipped, not filled:
+    no select runs over a chunk's rows as they were gathered (on the v5e
+    they are padded to 128 lanes; ``PERF.md`` section 6, PR 37); the
+    padding slots are masked as columns."""
+    mesh = _mesh(4)
+    resident = place_als(mesh, AXIS, block_ratings(CFG, ratings, 4))
+    blocks = resident.item_side if side == "item" else resident.user_side
+    factors = np.zeros(
+        (4 * als.ids_per_block(CFG.num_users if side == "item"
+                               else CFG.num_items, 4), CFG.rank), np.float32)
+    texts = {als.make_als_half_step(mesh, AXIS, CFG, side)
+             .lower(factors, *blocks.arrays).as_text() for _ in range(2)}
+    assert len(texts) == 1
+    _, tile, nt = blocks.src_pos.shape     # [D * chunks, TILE, NT]
+    rows = f"tensor<{tile * nt}x{CFG.rank}xf32>"
+    lines = next(iter(texts)).splitlines()
+    assert any("stablehlo.gather" in line and rows in line for line in lines)
+    assert not any("stablehlo.select" in line and rows in line
+                   for line in lines)
+    assert any("stablehlo.select" in line
+               and f"tensor<{CFG.rank}x{tile}x{nt}xf32>" in line
+               for line in lines)
+
+
 # -- the generator ------------------------------------------------------------
 
 def test_netflix_like_ratings_are_seeded_with_the_two_top_shares():

@@ -566,30 +566,96 @@ def _als_half_step_args(devices, side, links, chunks):
         shape((n * ids_per_block(num_dst, n),), jnp.int32))
 
 
-# the compiler's own count of a chip's temporaries: 0.877 GB where items
-# are solved (the 492 MB receive buffer at 128 lanes is most of it), 1.347
+def _whiles_that_gather(hlo_text):
+    """Names of the ``while`` ops of a compiled module's text whose body,
+    or a computation it calls, holds a ``gather``: the shape the v5e ran
+    wrongly when the gather's table was loop-carried in VMEM (``PERF.md``
+    section 7.14)."""
+    import re
+
+    bodies, name = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.-]+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            bodies[name] = []
+        elif name is not None:
+            bodies[name].append(line)
+
+    def gathers(computation, seen):
+        if computation in seen or computation not in bodies:
+            return False
+        seen.add(computation)
+        return any(
+            " gather(" in line
+            or any(gathers(callee, seen) for callee in re.findall(
+                r"(?:calls|to_apply|body|condition)=(%[\w.-]+)", line))
+            for line in bodies[computation])
+
+    return [re.match(r"\s*(?:ROOT )?(%[\w.-]+)", line).group(1)
+            for lines in bodies.values() for line in lines
+            if " while(" in line
+            and gathers(re.search(r"body=(%[\w.-]+)", line).group(1), set())]
+
+
+def test_whiles_that_gather_reads_a_modules_text():
+    text = """\
+%fused (p: u32[8,10], i: s32[4]) -> u32[4,10] {
+  %g = u32[4,10] gather(%p, %i), offset_dims={1}
+}
+
+%body.1 (t: (u32[8,10], s32[4])) -> (u32[8,10], s32[4]) {
+  %f = u32[4,10] fusion(%a, %b), kind=kCustom, calls=%fused
+}
+
+%body.2 (t: (s32[])) -> (s32[]) {
+  %add = s32[] add(%x, %y)
+}
+
+ENTRY %main (a: u32[8,10]) -> u32[4,10] {
+  %while.7 = (u32[8,10], s32[4]) while(%t), condition=%cond.1, body=%body.1
+  ROOT %while.9 = (s32[]) while(%u), condition=%cond.2, body=%body.2
+}
+"""
+    assert _whiles_that_gather(text) == ["%while.7"]
+
+
+# the compiler's own count of a chip's temporaries: 0.789 GB where items
+# are solved (the 492 MB receive buffer at 128 lanes is most of it), 1.244
 # GB where users are (the tile sums: 851,968 rows of 65 words at 128
-# lanes, twice), when written. 25 M gathered rows of 10 words whole and
-# padded to 128 lanes would be 12.8 GB: a chunk of them is live at a time.
+# lanes, twice), when written (0.877 / 1.347 GB before PR 37 clipped the
+# gather). 25 M gathered rows of 10 words whole and padded to 128 lanes
+# would be 12.8 GB: a chunk of them is live at a time.
 @pytest.mark.parametrize("side,links,chunks,table_rows,temp_limit", [
-    ("item", 480_188, 25, 480_189, 1_200_000_000),
-    ("user", 17_770, 26, 17_770, 1_800_000_000)])
+    ("item", 480_192, 25, 480_189, 1_200_000_000),
+    ("user", 17_772, 26, 17_770, 1_350_000_000)])
 def test_als_half_steps_compile_at_the_cells_size(
         v5e_host, side, links, chunks, table_rows, temp_limit):
     """An ALS half-step on the four described chips of one v5e host at
     ``als_4chip``'s size: the factor rows cross through the ragged
     all-to-all one to a 128-lane wire row, the grouping and the
     per-rating gather are ``take`` (one gather a chunk, in straight-line
-    code: no ``while``; its operand is the rows that can arrive, not the
-    whole ``out_factor`` 2 buffer), and no Mosaic kernel runs."""
+    code: no ``while``, and none whose body gathers; its operand is the
+    rows that can arrive, not the whole ``out_factor`` 2 buffer; what it
+    gathers is written once: no select and no bit-cast runs over a chunk
+    of rows padded to 128 lanes), and no Mosaic kernel runs."""
+    import re
+
     step, args = _als_half_step_args(v5e_host, side, links, chunks)
     compiled = step.lower(*args).compile()
     assert step.row_moves == ["take", "take"]
     text = compiled.as_text()
     assert MOSAIC_CALL not in text and " while(" not in text
+    assert _whiles_that_gather(text) == []
     assert f"u32[{2 * links},1,128]" in text      # the receive buffer
     gathers = [line for line in text.splitlines()
                if " gather(" in line and "/als.gather/" in line]
     assert len(gathers) == chunks
-    assert f"u32[{table_rows},10]" in text        # the gather's operand
+    assert f"f32[{table_rows},10]" in text        # the gather's operand
+    # a chunk's rows as gathered, 512 MB at 128 lanes where the table is
+    # small enough to be gathered as rows: one op a chunk writes them
+    entry = text[text.index("ENTRY "):]
+    written = re.findall(r"= [fu]32\[1048576,10\]\S* (?!bitcast\()[\w-]+\(",
+                         entry)
+    assert len(written) == chunks
     assert compiled.memory_analysis().temp_size_in_bytes < temp_limit
