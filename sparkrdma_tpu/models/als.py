@@ -375,13 +375,19 @@ def make_als_half_step(mesh: Mesh, axis_name: str, cfg: ALSConfig,
 
     A device profile names the four phases by scope (``als.exchange``,
     ``als.gather``, ``als.normal``, ``als.solve``: the module's
-    docstring). ``step.row_moves`` lists the forms the step's row moves
-    took (the grouping's and the gather's), once the step has been
-    traced. No option selects a form: the CPU tests run the program the
-    chip runs.
+    docstring), all under ``als.item_step`` or ``als.user_step``.
+    ``step.row_moves`` lists the forms the step's row moves took (the
+    grouping's and the gather's), once the step has been traced. No
+    option selects a form: the CPU tests run the program the chip runs.
     """
     if side not in SIDES:
         raise ValueError(f"side {side!r} is none of {SIDES}")
+    # The scopes below are op metadata, which jax leaves out of the
+    # persistent compile cache's key by default: an executable cached by a
+    # build with other scopes would be loaded in place of this one and a
+    # profile would carry that build's names (``make_fused_step`` sets the
+    # same, for the same reason). Process-wide, a matter of cache keys only.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     n = mesh.shape[axis_name]
     impl = resolve_impl(mesh, impl, axis_name)
     k = cfg.rank
@@ -393,11 +399,8 @@ def make_als_half_step(mesh: Mesh, axis_name: str, cfg: ALSConfig,
     row_moves: list = []
     move = row_mover(mesh, row_moves)
 
-    @jax.jit
-    @functools.partial(shard_map, mesh=mesh, in_specs=(spec,) * 7,
-                       out_specs=(spec, spec, spec))
-    def step(src_factors, out_idx, out_dest, src_pos, rating, tile_dst,
-             count):
+    def half_step(src_factors, out_idx, out_dest, src_pos, rating, tile_dst,
+                  count):
         with jax.named_scope("als.exchange"):
             rows = jax.lax.bitcast_convert_type(
                 jnp.take(src_factors, out_idx, axis=0), jnp.uint32)
@@ -462,6 +465,17 @@ def make_als_half_step(mesh: Mesh, axis_name: str, cfg: ALSConfig,
         return (factors,
                 jnp.stack([total, total]).astype(jnp.int32)[None],
                 overflowed[None])
+
+    @jax.jit
+    @functools.partial(shard_map, mesh=mesh, in_specs=(spec,) * 7,
+                       out_specs=(spec, spec, spec))
+    def step(src_factors, out_idx, out_dest, src_pos, rating, tile_dst,
+             count):
+        # the half-step whole under one scope: a job runs two programs
+        # whose ops share names, and a profile tells them apart by this
+        with jax.named_scope(f"als.{side}_step"):
+            return half_step(src_factors, out_idx, out_dest, src_pos,
+                             rating, tile_dst, count)
 
     step.row_moves = row_moves
     return step

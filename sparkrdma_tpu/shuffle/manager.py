@@ -36,7 +36,7 @@ from sparkrdma_tpu.runtime.pool import BufferPool
 from sparkrdma_tpu.shuffle.reader import TpuShuffleReader
 from sparkrdma_tpu.shuffle.resolver import TpuShuffleBlockResolver
 from sparkrdma_tpu.shuffle.writer import Partitioner, TpuShuffleWriter
-from sparkrdma_tpu.utils.stats import MemStats, ShuffleReaderStats
+from sparkrdma_tpu.utils.stats import ShuffleReaderStats
 from sparkrdma_tpu.utils import trace as trace_mod
 
 import logging
@@ -123,7 +123,6 @@ class TpuShuffleManager:
                              if self.conf.collect_shuffle_reader_stats else None)
         self.tracer = trace_mod.get(self.conf)
         self._role_name = executor_id  # "driver" for the driver role
-        self._mem_stats = MemStats()
 
         if is_driver:
             # HA deployments hand the driver role a shared lease store
@@ -416,7 +415,6 @@ class TpuShuffleManager:
         pool_stats = self.pool.stop()
         if pool_stats.get("bins"):
             log.info("buffer pool stats: %s", pool_stats)
-        log.info("host paging over manager lifetime: %s", self._mem_stats.diff())
         if self.driver is not None:
             self.driver.stop()
 

@@ -113,6 +113,13 @@ def run_mesh_reduce_fused(managers: Sequence[TpuShuffleManager],
     pw = device_row_words(handle.row_payload_bytes)
 
     if rows_per_round > 0:
+        def round_block(pieces_r, pieces_d):
+            with tracer.span("exchange.stage_cut", "exchange",
+                             rows=sum(map(len, pieces_r))) as args:
+                block = np.concatenate(pieces_r), np.concatenate(pieces_d)
+                args["bytes"] = block[0].nbytes + block[1].nbytes
+            return block
+
         # bounded rounds: stream spills straight into round blocks
         def round_blocks():
             pending_r: List[np.ndarray] = []
@@ -120,11 +127,13 @@ def run_mesh_reduce_fused(managers: Sequence[TpuShuffleManager],
             pending = 0
             per_round = rows_per_round * n_dev
             delivered: set = set()
-            for k, p in _iter_committed_batches(managers, handle,
-                                                delivered):
-                rows = _rows_to_u32(k, p)
-                dest = (np.asarray(partitioner(k), dtype=np.int32)
-                        % n_dev)
+            for k, p in _read_batches(tracer, _iter_committed_batches(
+                    managers, handle, delivered)):
+                rows = _pack_batch(tracer, k, p)
+                with tracer.span("exchange.stage_route", "exchange",
+                                 rows=len(k)):
+                    dest = (np.asarray(partitioner(k), dtype=np.int32)
+                            % n_dev)
                 while len(rows):
                     take = min(len(rows), per_round - pending)
                     pending_r.append(rows[:take])
@@ -132,13 +141,12 @@ def run_mesh_reduce_fused(managers: Sequence[TpuShuffleManager],
                     pending += take
                     rows, dest = rows[take:], dest[take:]
                     if pending == per_round:
-                        yield (np.concatenate(pending_r),
-                               np.concatenate(pending_d))
+                        yield round_block(pending_r, pending_d)
                         pending_r, pending_d, pending = [], [], 0
             _check_staging_complete(delivered, expect_maps,
                                     handle.shuffle_id)
             if pending:
-                yield np.concatenate(pending_r), np.concatenate(pending_d)
+                yield round_block(pending_r, pending_d)
 
         per_device, _rounds = run_fused_exchange_rounds(
             mesh, axis_name, round_blocks(), pw, rows_per_round,
@@ -149,9 +157,13 @@ def run_mesh_reduce_fused(managers: Sequence[TpuShuffleManager],
         # staging is here; the driver's own exchange.stage spans only
         # slice what this one made
         with tracer.span("exchange.stage", "exchange", round=0) as args:
-            keys, payload = _stage_all(managers, handle, expect_maps)
-            rows = _rows_to_u32(keys, payload)
-            dest = (np.asarray(partitioner(keys), dtype=np.int32) % n_dev)
+            keys, payload = _stage_all(managers, handle, expect_maps,
+                                       tracer)
+            rows = _pack_batch(tracer, keys, payload)
+            with tracer.span("exchange.stage_route", "exchange",
+                             rows=len(keys)):
+                dest = (np.asarray(partitioner(keys), dtype=np.int32)
+                        % n_dev)
             args["rows"] = len(rows)
             args["bytes"] = rows.nbytes + dest.nbytes
         per_device, _rounds = run_fused_exchange(
@@ -208,12 +220,14 @@ def run_mesh_reduce_hier(managers: Sequence[TpuShuffleManager],
         part_bytes = np.zeros((topology.num_slices, handle.num_partitions),
                               dtype=np.int64)
         delivered: set = set()
-        for i, k, p in _iter_committed_batches_indexed(managers, handle,
-                                                       delivered):
+        for i, k, p in _read_batches(tracer, _iter_committed_batches_indexed(
+                managers, handle, delivered)):
             home = topology.slice_of_slot(i, num_mgrs)
-            parts = np.asarray(partitioner(k), dtype=np.int64)
+            with tracer.span("exchange.stage_route", "exchange",
+                             rows=len(k)):
+                parts = np.asarray(partitioner(k), dtype=np.int64)
             np.add.at(part_bytes[home], parts, row_bytes)
-            all_rows.append(_rows_to_u32(k, p))
+            all_rows.append(_pack_batch(tracer, k, p))
             all_parts.append(parts)
             all_home.append(np.full(len(k), home, dtype=np.int32))
         _check_staging_complete(delivered, expect_maps, handle.shuffle_id)
@@ -257,7 +271,34 @@ def _unpack_devices(per_device, handle, partitioner, tracer):
     return results
 
 
-def _stage_all(managers, handle, expect_maps: Optional[int]
+def _read_batches(tracer, batches):
+    """``batches`` (``_iter_committed_batches*``), each pull from it under
+    an ``exchange.stage_read`` span: the resolver's read of one committed
+    spill and its decode, with the ``rows`` and ``bytes`` it gave. The
+    last pull, which finds the end, records ``rows=0``."""
+    while True:
+        with tracer.span("exchange.stage_read", "exchange", rows=0,
+                         bytes=0) as args:
+            batch = next(batches, None)
+            if batch is not None:
+                *_, keys, payload = batch
+                args.update(rows=len(keys),
+                            bytes=keys.nbytes + payload.nbytes)
+        if batch is None:
+            return
+        yield batch
+
+
+def _pack_batch(tracer, keys: np.ndarray, payload: np.ndarray) -> np.ndarray:
+    """``_rows_to_u32`` under an ``exchange.stage_pack`` span."""
+    with tracer.span("exchange.stage_pack", "exchange",
+                     rows=len(keys)) as args:
+        rows = _rows_to_u32(keys, payload)
+        args["bytes"] = rows.nbytes
+    return rows
+
+
+def _stage_all(managers, handle, expect_maps: Optional[int], tracer
                ) -> Tuple[np.ndarray, np.ndarray]:
     """Stage every committed local spill into one (keys, payload) pair:
     streamed sequentially (no host scatter) through the resolver's
@@ -266,7 +307,8 @@ def _stage_all(managers, handle, expect_maps: Optional[int]
     staging; bounded rounds stream instead."""
     all_keys, all_payloads = [], []
     delivered: set = set()
-    for k, p in _iter_committed_batches(managers, handle, delivered):
+    for k, p in _read_batches(tracer, _iter_committed_batches(
+            managers, handle, delivered)):
         all_keys.append(k)
         all_payloads.append(p)
     _check_staging_complete(delivered, expect_maps, handle.shuffle_id)

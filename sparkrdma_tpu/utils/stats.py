@@ -1,4 +1,4 @@
-"""Shuffle observability: fetch-latency histograms + host-memory stats.
+"""Shuffle observability: fetch-latency histograms and pipeline counters.
 
 Re-design of ``scala/RdmaShuffleReaderStats.scala``:
 
@@ -9,13 +9,13 @@ Re-design of ``scala/RdmaShuffleReaderStats.scala``:
   ``collect_shuffle_reader_stats``, scala/RdmaShuffleConf.scala:121-123);
 * the reference's ``OdpStats`` diffs NIC page-fault counters from sysfs
   before/after (RdmaShuffleReaderStats.scala:83-99). The TPU analogue of
-  "did my memory registration thrash" is host-process paging while staging:
-  ``MemStats`` diffs major/minor page faults + peak RSS from procfs.
+  "did my memory registration thrash" is host paging while staging, and
+  it is on the spans where the faults happen: ``minflt`` / ``majflt`` of
+  every ``utils.trace.Tracer.span``.
 """
 
 from __future__ import annotations
 
-import os
 import threading
 from typing import Dict, List, Optional
 
@@ -380,50 +380,3 @@ class ShuffleReaderStats:
                         remote, summary)
         if "failures" in snap:
             logger.info("shuffle fetch failure path: %s", snap["failures"])
-
-
-class MemStats:
-    """Host paging counters diffed over a window (OdpStats analogue,
-    RdmaShuffleReaderStats.scala:83-99)."""
-
-    def __init__(self):
-        self._start = self._read()
-
-    @staticmethod
-    def _read() -> dict:
-        try:
-            with open("/proc/self/stat") as f:
-                fields = f.read().split()
-            minflt, majflt = int(fields[9]), int(fields[11])
-        except (OSError, IndexError, ValueError):
-            minflt = majflt = 0
-        peak_kb = 0
-        try:
-            with open("/proc/self/status") as f:
-                for line in f:
-                    if line.startswith("VmHWM:"):
-                        peak_kb = int(line.split()[1])
-                        break
-        except (OSError, IndexError, ValueError):
-            pass
-        if peak_kb == 0:
-            # sandboxed /proc (gVisor-style) omits VmHWM; getrusage's
-            # ru_maxrss is already KiB on Linux
-            try:
-                import resource
-                peak_kb = resource.getrusage(
-                    resource.RUSAGE_SELF).ru_maxrss
-            except (ImportError, OSError, ValueError):
-                pass
-        return {"minor_faults": minflt, "major_faults": majflt,
-                "peak_rss_kb": peak_kb}
-
-    def diff(self) -> dict:
-        now = self._read()
-        return {k: now[k] - self._start[k] if k != "peak_rss_kb" else now[k]
-                for k in now}
-
-
-def process_stats() -> dict:
-    """One-shot convenience: pid + paging + rss snapshot."""
-    return {"pid": os.getpid(), **MemStats._read()}

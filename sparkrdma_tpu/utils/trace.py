@@ -17,12 +17,19 @@ trace) the program's spans lie in the profile beside the device's ops.
 With no session an annotation is one flag read. Every ``Tracer`` of a
 process counts from one origin, so the dumps of a driver's and its
 executors' tracers overlay in Perfetto.
+
+A span also says what its thread did: the thread's user and kernel CPU
+seconds, page faults and context switches inside the block, and the whole
+process's CPU seconds meanwhile (``ACCOUNTING_ARGS``, in the event's
+``args``); wall less CPU is time the thread was off the CPU.
+``complete_span``, ``instant`` and ``counter`` events carry none.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import resource
 import threading
 import time
 from contextlib import contextmanager
@@ -30,6 +37,13 @@ from typing import List
 
 # the one origin of every Tracer's clock in this process
 _ORIGIN = time.perf_counter()
+
+# what a live tracer's span adds to its event's args, from the thread's
+# rusage at both ends of the block: reserved, a caller passes none of them
+ACCOUNTING_ARGS = ("cpu_user_s", "cpu_sys_s", "minflt", "majflt", "nvcsw",
+                   "nivcsw", "proc_cpu_s")
+# Linux's; where it is missing the spans carry no accounting
+_RUSAGE_THREAD = getattr(resource, "RUSAGE_THREAD", None)
 
 
 def _annotation(name: str):
@@ -59,16 +73,36 @@ class Tracer:
         """Time the block as one chrome event and, under a running
         profiler session, as a ``TraceAnnotation`` of the same name.
         Yields the event's ``args`` dict: a block that learns a size only
-        at its end (the rows a ``next()`` returned) fills it in there."""
+        at its end (the rows a ``next()`` returned) fills it in there.
+        At the block's end the args gain ``ACCOUNTING_ARGS``: what the
+        thread (``RUSAGE_THREAD``) and, in ``proc_cpu_s``, the process
+        spent between the block's two ends."""
         if not self.enabled:
             yield args
             return
+        if not args.keys().isdisjoint(ACCOUNTING_ARGS):
+            raise ValueError(
+                f"span {name!r}: {sorted(set(args) & set(ACCOUNTING_ARGS))} "
+                "are the tracer's own accounting args")
+        if _RUSAGE_THREAD is not None:
+            ru0 = resource.getrusage(_RUSAGE_THREAD)
+            proc0 = time.process_time()
         start = self._now_us()
         try:
             with _annotation(name):
                 yield args
         finally:
             end = self._now_us()
+            if _RUSAGE_THREAD is not None:
+                ru1 = resource.getrusage(_RUSAGE_THREAD)
+                args.update(
+                    cpu_user_s=ru1.ru_utime - ru0.ru_utime,
+                    cpu_sys_s=ru1.ru_stime - ru0.ru_stime,
+                    minflt=ru1.ru_minflt - ru0.ru_minflt,
+                    majflt=ru1.ru_majflt - ru0.ru_majflt,
+                    nvcsw=ru1.ru_nvcsw - ru0.ru_nvcsw,
+                    nivcsw=ru1.ru_nivcsw - ru0.ru_nivcsw,
+                    proc_cpu_s=time.process_time() - proc0)
             with self._lock:
                 if len(self._events) >= self.MAX_EVENTS:
                     self.dropped += 1

@@ -31,6 +31,10 @@ SPANS = frozenset({
     "exchange.round",
     "exchange.split",
     "exchange.stage",
+    "exchange.stage_cut",
+    "exchange.stage_pack",
+    "exchange.stage_read",
+    "exchange.stage_route",
     "exchange.unpack",
     "fetch.blocks",
     "fetch.complete",

@@ -7,7 +7,8 @@ import numpy as np
 from sparkrdma_tpu.config import TpuShuffleConf
 from sparkrdma_tpu.shuffle.spark_compat import SparkCompatShuffleManager
 
-CONF = TpuShuffleConf(connect_timeout_ms=1000, max_connection_attempts=2)
+CONF_KW = dict(connect_timeout_ms=1000, max_connection_attempts=2)
+CONF = TpuShuffleConf(**CONF_KW)
 
 
 def u32_payload(values) -> np.ndarray:
@@ -25,11 +26,13 @@ def make_table(seed: int, rows: int, key_space: int):
     return keys, vals
 
 
-def make_cluster(tmp_path, n: int = 3):
-    """(driver, executors) with membership settled; caller stops them."""
-    driver = SparkCompatShuffleManager(CONF, isDriver=True)
+def make_cluster(tmp_path, n: int = 3, **conf_kw):
+    """(driver, executors) with membership settled; caller stops them.
+    ``conf_kw``: config keys laid over ``CONF``'s."""
+    conf = TpuShuffleConf(**CONF_KW, **conf_kw) if conf_kw else CONF
+    driver = SparkCompatShuffleManager(conf, isDriver=True)
     execs = [SparkCompatShuffleManager(
-        CONF, driverAddr=driver.driverAddr, executorId=str(i),
+        conf, driverAddr=driver.driverAddr, executorId=str(i),
         spill_dir=str(tmp_path / f"e{i}")) for i in range(n)]
     for ex in execs:
         ex.native.executor.wait_for_members(n)

@@ -299,6 +299,34 @@ def test_a_half_step_lowers_to_one_text_with_no_select_over_gathered_rows(
                for line in lines)
 
 
+@pytest.mark.parametrize("side", als.SIDES)
+def test_a_half_step_is_one_scope_and_its_names_are_in_the_cache_key(
+        ratings, side):
+    """Every op of a half-step lies under ``als.<side>_step`` (a job runs
+    two programs whose ops share names: a profile tells them apart by
+    it), the four phases inside it. The names are metadata: a cached
+    executable of a build without them must not be served for this
+    program (on the chip the parent's was, and the profile had no
+    ``als.item_step``: ``PERF.md`` section 6, PR 38)."""
+    import re
+
+    mesh = _mesh(2)
+    resident = place_als(mesh, AXIS, block_ratings(CFG, ratings, 2))
+    blocks = resident.item_side if side == "item" else resident.user_side
+    factors = np.zeros(
+        (2 * als.ids_per_block(CFG.num_users if side == "item"
+                               else CFG.num_items, 2), CFG.rank), np.float32)
+    hlo = als.make_als_half_step(mesh, AXIS, CFG, side).lower(
+        factors, *blocks.arrays).compile().as_text()
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
+    named = re.findall(r'op_name="(jit\(step\)/shard_map/[^"]*)"', hlo)
+    # (the compiler's own hoisted broadcasts carry no scope at all)
+    assert named and all(f"/als.{side}_step/" in path
+                         for path in named if "/als." in path)
+    for phase in ("exchange", "gather", "normal", "solve"):
+        assert any(f"/als.{phase}/" in path for path in named), phase
+
+
 # -- the generator ------------------------------------------------------------
 
 def test_netflix_like_ratings_are_seeded_with_the_two_top_shares():

@@ -24,10 +24,15 @@ from sparkrdma_tpu.parallel.device_plane import (
 )
 from sparkrdma_tpu.shuffle.manager import PartitionerSpec
 from sparkrdma_tpu.shuffle.spark_compat import ShuffleDependency
-from sparkrdma_tpu.utils.trace import Tracer
+from sparkrdma_tpu.utils.trace import ACCOUNTING_ARGS, Tracer
 
 SEED = int(os.environ.get("DEVICE_SEED", "0"))
 D = 8
+
+
+def _own(args):
+    """A span's args less the tracer's accounting: what the caller gave."""
+    return {k: v for k, v in args.items() if k not in ACCOUNTING_ARGS}
 
 
 @pytest.fixture(scope="module")
@@ -372,7 +377,7 @@ def test_merge_span_counts_bytes_written(mesh, want_rounds):
         mesh, "shuffle", blocks, W, per_round // D, key_words=2,
         impl="gather", out_factor=4, tracer=tracer)
     assert rounds == want_rounds
-    (merge,) = [e["args"] for e in tracer._events
+    (merge,) = [_own(e["args"]) for e in tracer._events
                 if e["name"] == "exchange.merge"]
     assert merge == {"runs": rounds, "rows": N,
                      "bytes": N * W * 4 if rounds > 1 else 0}
@@ -510,7 +515,7 @@ def test_result_stage_spans_tile_the_mesh_reduce(cluster, mesh4, budget,
     for e in spans:
         by_name.setdefault(e["name"], []).append(e)
     (reduce_span,) = by_name["engine.mesh_reduce"]
-    assert set(reduce_span["args"]) == {"shuffle"}
+    assert set(reduce_span["args"]) == {"shuffle", *ACCOUNTING_ARGS}
     lo, hi = reduce_span["ts"], reduce_span["ts"] + reduce_span["dur"]
     for name in STAGE_SPANS + ONCE_SPANS:
         assert by_name.get(name), f"no {name} span"
@@ -520,7 +525,7 @@ def test_result_stage_spans_tile_the_mesh_reduce(cluster, mesh4, budget,
     for name in ONCE_SPANS:
         assert len(by_name[name]) == 1, name
     rounds = len(by_name["exchange.round"])
-    merge, unpack, split = (by_name[n][0]["args"] for n in ONCE_SPANS)
+    merge, unpack, split = (_own(by_name[n][0]["args"]) for n in ONCE_SPANS)
     # the merge wrote every 12-byte row once, or nothing: a single run
     # passes through
     assert merge == {"runs": rounds, "rows": records,
@@ -535,7 +540,7 @@ def test_result_stage_spans_tile_the_mesh_reduce(cluster, mesh4, budget,
     for e in by_name["exchange.collect"]:
         # the whole padded receive buffer comes back, not the useful rows
         assert e["args"]["bytes"] > e["args"]["rows"] * 12
-    staged = [e["args"] for e in by_name["exchange.stage"]]
+    staged = [_own(e["args"]) for e in by_name["exchange.stage"]]
     assert all(set(a) == {"round", "rows", "bytes"} for a in staged)
     if multi_round:
         assert rounds == -(-records // (64 * D4)) and rounds >= 3

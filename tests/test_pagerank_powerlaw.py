@@ -27,7 +27,7 @@ from sparkrdma_tpu.models.pagerank import (  # noqa: E402
     powerlaw_graph,
 )
 from sparkrdma_tpu.parallel import exchange  # noqa: E402
-from sparkrdma_tpu.utils.trace import Tracer  # noqa: E402
+from sparkrdma_tpu.utils.trace import ACCOUNTING_ARGS, Tracer  # noqa: E402
 
 AXIS = "shuffle"
 ZIPF_S = 0.9
@@ -166,11 +166,14 @@ def test_job_spans_and_counters(tmp_path):
     assert set(spans) == {"pagerank.job", "pagerank.dispatch",
                           "pagerank.wait"}
     job = spans["pagerank.job"]
-    assert job["args"] == {"iterations": 3, "edges": len(edges) - 10,
-                           "vertices": num_v,
-                           "received": [len(edges) - 10] * 3,
-                           # 8-byte rows: jnp.take on any platform
-                           "row_move": "sort"}
+    # what the caller gave; the tracer adds its accounting beside it
+    own = {k: v for k, v in job["args"].items()
+           if k not in ACCOUNTING_ARGS}
+    assert own == {"iterations": 3, "edges": len(edges) - 10,
+                   "vertices": num_v,
+                   "received": [len(edges) - 10] * 3,
+                   # 8-byte rows: jnp.take on any platform
+                   "row_move": "sort"}
     for inner in ("pagerank.dispatch", "pagerank.wait"):
         assert job["ts"] <= spans[inner]["ts"]
         assert (spans[inner]["ts"] + spans[inner]["dur"]

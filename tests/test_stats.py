@@ -5,9 +5,7 @@ import logging
 from sparkrdma_tpu.config import TpuShuffleConf
 from sparkrdma_tpu.utils.stats import (
     FetchHistogram,
-    MemStats,
     ShuffleReaderStats,
-    process_stats,
 )
 
 
@@ -33,18 +31,6 @@ def test_reader_stats_per_remote():
     assert snap["per_remote"]["0"]["count"] == 2
     assert snap["per_remote"]["3"]["count"] == 1
     stats.log_summary(logging.getLogger("test"))  # must not raise
-
-
-def test_mem_stats_diff_monotonic():
-    m = MemStats()
-    # touch some memory to cause faults
-    blob = bytearray(4 << 20)
-    blob[::4096] = b"x" * len(blob[::4096])
-    d = m.diff()
-    assert d["minor_faults"] >= 0
-    assert d["peak_rss_kb"] > 0
-    p = process_stats()
-    assert p["pid"] > 0
 
 
 def test_device_profile_captures_xla_trace(tmp_path):
