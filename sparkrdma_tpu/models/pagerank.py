@@ -13,8 +13,9 @@ their source vertex's device. One iteration is one jitted SPMD step:
    ``src`` (local by construction): one random read an edge;
 2. ragged exchange of ``(dst, contribution)`` rows to dst's owner device
    (the GraphX shuffle);
-3. segment-sum received contributions into local ranks (one scatter-add),
-   then ``rank = (1 - d)/V + d * sums``.
+3. segment-sum received contributions into local ranks (one scatter-add
+   in a loop over the receive buffer's chunks that hold them), then
+   ``rank = (1 - d)/V + d * sums``.
 
 Ranks never leave their shard; only contributions move — the same traffic
 shape GraphX produces, minus the host.
@@ -52,6 +53,18 @@ class PageRankConfig:
     out_factor: int = 2
 
 
+_ACCUMULATE_CHUNKS = 32   # chunks a receive buffer is cut into
+_ACCUMULATE_TILE = 1024   # a chunk is whole tiles of this many slots
+
+
+def accumulate_chunk(capacity: int) -> int:
+    """Receive slots one trip of ``pagerank.accumulate``'s loop reads: a
+    32nd of ``capacity`` rounded up to whole 1,024-slot tiles, and never
+    past ``capacity`` (1,049,600 of 33,554,560 at the cells' size)."""
+    per = -(-capacity // _ACCUMULATE_CHUNKS)
+    return min(capacity, -(-per // _ACCUMULATE_TILE) * _ACCUMULATE_TILE)
+
+
 def make_pagerank_step(mesh: Mesh, axis_name: str, cfg: PageRankConfig,
                        impl: str = "auto"):
     """One jitted PageRank iteration (a *superstep*).
@@ -82,8 +95,10 @@ def make_pagerank_step(mesh: Mesh, axis_name: str, cfg: PageRankConfig,
     ``pagerank.contrib`` (one per-edge gather of ``rank / out_degree``
     and the per-vertex divide that makes that table),
     ``pagerank.exchange`` (grouping, with its ``row_sort``, and the
-    transport) and ``pagerank.accumulate`` (masking, scatter-add,
-    damping). ``step.row_moves`` lists the form the grouping's row move
+    transport) and ``pagerank.accumulate`` (a loop over the chunks of
+    ``accumulate_chunk`` slots that hold the ``total`` records received,
+    masking and scatter-adding one a trip; then the damping).
+    ``step.row_moves`` lists the form the grouping's row move
     took (``ops.row_permute``), once the step has been traced.
     """
     n = mesh.shape[axis_name]
@@ -125,14 +140,30 @@ def make_pagerank_step(mesh: Mesh, axis_name: str, cfg: PageRankConfig,
                                       cfg.out_factor, impl, move)
         with jax.named_scope("pagerank.accumulate"):
             total = recv_counts.sum()
-            rvalid = jnp.arange(received.shape[0], dtype=jnp.int32) < total
-            rdst = jnp.where(
-                rvalid, received[:, 0].astype(jnp.int32) - me * v_local, 0)
-            rcontrib = jnp.where(
-                rvalid,
-                jax.lax.bitcast_convert_type(received[:, 1], jnp.float32),
-                0.0)
-            sums = jnp.zeros(v_local, jnp.float32).at[rdst].add(rcontrib)
+            cap = received.shape[0]
+            chunk = accumulate_chunk(cap)
+            iota = jnp.arange(chunk, dtype=jnp.int32)
+
+            def add_chunk(c, sums):
+                # the last chunk's start is clamped to cap - chunk: mask by
+                # the slot it read, so the overlap is not counted twice
+                first = c * chunk
+                start = jnp.minimum(first, cap - chunk)
+                slot = start + iota
+                rvalid = (slot >= first) & (slot < total)
+                part = jax.lax.dynamic_slice_in_dim(received, start, chunk)
+                rdst = jnp.where(
+                    rvalid, part[:, 0].astype(jnp.int32) - me * v_local, 0)
+                rcontrib = jnp.where(
+                    rvalid,
+                    jax.lax.bitcast_convert_type(part[:, 1], jnp.float32),
+                    0.0)
+                return sums.at[rdst].add(rcontrib)
+
+            # the records arrived as the prefix [0, total): one scatter-add
+            # a chunk, over the chunks that hold them and no further
+            sums = jax.lax.fori_loop(0, (total + chunk - 1) // chunk,
+                                     add_chunk, jnp.zeros_like(ranks))
             new_ranks = ((1.0 - cfg.damping) / cfg.num_vertices
                          + cfg.damping * sums)
         return (new_ranks,
@@ -249,7 +280,9 @@ class PageRankJob:
     engine's): ``pagerank.job`` (``iterations``, ``edges``, ``vertices``;
     at its end ``received``, the contributions delivered in each
     superstep, and ``row_move``, the form the rows followed their order
-    in) around ``pagerank.dispatch`` and ``pagerank.wait``.
+    in, and ``accumulate_fill``, the most slots any device's accumulate
+    loop read, whole chunks up to the capacity, over that capacity) around
+    ``pagerank.dispatch`` and ``pagerank.wait``.
     Counters, per job: ``pagerank.recv_fill`` (most records any device
     received, the exchange's fill among them, over its receive capacity)
     and ``pagerank.max_in_degree``.
@@ -287,8 +320,11 @@ class PageRankJob:
             received = np.array([np.asarray(r) for r, _ in facts])
             args["received"] = received[:, :, 0].sum(axis=1).tolist()
             args["row_move"] = forms_label(self._step.row_moves)
-            tracer.counter("pagerank.recv_fill",
-                           float(received[:, :, 1].max()) / self._capacity,
+            most = int(received[:, :, 1].max())
+            chunk = accumulate_chunk(self._capacity)
+            args["accumulate_fill"] = min(
+                -(-most // chunk) * chunk, self._capacity) / self._capacity
+            tracer.counter("pagerank.recv_fill", most / self._capacity,
                            "pagerank")
             tracer.counter("pagerank.max_in_degree", graph.max_in_degree,
                            "pagerank")

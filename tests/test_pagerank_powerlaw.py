@@ -22,6 +22,7 @@ from benchmark import reference_pagerank  # noqa: E402
 from sparkrdma_tpu.models.pagerank import (  # noqa: E402
     PageRankConfig,
     PageRankJob,
+    accumulate_chunk,
     make_pagerank_step,
     place_graph,
     powerlaw_graph,
@@ -169,6 +170,7 @@ def test_job_spans_and_counters(tmp_path):
     # what the caller gave; the tracer adds its accounting beside it
     own = {k: v for k, v in job["args"].items()
            if k not in ACCOUNTING_ARGS}
+    fill = own.pop("accumulate_fill")
     assert own == {"iterations": 3, "edges": len(edges) - 10,
                    "vertices": num_v,
                    "received": [len(edges) - 10] * 3,
@@ -188,6 +190,15 @@ def test_job_spans_and_counters(tmp_path):
     capacity = exchange.record_capacity(per_dev, 2, devices, cfg.out_factor)
     assert capacity == cfg.out_factor * (16 + devices) * 64
     assert 64 / capacity <= counters["pagerank.recv_fill"] <= 1.0
+    # the slots the accumulate's loop read: whole chunks past the most
+    # records received, no more than the buffer holds
+    chunk = accumulate_chunk(capacity)
+    assert chunk < capacity
+    assert counters["pagerank.recv_fill"] <= fill <= 1.0
+    slots = round(fill * capacity)
+    assert slots % chunk == 0 or slots == capacity
+    most = round(counters["pagerank.recv_fill"] * capacity)
+    assert slots == min(-(-most // chunk) * chunk, capacity)
 
 
 def test_the_three_scopes_name_the_steps_ops():
@@ -266,6 +277,89 @@ def test_one_table_gather_gives_the_two_gather_ranks_bit_for_bit(
     assert len(np.unique(ranks)) > num_v // 4     # no trivial fixed point
     np.testing.assert_array_equal(ranks.view(np.uint32),
                                   want.view(np.uint32))
+
+
+# -- the accumulate's loop over the receive buffer's chunks -------------------
+
+def _chunk_edge_graph(devices, per_dev, to_first, hub):
+    """Source device ``s`` sends ``to_first[s]`` of its edges to device 0
+    (to one vertex where ``hub``, else spread over its vertices); the rest
+    go round the other devices, or are padding where there are none. So
+    device 0 receives exactly ``to_first`` records from its senders, each
+    sender's filled up to whole wire rows of 64."""
+    v_local = 256
+    num_v = devices * v_local
+    rng = np.random.default_rng([devices, per_dev, *to_first])
+    edges = np.empty((devices * per_dev, 2), np.int32)
+    for s, k in enumerate(to_first):
+        own = edges[s * per_dev:(s + 1) * per_dev]
+        own[:, 0] = rng.integers(s * v_local, (s + 1) * v_local, per_dev)
+        own[:k, 1] = 5 if hub else rng.integers(0, v_local, k)
+        if devices == 1:
+            own[k:, 0] = -1
+        else:
+            rest = per_dev - k
+            owner = 1 + np.arange(rest) % (devices - 1)
+            own[k:, 1] = owner * v_local + rng.integers(0, v_local, rest)
+    valid = edges[:, 0] >= 0
+    out_deg = np.bincount(edges[valid, 0],
+                          minlength=num_v).astype(np.float32)
+    return num_v, edges, out_deg
+
+
+# per case and device count: (edges a device, out_factor, records each
+# sender sends to device 0, one hub), and where device 0's ``total`` lies
+# against the chunks (``accumulate_chunk``: 1,024 slots here)
+_CHUNK_CASES = {
+    "whole_chunks": {1: (4096, 2, [3072], False),
+                     4: (4096, 2, [768] * 4, False)},
+    "one_record_past_a_chunk": {1: (4096, 2, [3073], False),
+                                4: (4096, 2, [768, 768, 768, 769], False)},
+    "clamped_last_chunk": {1: (4160, 1, [4160], False),
+                           4: (4096, 2, [2112] * 4, False)},
+    "hub_within_a_chunk_of_capacity": {1: (4160, 1, [4160], True),
+                                       4: (4096, 2, [2176] * 4, True)},
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CHUNK_CASES))
+@pytest.mark.parametrize("devices", [1, 4])
+def test_accumulate_at_the_chunk_boundaries(devices, case):
+    """Where device 0's records end against the accumulate's chunks, two
+    supersteps give the reference's ranks within float32's bound and the
+    plain single-scatter superstep's bit for bit: no slot is summed twice
+    where the last chunk's start is clamped, and none is left out."""
+    per_dev, out_factor, to_first, hub = _CHUNK_CASES[case][devices]
+    num_v, edges, out_deg = _chunk_edge_graph(devices, per_dev, to_first,
+                                              hub)
+    cfg = PageRankConfig(num_vertices=num_v, edges_per_device=per_dev,
+                         out_factor=out_factor)
+    capacity = exchange.record_capacity(per_dev, 2, devices, out_factor)
+    chunk = accumulate_chunk(capacity)
+    step = make_pagerank_step(_mesh(devices), AXIS, cfg)
+    ranks = want = np.full(num_v, 1.0 / num_v, np.float32)
+    for _ in range(2):
+        ranks, received, overflowed = step(edges, ranks, out_deg)
+        assert not np.asarray(overflowed).any()
+        want = _two_gather_superstep(edges, want, out_deg, cfg.damping)
+    total = int(np.asarray(received)[0, 1])
+    assert total == sum(-(-k // 64) * 64 for k in to_first)
+    assert 1024 == chunk < capacity
+    if case == "whole_chunks":
+        assert total % chunk == 0
+    elif case == "one_record_past_a_chunk":
+        # the last sender's one record past the boundary, then its fill
+        assert sum(to_first) % chunk == 1
+    elif case == "clamped_last_chunk":
+        assert capacity % chunk and total > capacity - capacity % chunk
+    else:
+        assert total > capacity - chunk
+    ranks = np.asarray(ranks)
+    problems, readings = reference_pagerank.pagerank_report(
+        ranks, edges, num_v, cfg.damping, 2)
+    assert problems == [] and readings["bound_share"] < 0.5
+    np.testing.assert_array_equal(ranks.view(np.uint32),
+                                  np.asarray(want).view(np.uint32))
 
 
 def _equations(jaxpr, scope=""):

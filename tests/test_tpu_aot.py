@@ -659,3 +659,31 @@ def test_als_half_steps_compile_at_the_cells_size(
                          entry)
     assert len(written) == chunks
     assert compiled.memory_analysis().temp_size_in_bytes < temp_limit
+
+
+# the compiler's own count of a chip's temporaries: 0.807 GB on one chip
+# and 0.941 GB on four when written (0.672 and 0.807 with the masked
+# scatter-add over the whole receive buffer); the receive buffer of
+# 33,554,560 records is 0.27 GB a chip
+@pytest.mark.parametrize("chips", [1, 4])
+def test_pagerank_accumulate_is_one_scatter_in_a_loop_at_the_cells_size(
+        v5e_host, chips):
+    """The PageRank step at the cells' size (16,777,216 edges and 468,750
+    vertices a chip) on described v5e chips: the scatter-add is the
+    program's one scatter, in the body of ``pagerank.accumulate``'s loop
+    over the receive buffer's chunks, and no loop of that scope gathers
+    (the shape the v5e ran wrongly, ``PERF.md`` section 7.14)."""
+    import re
+
+    step, args = _pagerank_step_args(v5e_host[:chips], 16_777_216, 468_750)
+    compiled = step.lower(*args).compile()
+    text = compiled.as_text()
+    scatters = [line for line in text.splitlines() if " scatter(" in line]
+    assert len(scatters) == 1
+    assert "/pagerank.accumulate/while/body/" in scatters[0]
+    loops = [re.match(r"\s*(?:ROOT )?(%[\w.-]+)", line).group(1)
+             for line in text.splitlines()
+             if " while(" in line and "/pagerank.accumulate/" in line]
+    assert loops
+    assert not set(loops) & set(_whiles_that_gather(text))
+    assert compiled.memory_analysis().temp_size_in_bytes < 1_000_000_000
